@@ -393,6 +393,10 @@ async def run_node(config) -> None:
         }
         server.broker.metrics.shard_restarts = int(
             os.environ.get("CHANAMQ_SHARD_RESTARTS", "0") or 0)
+        router = server.broker.router
+        log.info("shard %d of %d (pid %d): router backend=%s",
+                 shard_index, shard_topo.count, os.getpid(),
+                 router.backend if router is not None else "off")
         if config.bool("chana.mq.shard.reuse-port"):
             server.reuse_port = True
         else:
@@ -601,19 +605,10 @@ async def run_node(config) -> None:
             # live-telemetry forecaster (SURVEY.md §7.1's JAX role): samples
             # metrics on the loop, trains/predicts on a worker thread,
             # serves GET /admin/forecast + chanamq_forecast_* gauges.
-            # Fail fast on a core-only install: without the probe, a
-            # missing jax would only surface as a traceback per train
-            # round (worker thread), never as a boot error.
-            try:
-                import jax  # noqa: F401
-                import numpy  # noqa: F401
-            except ImportError as exc:
-                from ..config import ConfigError
-
-                raise ConfigError(
-                    "chana.mq.forecast.enabled requires jax + numpy "
-                    "(pip install 'chanamq-tpu[forecast]'); "
-                    f"import failed: {exc}") from None
+            # start() claims the device (chanamq_tpu/device.py): a missing
+            # jax, or a backend that is not the chip and was not asked
+            # for, is a boot error here — not a traceback per train round
+            # on the worker thread.
             from ..models.service import ForecastService
 
             forecaster = ForecastService(

@@ -32,6 +32,7 @@ from typing import TYPE_CHECKING, Any, Optional
 
 import numpy as np
 
+from .. import device
 from .telemetry import (
     FEATURES, TelemetryRing, TopKSlots, counter_state, normalization,
     sample, training_batch,
@@ -102,7 +103,9 @@ class ForecastService:
         self._round_inflight = False
         self._stopping = False  # cooperative cancel for an in-flight round
         self._np_rng = np.random.default_rng(0)
-        # lazily-built JAX state (worker thread only)
+        # the process's device, claimed in start(); the JAX state on it
+        # is built lazily on the worker thread
+        self.device: Optional[device.Device] = None
         self._jax_state: Optional[dict[str, Any]] = None
         # latest results (event loop writes, anyone reads)
         self.forecast: Optional[dict[str, float]] = None
@@ -123,13 +126,17 @@ class ForecastService:
     # -- lifecycle ---------------------------------------------------------
 
     async def start(self) -> None:
+        # the worker thread jits on whatever device this process holds:
+        # claim it now, at boot, so a wrong backend fails the boot
+        self.device = device.claim()
         self.broker.forecaster = self
         self._task = asyncio.get_event_loop().create_task(self._run())
         self._task.add_done_callback(self._on_run_done)
         log.info(
             "forecast service on: interval=%.3gs train-interval=%.3gs "
-            "window=%d model=%s", self.interval_s, self.train_interval_s,
-            self.seq_len, self.model_kwargs)
+            "window=%d model=%s device=%s (%s)", self.interval_s,
+            self.train_interval_s, self.seq_len, self.model_kwargs,
+            self.device.platform, self.device.kind)
 
     async def stop(self) -> None:
         # cooperative cancel: concurrent.futures joins worker threads at

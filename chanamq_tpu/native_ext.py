@@ -3,8 +3,11 @@
 Load order: (1) the library pip built at install time
 (chanamq_tpu/_chanamq_native*.so, see setup.py), (2) a repo checkout's
 native/libchanamq_native.so, compiled on first use when a C++ toolchain is
-present. Falls back silently (callers keep the pure-Python implementations)
-when no library can be found or built, or CHANAMQ_NATIVE=0.
+present. Callers keep the pure-Python implementations when CHANAMQ_NATIVE=0
+asks for them; when the library was wanted and cannot be found, built or
+loaded they do too, after a WARNING — the router's batched device path only
+runs behind the native frame scan, so a quiet drop to Python would also be a
+quiet drop off the device.
 
 Exposes:
   NativeFrameParser   — drop-in for amqp.frame.FrameParser; batches also
@@ -52,7 +55,7 @@ def _build() -> bool:
                        capture_output=True, timeout=120)
         return os.path.exists(_LIB_PATH)
     except Exception as exc:
-        log.info("native build unavailable: %r", exc)
+        log.warning("native build failed: %r", exc)
         return False
 
 
@@ -88,11 +91,16 @@ def load() -> Optional[ctypes.CDLL]:
         return None
     lib_path = _find_lib()
     if lib_path is None:
+        log.warning(
+            "native library unavailable (nothing prebuilt, and `make -C %s` "
+            "did not produce one): running the pure-Python hot paths. Set "
+            "CHANAMQ_NATIVE=0 to choose them deliberately.", _NATIVE_DIR)
         return None
     try:
         lib = ctypes.CDLL(lib_path)
     except OSError as exc:
-        log.info("native lib load failed: %r", exc)
+        log.warning("native library %s failed to load (%r): running the "
+                    "pure-Python hot paths", lib_path, exc)
         return None
     lib.chana_scan_frames.restype = ctypes.c_int
     lib.chana_scan_frames.argtypes = [

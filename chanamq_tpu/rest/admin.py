@@ -20,6 +20,7 @@ import time
 from typing import Optional
 from urllib.parse import parse_qs, unquote
 
+from .. import device, native_ext
 from ..broker.broker import Broker
 from ..store.api import is_replica_vhost
 
@@ -852,7 +853,8 @@ class AdminServer:
         "lifecycle_evacuation_retries", "lifecycle_rollbacks",
         "lifecycle_stale_epoch_refused", "lifecycle_join_rebalances",
         "lifecycle_stale_holders_cleared",
-        "router_batches", "router_batch_msgs", "router_compiles",
+        "router_batches", "router_batch_msgs", "router_kernel_launches",
+        "router_compiles",
         "router_fallback_msgs", "router_parity_mismatches",
         "profile_samples_total", "profile_slow_callbacks_total",
         "profile_gc_pauses_total", "profile_gc_pause_ns_total",
@@ -1130,8 +1132,16 @@ class AdminServer:
         return "\n".join(out) + "\n"
 
     def _overview(self) -> dict:
+        held = device.claimed()
+        router = self.broker.router
         return {
             "product": "chanamq-tpu",
+            # what this process runs on: the device it claimed at boot
+            # (None: it holds none and never imported JAX), which backend
+            # its router matches on, and whether the C++ hot paths loaded
+            "device": held.snapshot() if held is not None else None,
+            "router_backend": router.backend if router is not None else None,
+            "native": native_ext.available(),
             "vhosts": {
                 name: {
                     "active": vhost.active,

@@ -428,13 +428,17 @@ def route_batch(
     compiled: CompiledExchange,
     items: list,
     backend: str = "jax",
+    metrics: Any = None,
 ) -> list:
     """Route a batch through a compiled snapshot.
 
     ``items`` is a list of ``(routing_key, headers-or-None)``; the return
     is an aligned list of frozensets of queue names. backend="jax" runs the
     match kernels under jit; backend="python" runs the identical kernel
-    body on numpy (no jax import at all)."""
+    body on numpy (no jax import at all). ``metrics`` (the broker's
+    registry) counts each jitted kernel call in ``router_kernel_launches``
+    — the one series that tells a flush that reached the device from one
+    the key memo, a host dict or the numpy twin served."""
     kind = compiled.kind
     if kind == "fanout":
         always = compiled.always
@@ -474,6 +478,8 @@ def route_batch(
         pre_m, suf_m, mlen = _tokenize_topic(wild, uniq, b)
         if backend == "jax":
             kern, _ = _jit_kernels()
+            if metrics is not None:
+                metrics.router_kernel_launches += 1
             rows = np.asarray(kern(
                 wild["pre"], wild["suf"], wild["plen"], wild["slen"],
                 wild["has_hash"], wild["masks"], pre_m, suf_m, mlen))
@@ -497,6 +503,8 @@ def route_batch(
         pids = _tokenize_headers(table, [h for _, h in items], b)
         if backend == "jax":
             _, kern = _jit_kernels()
+            if metrics is not None:
+                metrics.router_kernel_launches += 1
             rows = np.asarray(kern(
                 table["req"], table["rcount"], table["is_all"],
                 table["masks"], pids))

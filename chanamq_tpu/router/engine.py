@@ -34,6 +34,8 @@ import logging
 import time
 from typing import TYPE_CHECKING, Optional
 
+from .. import device
+from ..config import ConfigError
 from . import compile as rcompile
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -77,7 +79,20 @@ class TensorRouter:
         verify: bool = False,
     ) -> None:
         self.broker = broker
-        self.backend = backend if backend in ("jax", "python") else "jax"
+        if backend not in ("jax", "python"):
+            raise ConfigError(
+                "chana.mq.router.backend must be 'jax' or 'python', "
+                f"got {backend!r}")
+        self.backend = backend
+        # backend jax claims the process's device here, at boot — not at
+        # the first wildcard flush in the middle of traffic
+        self.device = device.claim() if backend == "jax" else None
+        if self.device is not None:
+            log.info("tensor router: backend=jax, match kernels on %s (%s)",
+                     self.device.platform, self.device.kind)
+        else:
+            log.info("tensor router: backend=python, match kernels on "
+                     "numpy; this process holds no device")
         self.min_batch = max(1, min_batch)
         self.max_wildcards = max_wildcards
         self.max_queues = max_queues
@@ -327,7 +342,8 @@ class TensorRouter:
                         out[idx] = self._queues(vhost_name, vhost, names)
                 continue
             items = [(entries[i][1], entries[i][2].headers) for i in idxs]
-            name_sets = rcompile.route_batch(compiled, items, self.backend)
+            name_sets = rcompile.route_batch(
+                compiled, items, self.backend, metrics)
             if self.verify:
                 exchange = vhost.exchanges[exchange_name]
                 if exchange.ex_matcher is not None:

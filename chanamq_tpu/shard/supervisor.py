@@ -13,7 +13,10 @@ no second config mechanism):
   failure timeouts come from the much tighter ``chana.mq.shard.*``
   knobs;
 * ``CHANAMQ_ADMIN_PORT`` = admin base + index, ``CHANAMQ_STORE_PATH``
-  gets a per-shard suffix so sqlite files never collide.
+  gets a per-shard suffix so sqlite files never collide;
+* every worker but shard 0 gets ``CHANAMQ_ROUTER_BACKEND=python`` and
+  ``CHANAMQ_FORECAST_ENABLED=false``: a chip belongs to one process, so
+  shard 0 alone claims the device (the supervisor never imports JAX).
 
 A worker that dies is respawned after ``chana.mq.shard.restart-backoff``
 (up to ``chana.mq.shard.max-restarts`` times); the survivors' membership
@@ -34,6 +37,9 @@ from typing import Optional
 from .topology import ShardTopology
 
 log = logging.getLogger("chanamq.shard.supervisor")
+
+# the one worker that holds the node's accelerator (chanamq_tpu/device.py)
+DEVICE_SHARD = 0
 
 
 def child_env(
@@ -56,6 +62,13 @@ def child_env(
         "CHANAMQ_CLUSTER_FAILURE_TIMEOUT":
             config.str("chana.mq.shard.failure-timeout"),
     })
+    if index != DEVICE_SHARD:
+        # a chip belongs to one process: only DEVICE_SHARD claims it. The
+        # others match on the numpy twin of the same kernels and run no
+        # forecaster, so they never import JAX (each worker's boot line
+        # says which backend it got)
+        env["CHANAMQ_ROUTER_BACKEND"] = "python"
+        env["CHANAMQ_FORECAST_ENABLED"] = "false"
     if config.bool("chana.mq.admin.enabled"):
         env["CHANAMQ_ADMIN_PORT"] = str(
             config.int("chana.mq.admin.port") + index)

@@ -216,8 +216,11 @@ class Metrics:
         self.lifecycle_stale_epoch_refused = 0
         self.lifecycle_join_rebalances = 0
         self.lifecycle_stale_holders_cleared = 0
-        # tensorized router (chanamq_tpu/router/): kernel batches routed,
-        # messages in them, table compiles + the current generation (gauge),
+        # tensorized router (chanamq_tpu/router/): batches routed through a
+        # compiled table (memo hits, host dicts and kernel calls alike),
+        # messages in them, jitted kernel calls among them (the only ones
+        # that reached the device), table compiles + the current
+        # generation (gauge),
         # messages that fell back to the Python matcher (uncompilable
         # exchange or sub-min-batch flush), and verify-mode parity
         # mismatches (always 0 unless a kernel bug slips parity testing).
@@ -225,6 +228,7 @@ class Metrics:
         # messages per kernel call, not microseconds.
         self.router_batches = 0
         self.router_batch_msgs = 0
+        self.router_kernel_launches = 0
         self.router_compiles = 0
         self.router_generation = 0
         self.router_fallback_msgs = 0
@@ -466,6 +470,7 @@ class Metrics:
                 self.lifecycle_stale_holders_cleared,
             "router_batches": self.router_batches,
             "router_batch_msgs": self.router_batch_msgs,
+            "router_kernel_launches": self.router_kernel_launches,
             "router_compiles": self.router_compiles,
             "router_generation": self.router_generation,
             "router_fallback_msgs": self.router_fallback_msgs,

@@ -7,6 +7,12 @@
 set -u
 cd "$(dirname "$0")/.."
 
+# This gate runs on the CPU, and says so once for every smoke below: a
+# broker refuses to boot on a CPU that JAX_PLATFORMS did not ask for
+# (chanamq_tpu/device.py), and every `python bench.py` line starts brokers.
+# The chip's own check is `python chip_smoke.py`, not this script.
+export JAX_PLATFORMS=cpu
+
 # Native pipeline gate: rebuild the library from a clean tree so the suite
 # below exercises the freshly-built scanner/encoder (a stale .so silently
 # falling back to Python would pass every parity test while benching the

@@ -4,7 +4,8 @@ native/chanamq_native.cpp is a plain `extern "C"` shared object consumed via
 ctypes (chanamq_tpu/native_ext.py), not a CPython extension module — so it is
 compiled with build_ext machinery but never imported. A missing/broken C++
 toolchain must not fail the install: the broker runs on its pure-Python hot
-paths (native_ext falls back silently), so build errors just skip the lib.
+paths (native_ext logs a WARNING and falls back), so build errors just skip
+the lib.
 """
 
 from setuptools import Extension, setup

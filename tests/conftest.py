@@ -5,8 +5,11 @@ import sys
 
 import pytest
 
-# Multi-chip sharding tests run on a virtual 8-device CPU mesh; set the flags
-# before any jax import (only the jax-marked tests import jax at all).
+# The suite runs on the CPU, and this is the one place that asks for it: a
+# Broker with the default router backend claims JAX's device when it is built
+# (chanamq_tpu/device.py), which refuses a CPU nobody asked for. The mesh
+# sharding tests use 8 virtual CPU devices; both must be set before any jax
+# import.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:

@@ -230,7 +230,11 @@ async def test_cursor_commits_mirror_to_remote():
                      .committed.get("group-1") == 10),
             what="cursor mirror")
         assert b_srv.broker.metrics.federation_cursors_mirrored >= 1
-        assert a_srv.broker.metrics.federation_cursors_shipped >= 1
+        # the shipper counts a cursor only once fed.cursor's reply is back,
+        # which is after the mirror (checked above) has applied it
+        await eventually(
+            lambda: a_srv.broker.metrics.federation_cursors_shipped >= 1,
+            what="cursor ship acknowledged")
         assert any(ev == "cursor.mirrored" for ev, _ in fed_b.events)
         await conn.close()
     finally:

@@ -12,7 +12,7 @@ import json
 import numpy as np
 import pytest
 
-jax = pytest.importorskip("jax")
+pytest.importorskip("jax")
 
 from chanamq_tpu.broker.server import BrokerServer  # noqa: E402
 from chanamq_tpu.client import AMQPClient  # noqa: E402
@@ -23,11 +23,6 @@ from chanamq_tpu.models.telemetry import (  # noqa: E402
 from chanamq_tpu.rest.admin import AdminServer  # noqa: E402
 
 pytestmark = pytest.mark.asyncio
-
-
-@pytest.fixture(scope="module", autouse=True)
-def force_cpu():
-    jax.config.update("jax_platforms", "cpu")
 
 
 # -- ring unit tests ---------------------------------------------------------

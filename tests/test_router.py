@@ -251,6 +251,47 @@ def test_engine_python_backend_and_fallback(event_loop):
     assert broker.metrics.router_fallback_msgs == before + 1
 
 
+def test_kernel_launches_count_only_flushes_that_reach_the_device(event_loop):
+    """router_batches counts memo hits, host dicts and kernel calls alike;
+    router_kernel_launches counts the jitted calls alone."""
+    broker = _mk_broker_with_topic(event_loop)
+    router, metrics = broker.router, broker.metrics
+    router.min_batch = 1
+    router.route_pending("/", _entries([("ex", "a.b"), ("ex", "a.c")]))
+    assert (metrics.router_batches, metrics.router_kernel_launches) == (1, 1)
+    # the same keys again: the key memo serves them, no kernel call
+    router.route_pending("/", _entries([("ex", "a.b"), ("ex", "a.c")]))
+    assert (metrics.router_batches, metrics.router_kernel_launches) == (2, 1)
+    # the numpy twin of the kernel is a batch, but not a device launch
+    router.backend = "python"
+    router.route_pending("/", _entries([("ex", "a.new")]))
+    assert (metrics.router_batches, metrics.router_kernel_launches) == (3, 1)
+    assert metrics.snapshot()["router_kernel_launches"] == 1
+
+
+@pytest.mark.parametrize("backend,holds_device", [
+    ("jax", True), ("python", False)])
+def test_router_claims_the_device_at_construction(backend, holds_device,
+                                                  caplog):
+    """Backend jax names its device when the router is built (not at the
+    first wildcard flush); backend python holds none; each says which."""
+    with caplog.at_level("INFO", logger="chanamq.router"):
+        broker = Broker(router_backend=backend)
+    device = broker.router.device
+    assert (device is not None) == holds_device
+    if holds_device:
+        assert device.platform == "cpu"  # conftest asked for it
+        assert device.count >= 1 and device.kind
+    assert f"backend={backend}" in caplog.text
+
+
+def test_unknown_router_backend_is_a_config_error():
+    from chanamq_tpu.config import ConfigError
+
+    with pytest.raises(ConfigError, match="chana.mq.router.backend"):
+        Broker(router_backend="numpy")
+
+
 def test_engine_min_batch_falls_back(event_loop):
     broker = _mk_broker_with_topic(event_loop)
     router = broker.router

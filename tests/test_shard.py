@@ -112,6 +112,27 @@ async def test_child_env_layers_per_shard_values(tmp_path):
     assert env["CHANAMQ_STORE_PATH"] == str(tmp_path / "node.db") + ".shard1"
 
 
+@pytest.mark.parametrize("index,holds_device", [(0, True), (1, False),
+                                                (3, False)])
+async def test_child_env_gives_the_device_to_one_shard(
+        tmp_path, monkeypatch, index, holds_device):
+    """A chip belongs to one process: shard 0 keeps the configured router
+    backend and forecaster, every other worker is started on the numpy
+    twin with no forecaster, so it never imports JAX."""
+    monkeypatch.delenv("CHANAMQ_ROUTER_BACKEND", raising=False)
+    monkeypatch.delenv("CHANAMQ_FORECAST_ENABLED", raising=False)
+    config = _config({"chana.mq.forecast.enabled": True})
+    topo = ShardTopology(count=4, host="127.0.0.1", base_port=7100,
+                         dir=str(tmp_path))
+    env = child_env(config, topo, index, restarts=0)
+    if holds_device:
+        assert "CHANAMQ_ROUTER_BACKEND" not in env
+        assert "CHANAMQ_FORECAST_ENABLED" not in env
+    else:
+        assert env["CHANAMQ_ROUTER_BACKEND"] == "python"
+        assert env["CHANAMQ_FORECAST_ENABLED"] == "false"
+
+
 async def test_supervisor_restart_budget(monkeypatch, tmp_path):
     """A worker that keeps dying is respawned max-restarts times, then
     left down — the watcher must not spin."""
