@@ -20,7 +20,7 @@ import logging
 import time
 from typing import Any, Awaitable, Optional
 
-from .. import chaos, events, profile, trace
+from .. import chaos, device, events, profile, trace
 
 from ..amqp.constants import ErrorCode, ExchangeType
 from ..amqp.properties import BasicProperties
@@ -329,19 +329,21 @@ class Broker:
         metrics = self.metrics
         prof = profile.ACTIVE
         t_enq = time.perf_counter_ns() if prof is not None else 0
-        for entry, queues in zip(entries, routes):
-            exchange, routing_key, props, body, header, exrk, confirmed = entry
-            metrics.published(len(body))
-            if trace.ACTIVE is not None:
-                tr = trace.ACTIVE.begin_publish(self.trace_node,
-                                                props.headers)
-                if tr is not None:
-                    # the whole flush routed as one kernel call: each
-                    # sampled message carries the batch's ROUTE window
-                    tr.span(trace.ROUTE, t0, t1, self.trace_node)
-            self._publish_local(
-                queues, exchange, routing_key, props, body, False,
-                header, confirm_marks if confirmed else None, exrk)
+        with device.span("broker.enqueue"):
+            for entry, queues in zip(entries, routes):
+                (exchange, routing_key, props, body, header, exrk,
+                 confirmed) = entry
+                metrics.published(len(body))
+                if trace.ACTIVE is not None:
+                    tr = trace.ACTIVE.begin_publish(self.trace_node,
+                                                    props.headers)
+                    if tr is not None:
+                        # the whole flush routed as one kernel call: each
+                        # sampled message carries the batch's ROUTE window
+                        tr.span(trace.ROUTE, t0, t1, self.trace_node)
+                self._publish_local(
+                    queues, exchange, routing_key, props, body, False,
+                    header, confirm_marks if confirmed else None, exrk)
         if prof is not None:
             # batch-granular ledger: one accumulate covers the whole flush
             # (route window from the router, enqueue from the loop above),

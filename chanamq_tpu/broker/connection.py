@@ -53,7 +53,7 @@ from ..amqp.frame import (
 from ..amqp import methods as am
 from ..amqp.properties import BasicProperties
 from ..amqp.frame import ENC_META as _ENC_META
-from .. import events, profile, trace
+from .. import device, events, profile, trace
 from .broker import Broker, BrokerError
 from .channel import ChannelMode, Consumer, ServerChannel
 from ..flow import STAGE_THROTTLE
@@ -289,6 +289,8 @@ class AMQPConnection:
         # per-channel/per-queue FIFO and confirm durability are preserved
         # exactly as if each message had published inline
         self._route_pending: list = []
+        # the read chunk's open `conn.ingress` profiler span, if any
+        self._ingress = None
         self._remote_strict = False
         self._remote_failures: list = []
         # tail of the ordered background chain pipelining remote-push
@@ -956,7 +958,16 @@ class AMQPConnection:
                               + sns[profile.CLUSTER_PUSH]
                               + sns[profile.INGRESS_CYCLE])
             if scan is not None:
-                ok = await self._consume_scan(scan(data))
+                # one profiler span a read chunk over the native scan and
+                # the fused-publish loop; the doors out of that synchronous
+                # stretch (a flush, a generic command, a close) end it
+                # first, so that no other span opens inside it
+                self._ingress = device.span("conn.ingress")
+                self._ingress.__enter__()
+                try:
+                    ok = await self._consume_scan(scan(data))
+                finally:
+                    self._end_ingress()
             else:
                 ok = await self._consume_feed(self._parser.feed(data))
             if ok:
@@ -972,9 +983,16 @@ class AMQPConnection:
             if not ok:
                 return
 
+    def _end_ingress(self) -> None:
+        span = self._ingress
+        if span is not None:
+            self._ingress = None
+            span.__exit__(None, None, None)
+
     async def _run_command(self, out: AMQCommand) -> bool:
         """Dispatch one assembled command with the connection's error
         semantics. Returns False when the connection must stop serving."""
+        self._end_ingress()
         if (self.broker.flow_refusing
                 and type(out.method) is am.Basic.Publish
                 and out.channel != 0
@@ -1365,6 +1383,7 @@ class AMQPConnection:
         publish path never awaits, so a flush can run at any point of
         read-batch processing without yielding the event loop (which is
         exactly what makes deferral invisible to other connections)."""
+        self._end_ingress()
         entries, self._route_pending = self._route_pending, []
         self.broker.flush_deferred_publishes(
             self.vhost_name, entries, self._confirm_marks)
@@ -1442,6 +1461,7 @@ class AMQPConnection:
         blob + queue-log rows — all in one group-commit batch). Free for
         single-node transient traffic: with no remote pushes and no enqueue
         windows recorded, flush([]) resolves immediately."""
+        self._end_ingress()
         if self._route_pending:
             # deferred publishes must enqueue their store writes (and
             # record their marks) before the marks are consumed below
@@ -1503,11 +1523,13 @@ class AMQPConnection:
     def _flush_confirms(self) -> None:
         if not self._pending_confirms:
             return
-        for channel_id, max_seq in self._pending_confirms.items():
-            if channel_id in self.channels:
-                self.send_method(
-                    channel_id, am.Basic.Ack(delivery_tag=max_seq, multiple=True))
-        self._pending_confirms.clear()
+        with device.span("conn.confirms"):
+            for channel_id, max_seq in self._pending_confirms.items():
+                if channel_id in self.channels:
+                    self.send_method(
+                        channel_id,
+                        am.Basic.Ack(delivery_tag=max_seq, multiple=True))
+            self._pending_confirms.clear()
 
     # ------------------------------------------------------------------
     # teardown / close

@@ -19,6 +19,7 @@ where they are built, before the first ``jax.jit``, so that
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -97,6 +98,26 @@ class Device:
 _claimed: Optional[Device] = None
 
 
+# what ``span()`` hands out while no profiler session can record
+_NO_SPAN = contextlib.nullcontext()
+# jax.profiler.TraceAnnotation once a device is claimed (this module owns
+# the JAX import: a process on router.backend=python never loads it)
+_annotation = None
+
+
+def span(name: str):
+    """A context manager that puts ``name`` on the calling thread's line of
+    the profiler's trace, on the device trace's clock, so an idle gap of the
+    chip can be credited to what the host was doing meanwhile. Outside a
+    profiler session (one C call tells), and in a process that claimed no
+    device, it is a shared no-op. Spans are written flat: open none inside
+    another, and none across an ``await`` (trace readers credit a stretch to
+    the outermost event and would lose everything inside)."""
+    if _annotation is None or not _annotation.is_enabled():
+        return _NO_SPAN
+    return _annotation(name)
+
+
 def claimed() -> Optional[Device]:
     """The device this process holds, or None when nothing claimed one
     (router backend python and no forecaster: JAX was never imported)."""
@@ -107,7 +128,7 @@ def claim() -> Device:
     """Bring JAX's backend up, check it against the rule above, point the
     compile cache, and log the device. Idempotent: the backend is a
     process-wide fact, so every later call returns the first result."""
-    global _claimed
+    global _claimed, _annotation
     if _claimed is not None:
         return _claimed
     try:
@@ -142,4 +163,5 @@ def claim() -> Device:
         device.platform, device.kind, device.count, asked or "unset",
         cache_dir)
     _claimed = device
+    _annotation = jax.profiler.TraceAnnotation
     return device

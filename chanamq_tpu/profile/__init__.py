@@ -18,7 +18,18 @@ Three coupled parts, all always-cheap enough to leave on in production:
   callback stalling the loop past ``chana.mq.profile.slow-callback-ms``,
   and a ``gc.callbacks`` hook attributes collector pauses.
 - the aggregate view at ``GET /admin/profile``: µs/msg by stage and by
-  subsystem plus the fraction of process CPU the ledger attributes.
+  subsystem plus the fraction of process CPU the ledger attributes, and a
+  ``router`` block: the launch counters ``Metrics`` keeps whether the
+  ledger is on or not (``chanamq_router_{tokenize,dispatch,wait,decode,
+  route}_ns``, ``_kernel_{keys,rows}``, ``_h2d_bytes``; stamped in
+  router/compile.py ``_launch``) and, under ``per_launch``, the ``route``
+  stage split per device launch by them. The ledger's ``route`` window
+  and ``router_route_ns`` are one pair of stamps.
+
+The hierarchy of the loop's work lives here (``ingress-cycle`` ⊃ ``route``
+⊃ …). The flat names a ``jax.profiler`` trace shows on the loop's thread
+(``device.span``: ``conn.ingress``, ``router.lookup``, …) are a different
+outlet of the same seams, on the device trace's clock.
 
 Like ``trace`` and ``chaos``: disabled (the default) costs one module
 attribute load + ``is None`` per seam.

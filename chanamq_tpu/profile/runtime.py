@@ -192,6 +192,26 @@ class ProfileRuntime:
 
     # -- aggregate view ------------------------------------------------------
 
+    def _router_block(self) -> dict:
+        """The route stage seen from inside: the integers /admin/overview
+        serves, and what one device launch costs the loop by them."""
+        m = self.metrics
+        launches = m.router_kernel_launches
+        block = {"kernel_launches": launches, **m.router_launch()}
+        if launches and m.router_kernel_rows:
+            us = 1e-3 / launches
+            block["per_launch"] = {
+                "tokenize_us": round(m.router_tokenize_ns * us, 1),
+                "dispatch_us": round(m.router_dispatch_ns * us, 1),
+                "wait_us": round(m.router_wait_ns * us, 1),
+                "decode_us": round(m.router_decode_ns * us, 1),
+                "keys": round(m.router_kernel_keys / launches, 1),
+                "useful_row_pct": round(
+                    100.0 * m.router_kernel_keys / m.router_kernel_rows, 1),
+                "h2d_bytes": round(m.router_h2d_bytes / launches),
+            }
+        return block
+
     def snapshot(self) -> dict:
         """The /admin/profile payload: per-stage and per-subsystem µs plus
         the attribution ratio. Pure reads — safe on the admin path."""
@@ -243,6 +263,8 @@ class ProfileRuntime:
                 "max_pause_ns": self.gc_max_pause_ns,
             },
         }
+        if self.metrics is not None:
+            out["router"] = self._router_block()
         sampler = self.sampler
         if sampler is not None:
             out["sampler"] = {

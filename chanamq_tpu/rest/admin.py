@@ -23,6 +23,7 @@ from urllib.parse import parse_qs, unquote
 from .. import device, native_ext
 from ..broker.broker import Broker
 from ..store.api import is_replica_vhost
+from ..utils.metrics import Metrics
 
 log = logging.getLogger("chanamq.admin")
 
@@ -800,7 +801,7 @@ class AdminServer:
     def _profile(self) -> dict:
         """Cost-ledger aggregate: µs/msg by stage and subsystem, loop busy
         time vs process CPU (attribution ratio), GC pauses, slow-callback
-        captures."""
+        captures, and the router's launch counters (`router`)."""
         return self._profsvc().snapshot()
 
     def _profile_stacks(self) -> str:
@@ -856,6 +857,7 @@ class AdminServer:
         "router_batches", "router_batch_msgs", "router_kernel_launches",
         "router_compiles",
         "router_fallback_msgs", "router_parity_mismatches",
+        *Metrics.ROUTER_LAUNCH,
         "profile_samples_total", "profile_slow_callbacks_total",
         "profile_gc_pauses_total", "profile_gc_pause_ns_total",
         "events_published_total", "events_dropped_total",

@@ -45,9 +45,12 @@ as tables and batches grow.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Iterable, Optional
 
 import numpy as np
+
+from .. import device
 
 STAR = -1   # pattern cell: '*' (matches exactly one word)
 PAD = -2    # pattern cell: beyond this pattern's length
@@ -417,11 +420,45 @@ def _jit_kernels():
         import jax
         import jax.numpy as jnp
 
-        _JIT_TOPIC = jax.jit(
-            lambda *a: _topic_kernel(jnp, *a))
-        _JIT_HEADERS = jax.jit(
-            lambda *a: _headers_kernel(jnp, *a))
+        # functions with names, not lambdas: a call's host event reads
+        # PjitFunction(topic_match), its module jit_topic_match, and the
+        # ops carry the scope, so a trace tells the two kernels apart
+        def topic_match(*args):
+            with jax.named_scope("router.topic_match"):
+                return _topic_kernel(jnp, *args)
+
+        def headers_match(*args):
+            with jax.named_scope("router.headers_match"):
+                return _headers_kernel(jnp, *args)
+
+        _JIT_TOPIC = jax.jit(topic_match)
+        _JIT_HEADERS = jax.jit(headers_match)
     return _JIT_TOPIC, _JIT_HEADERS
+
+
+def _launch(kern, args: tuple, keys: int, t_tok: int, metrics) -> tuple:
+    """One jitted kernel call and the wait for its rows, on the calling
+    thread (the event loop's), stamped once at each boundary: ``t_tok`` is
+    when the tokenizer started, ``keys`` how many rows carry a real key.
+    Returns ``(rows, t_rows)``, ``t_rows`` being when the rows were on the
+    host, from where the caller times its decode. The jitted call and
+    ``np.asarray`` write JAX's own events into a profiler trace, so no
+    span is opened here."""
+    t_call = time.perf_counter_ns()
+    result = kern(*args)
+    t_back = time.perf_counter_ns()
+    rows = np.asarray(result)
+    t_rows = time.perf_counter_ns()
+    if metrics is not None:
+        metrics.router_kernel_launches += 1
+        metrics.router_tokenize_ns += t_call - t_tok
+        metrics.router_dispatch_ns += t_back - t_call
+        metrics.router_wait_ns += t_rows - t_back
+        metrics.router_kernel_keys += keys
+        metrics.router_kernel_rows += rows.shape[0]
+        metrics.router_h2d_bytes += sum(
+            a.nbytes for a in args if isinstance(a, np.ndarray))
+    return rows, t_rows
 
 
 def route_batch(
@@ -438,7 +475,10 @@ def route_batch(
     body on numpy (no jax import at all). ``metrics`` (the broker's
     registry) counts each jitted kernel call in ``router_kernel_launches``
     — the one series that tells a flush that reached the device from one
-    the key memo, a host dict or the numpy twin served."""
+    the key memo, a host dict or the numpy twin served — and, with it,
+    what that launch cost the calling thread (``_launch``). The stretches
+    either side of the call carry ``device.span`` names, flat: lookup,
+    tokenize, (JAX's own two events), decode."""
     kind = compiled.kind
     if kind == "fanout":
         always = compiled.always
@@ -453,46 +493,49 @@ def route_batch(
         # memo is keyed on the key alone: steady-state routing (bounded
         # key cardinality, the common AMQP shape) is one dict hit per
         # message and only never-seen keys pay tokenize + kernel
-        wild = compiled.wild
-        out = [None] * len(items)
-        miss: dict = {}  # unique unseen keys -> their positions
-        for i, (key, _) in enumerate(items):
-            names = memo.get(key)
-            if names is None:
-                miss.setdefault(key, []).append(i)
-            else:
-                out[i] = names
-        if not miss:
-            return out
-        if len(memo) + len(miss) >= _MEMO_CAP:
-            memo.clear()
-        if wild is None:
-            for key, idxs in miss.items():
-                names = compiled.exact.get(key, _EMPTY) | compiled.always
-                memo[key] = names
-                for i in idxs:
+        with device.span("router.lookup"):
+            wild = compiled.wild
+            out = [None] * len(items)
+            miss: dict = {}  # unique unseen keys -> their positions
+            for i, (key, _) in enumerate(items):
+                names = memo.get(key)
+                if names is None:
+                    miss.setdefault(key, []).append(i)
+                else:
                     out[i] = names
-            return out
-        uniq = list(miss)
-        b = _bucket(len(uniq), 16)
-        pre_m, suf_m, mlen = _tokenize_topic(wild, uniq, b)
-        if backend == "jax":
-            kern, _ = _jit_kernels()
-            if metrics is not None:
-                metrics.router_kernel_launches += 1
-            rows = np.asarray(kern(
-                wild["pre"], wild["suf"], wild["plen"], wild["slen"],
-                wild["has_hash"], wild["masks"], pre_m, suf_m, mlen))
-        else:
-            rows = _topic_kernel(
-                np, wild["pre"], wild["suf"], wild["plen"], wild["slen"],
+            if not miss:
+                return out
+            if len(memo) + len(miss) >= _MEMO_CAP:
+                memo.clear()
+            if wild is None:
+                for key, idxs in miss.items():
+                    names = compiled.exact.get(key, _EMPTY) | compiled.always
+                    memo[key] = names
+                    for i in idxs:
+                        out[i] = names
+                return out
+            uniq = list(miss)
+            b = _bucket(len(uniq), 16)
+        t_tok = time.perf_counter_ns()
+        with device.span("router.tokenize"):
+            pre_m, suf_m, mlen = _tokenize_topic(wild, uniq, b)
+        args = (wild["pre"], wild["suf"], wild["plen"], wild["slen"],
                 wild["has_hash"], wild["masks"], pre_m, suf_m, mlen)
-        for j, key in enumerate(uniq):
-            names = (compiled.exact.get(key, _EMPTY) | compiled.always
-                     | compiled._decode_mask(rows[j]))
-            memo[key] = names
-            for i in miss[key]:
-                out[i] = names
+        t_rows = 0
+        if backend == "jax":
+            rows, t_rows = _launch(
+                _jit_kernels()[0], args, len(uniq), t_tok, metrics)
+        else:
+            rows = _topic_kernel(np, *args)
+        with device.span("router.decode"):
+            for j, key in enumerate(uniq):
+                names = (compiled.exact.get(key, _EMPTY) | compiled.always
+                         | compiled._decode_mask(rows[j]))
+                memo[key] = names
+                for i in miss[key]:
+                    out[i] = names
+        if t_rows and metrics is not None:
+            metrics.router_decode_ns += time.perf_counter_ns() - t_rows
         return out
 
     if kind == "headers":
@@ -500,29 +543,31 @@ def route_batch(
         if table is None:
             return [compiled.always] * len(items)
         b = _bucket(len(items), 16)
-        pids = _tokenize_headers(table, [h for _, h in items], b)
-        if backend == "jax":
-            _, kern = _jit_kernels()
-            if metrics is not None:
-                metrics.router_kernel_launches += 1
-            rows = np.asarray(kern(
-                table["req"], table["rcount"], table["is_all"],
-                table["masks"], pids))
-        else:
-            rows = _headers_kernel(
-                np, table["req"], table["rcount"], table["is_all"],
+        t_tok = time.perf_counter_ns()
+        with device.span("router.tokenize"):
+            pids = _tokenize_headers(table, [h for _, h in items], b)
+        args = (table["req"], table["rcount"], table["is_all"],
                 table["masks"], pids)
-        out = []
-        for i in range(len(items)):
-            row = rows[i]
-            mk = row.tobytes()
-            names = memo.get(mk)
-            if names is None:
-                names = compiled.always | compiled._decode_mask(row)
-                if len(memo) >= _MEMO_CAP:
-                    memo.clear()
-                memo[mk] = names
-            out.append(names)
+        t_rows = 0
+        if backend == "jax":
+            rows, t_rows = _launch(
+                _jit_kernels()[1], args, len(items), t_tok, metrics)
+        else:
+            rows = _headers_kernel(np, *args)
+        with device.span("router.decode"):
+            out = []
+            for i in range(len(items)):
+                row = rows[i]
+                mk = row.tobytes()
+                names = memo.get(mk)
+                if names is None:
+                    names = compiled.always | compiled._decode_mask(row)
+                    if len(memo) >= _MEMO_CAP:
+                        memo.clear()
+                    memo[mk] = names
+                out.append(names)
+        if t_rows and metrics is not None:
+            metrics.router_decode_ns += time.perf_counter_ns() - t_rows
         return out
 
     raise Uncompilable(f"unknown exchange type {kind!r}")

@@ -312,59 +312,77 @@ class TensorRouter:
         metrics = self.broker.metrics
         vhost = self.broker.vhosts[vhost_name]
         out: list = [None] * len(entries)
-        # group by exchange: one compiled snapshot + one kernel call each
-        groups: dict[str, list[int]] = {}
-        for idx, entry in enumerate(entries):
-            groups.setdefault(entry[0], []).append(idx)
+        # the flush's own stretches carry the names route_batch gives its
+        # memo scan and its decode, so no part of a flush is unnamed in a
+        # profiler trace; route_batch writes the spans in between
+        with device.span("router.lookup"):
+            # group by exchange: one compiled snapshot + one kernel call each
+            groups: dict[str, list[int]] = {}
+            for idx, entry in enumerate(entries):
+                groups.setdefault(entry[0], []).append(idx)
         for exchange_name, idxs in groups.items():
-            compiled = self._get_compiled(vhost, vhost_name, exchange_name)
-            use_kernel = compiled is not None and (
-                compiled.kernel_rows == 0 or len(idxs) >= self.min_batch)
-            if not use_kernel:
-                # Python fallback: uncompilable table, or a batch too
-                # small to amortize the kernel dispatch. An e2e source
-                # falls back to the full graph walk, not the single-hop
-                # matcher — the closure IS the exchange's route set.
-                metrics.router_fallback_msgs += len(idxs)
-                exchange = vhost.exchanges[exchange_name]
-                if exchange.ex_matcher is not None:
-                    for idx in idxs:
-                        entry = entries[idx]
-                        names = frozenset(vhost.route(
-                            exchange_name, entry[1], entry[2].headers))
-                        out[idx] = self._queues(vhost_name, vhost, names)
-                else:
-                    matcher = exchange.matcher
-                    for idx in idxs:
-                        entry = entries[idx]
-                        names = frozenset(
-                            matcher.route(entry[1], entry[2].headers))
-                        out[idx] = self._queues(vhost_name, vhost, names)
-                continue
-            items = [(entries[i][1], entries[i][2].headers) for i in idxs]
+            with device.span("router.lookup"):
+                compiled = self._get_compiled(
+                    vhost, vhost_name, exchange_name)
+                use_kernel = compiled is not None and (
+                    compiled.kernel_rows == 0 or len(idxs) >= self.min_batch)
+                if not use_kernel:
+                    # Python fallback: uncompilable table, or a batch too
+                    # small to amortize the kernel dispatch. An e2e source
+                    # falls back to the full graph walk, not the single-hop
+                    # matcher — the closure IS the exchange's route set.
+                    metrics.router_fallback_msgs += len(idxs)
+                    exchange = vhost.exchanges[exchange_name]
+                    if exchange.ex_matcher is not None:
+                        for idx in idxs:
+                            entry = entries[idx]
+                            names = frozenset(vhost.route(
+                                exchange_name, entry[1], entry[2].headers))
+                            out[idx] = self._queues(vhost_name, vhost, names)
+                    else:
+                        matcher = exchange.matcher
+                        for idx in idxs:
+                            entry = entries[idx]
+                            names = frozenset(
+                                matcher.route(entry[1], entry[2].headers))
+                            out[idx] = self._queues(vhost_name, vhost, names)
+                    continue
+                items = [(entries[i][1], entries[i][2].headers)
+                         for i in idxs]
             name_sets = rcompile.route_batch(
                 compiled, items, self.backend, metrics)
-            if self.verify:
-                exchange = vhost.exchanges[exchange_name]
-                if exchange.ex_matcher is not None:
-                    # live oracle for a flattened closure is the runtime
-                    # graph walk itself
-                    def _oracle(k, h, _n=exchange_name):
-                        return vhost.route(_n, k, h)
-                else:
-                    _oracle = exchange.matcher.route
-                for pos, (key, headers) in enumerate(items):
-                    oracle = _oracle(key, headers)
-                    if set(name_sets[pos]) != oracle:
-                        metrics.router_parity_mismatches += 1
-                        log.error(
-                            "router parity mismatch on %s/%s key=%r: "
-                            "kernel=%r oracle=%r", vhost_name, exchange_name,
-                            key, sorted(name_sets[pos]), sorted(oracle))
-                        name_sets[pos] = frozenset(oracle)
-            metrics.router_batches += 1
-            metrics.router_batch_msgs += len(idxs)
-            metrics.router_batch_size.observe_us(len(idxs))
-            for idx, names in zip(idxs, name_sets):
-                out[idx] = self._queues(vhost_name, vhost, names)
-        return out, t0, time.perf_counter_ns()
+            with device.span("router.decode"):
+                if self.verify:
+                    self._verify(vhost, vhost_name, exchange_name, items,
+                                 name_sets)
+                metrics.router_batches += 1
+                metrics.router_batch_msgs += len(idxs)
+                metrics.router_batch_size.observe_us(len(idxs))
+                for idx, names in zip(idxs, name_sets):
+                    out[idx] = self._queues(vhost_name, vhost, names)
+        t1 = time.perf_counter_ns()
+        metrics.router_route_ns += t1 - t0
+        return out, t0, t1
+
+    def _verify(self, vhost, vhost_name: str, exchange_name: str,
+                items: list, name_sets: list) -> None:
+        """``chana.mq.router.verify``: every kernel answer against the live
+        oracle, the oracle preferred on a mismatch."""
+        metrics = self.broker.metrics
+        exchange = vhost.exchanges[exchange_name]
+        if exchange.ex_matcher is not None:
+            # live oracle for a flattened closure is the runtime
+            # graph walk itself
+            def _oracle(k, h, _n=exchange_name):
+                return vhost.route(_n, k, h)
+        else:
+            _oracle = exchange.matcher.route
+        for pos, (key, headers) in enumerate(items):
+            oracle = _oracle(key, headers)
+            if set(name_sets[pos]) != oracle:
+                metrics.router_parity_mismatches += 1
+                log.error(
+                    "router parity mismatch on %s/%s key=%r: "
+                    "kernel=%r oracle=%r", vhost_name, exchange_name,
+                    key, sorted(name_sets[pos]), sorted(oracle))
+                name_sets[pos] = frozenset(oracle)

@@ -54,6 +54,13 @@ class Histogram:
 
 
 class Metrics:
+    # the launch counters (see __init__), in the order every surface lists
+    ROUTER_LAUNCH = (
+        "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
+        "router_decode_ns", "router_kernel_keys", "router_kernel_rows",
+        "router_h2d_bytes", "router_route_ns",
+    )
+
     def __init__(self) -> None:
         self.published_msgs = 0
         self.published_bytes = 0
@@ -234,6 +241,24 @@ class Metrics:
         self.router_fallback_msgs = 0
         self.router_parity_mismatches = 0
         self.router_batch_size = Histogram()
+        # the launch from inside (router/compile.py route_batch, backend
+        # jax only; each advances once per jitted kernel call, so every
+        # ratio to router_kernel_launches is per device launch): wall ns
+        # of the tokenizer, of the jitted call until it returns (the
+        # arguments' DevicePuts and the enqueue), of np.asarray on the
+        # result (the loop blocked on the device and the copy back), of
+        # the mask decode and memo fill after it; rows that carry a real
+        # key or header set and rows after padding to the bucket; bytes of
+        # the host arrays handed to the call (an argument already on the
+        # device counts 0). Per flush: route_pending's whole window.
+        self.router_tokenize_ns = 0
+        self.router_dispatch_ns = 0
+        self.router_wait_ns = 0
+        self.router_decode_ns = 0
+        self.router_kernel_keys = 0
+        self.router_kernel_rows = 0
+        self.router_h2d_bytes = 0
+        self.router_route_ns = 0
         # native batch egress (native/chanamq_native.cpp): delivery
         # batches rendered by chana_encode_deliveries, the messages and
         # wire bytes they covered, pool-dry acquires that fell back to a
@@ -326,6 +351,11 @@ class Metrics:
     def delivered(self, nbytes: int) -> None:
         self.delivered_msgs += 1
         self.delivered_bytes += nbytes
+
+    def router_launch(self) -> dict:
+        """The launch counters by name: the same integers for
+        /admin/overview, the Prometheus list and /admin/profile."""
+        return {name: getattr(self, name) for name in self.ROUTER_LAUNCH}
 
     def histograms(self) -> "dict[str, Histogram]":
         """Every registered histogram, for cumulative Prometheus export."""
@@ -478,6 +508,7 @@ class Metrics:
             "router_batch_size_p50": self.router_batch_size.percentile_us(0.50),
             "router_batch_size_p99": self.router_batch_size.percentile_us(0.99),
             "router_batch_size_mean": self.router_batch_size.mean_us,
+            **self.router_launch(),
             "native_egress_batches": self.native_egress_batches,
             "native_egress_msgs": self.native_egress_msgs,
             "native_egress_bytes": self.native_egress_bytes,

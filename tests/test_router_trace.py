@@ -1,0 +1,321 @@
+"""The router launch measured from inside the program: the per-launch
+counters of `Metrics` (stamped in router/compile.py `_launch`), where they
+are served, the kernels' names, and `device.span` — what it costs a process
+without JAX, and what it writes into a real `jax.profiler` trace.
+"""
+
+import asyncio
+import glob
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from chanamq_tpu import device, native_ext, profile
+from chanamq_tpu.amqp.properties import BasicProperties
+from chanamq_tpu.broker.broker import Broker
+from chanamq_tpu.broker.server import BrokerServer
+from chanamq_tpu.client import AMQPClient
+from chanamq_tpu.profile.runtime import ProfileRuntime
+from chanamq_tpu.rest.admin import AdminServer
+from chanamq_tpu.router import compile as rcompile
+from chanamq_tpu.utils.metrics import Metrics
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+PER_LAUNCH = (
+    "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
+    "router_decode_ns", "router_kernel_keys", "router_kernel_rows",
+    "router_h2d_bytes")
+PER_FLUSH = ("router_route_ns",)
+SPANS = ("conn.ingress", "router.lookup", "router.tokenize", "router.decode",
+         "broker.enqueue", "conn.confirms")
+
+
+def test_the_registry_names_every_counter_once():
+    assert Metrics.ROUTER_LAUNCH == PER_LAUNCH + PER_FLUSH
+    snap = Metrics().snapshot()
+    assert all(snap[name] == 0 for name in Metrics.ROUTER_LAUNCH)
+
+
+def _broker(loop, kind: str) -> Broker:
+    broker = Broker()
+    run = loop.run_until_complete
+    run(broker.create_vhost("/"))
+    run(broker.declare_exchange("/", "ex", kind))
+    for queue in ("q1", "q2"):
+        run(broker.declare_queue("/", queue))
+    if kind == "topic":
+        run(broker.bind_queue("/", "q1", "ex", "a.*"))
+        run(broker.bind_queue("/", "q2", "ex", "#.z"))
+    else:
+        run(broker.bind_queue("/", "q1", "ex", "", {"x-match": "all", "k": 1}))
+        run(broker.bind_queue("/", "q2", "ex", "", {"x-match": "any", "k": 2}))
+    broker.router.min_batch = 1
+    return broker
+
+
+def _entries(kind: str, n: int, duplicates: int, tag: str = "") -> list:
+    """n rows for exchange `ex`, the last `duplicates` repeating the first
+    ones: routing keys for a topic exchange, header sets for headers."""
+    rows = []
+    for i in range(n):
+        j = i if i < n - duplicates else i - (n - duplicates)
+        if kind == "topic":
+            rows.append(("ex", f"a.k{tag}{j}", BasicProperties(), b"x", None,
+                         None, False))
+        else:
+            rows.append(("ex", "", BasicProperties(headers={"k": j % 3}),
+                         b"x", None, None, False))
+    return rows
+
+
+def _arg_bytes(broker: Broker, kind: str, real_rows: int) -> "tuple[int, int]":
+    """(bucket, bytes handed up) one launch of `real_rows` must count."""
+    compiled = broker.router._compiled[("/", "ex")]
+    b = rcompile._bucket(real_rows, 16)
+    if kind == "topic":
+        wild = compiled.wild
+        tables = [wild[k] for k in ("pre", "suf", "plen", "slen", "has_hash",
+                                    "masks")]
+        words = 4 * b * (wild["p"] + wild["s"] + 1)
+    else:
+        table = compiled.headers
+        tables = [table[k] for k in ("req", "rcount", "is_all", "masks")]
+        words = 4 * b * 2  # one header a message: the bucket's floor of 2
+    return b, sum(t.nbytes for t in tables) + words
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_counters_advance_once_a_launch_and_agree_with_the_batch(
+        event_loop, kind):
+    broker = _broker(event_loop, kind)
+    router, metrics = broker.router, broker.metrics
+    n, d = 20, 5
+    router.route_pending("/", _entries(kind, n, d))
+    # a topic launch carries each unseen key once; headers has no key memo
+    real = n - d if kind == "topic" else n
+    bucket, up = _arg_bytes(broker, kind, real)
+    assert metrics.router_kernel_launches == 1
+    assert metrics.router_kernel_keys == real
+    assert metrics.router_kernel_rows == bucket
+    assert metrics.router_h2d_bytes == up
+    first = metrics.router_launch()
+    assert all(first[name] > 0 for name in (
+        "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
+        "router_decode_ns", "router_route_ns"))
+    # the stamps nest: the four stages of the launch inside the flush
+    assert sum(first[name] for name in PER_LAUNCH[:4]) <= \
+        first["router_route_ns"]
+
+    router.route_pending("/", _entries(kind, n, d))
+    again = metrics.router_launch()
+    assert again["router_route_ns"] > first["router_route_ns"]
+    if kind == "topic":  # the key memo serves every position: no launch
+        assert metrics.router_kernel_launches == 1
+        assert all(again[name] == first[name] for name in PER_LAUNCH)
+    else:  # every headers flush reaches the kernel
+        assert metrics.router_kernel_launches == 2
+        assert again["router_kernel_keys"] == 2 * n
+        assert again["router_h2d_bytes"] == 2 * up
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_the_numpy_backend_advances_no_launch_counter(event_loop, kind):
+    broker = _broker(event_loop, kind)
+    broker.router.backend = "python"
+    broker.router.route_pending("/", _entries(kind, 20, 5))
+    counters = broker.metrics.router_launch()
+    assert broker.metrics.router_kernel_launches == 0
+    assert all(counters[name] == 0 for name in PER_LAUNCH)
+    assert counters["router_route_ns"] > 0  # the flush is timed all the same
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_the_profile_page_reads_the_counters_per_launch(event_loop, kind):
+    """`/admin/profile`'s `router` block is the program's own reader of the
+    launch counters: what one launch cost, by the same integers."""
+    broker = _broker(event_loop, kind)
+    rt = ProfileRuntime(metrics=broker.metrics, slow_callback_ms=0,
+                        gc_hook=False, broker=broker)
+    assert "per_launch" not in rt.snapshot()["router"]  # nothing launched
+    n, d = 20, 5
+    broker.router.route_pending("/", _entries(kind, n, d))
+    real = n - d if kind == "topic" else n
+    bucket, up = _arg_bytes(broker, kind, real)
+    block = rt.snapshot()["router"]
+    per = block["per_launch"]
+    assert block["kernel_launches"] == 1
+    assert per["keys"] == real and per["h2d_bytes"] == up
+    assert per["useful_row_pct"] == round(100.0 * real / bucket, 1)
+    for stage in ("tokenize", "dispatch", "wait", "decode"):
+        assert per[f"{stage}_us"] == round(
+            block[f"router_{stage}_ns"] / 1e3, 1)
+
+
+def test_the_kernels_have_names():
+    """The host event reads PjitFunction(topic_match), the module
+    jit_topic_match: a trace tells the two kernels apart."""
+    table = rcompile.compile_exchange("topic", [("a.*", "q", None)]).wild
+    topic, headers = rcompile._jit_kernels()
+    lowered = topic.lower(
+        table["pre"], table["suf"], table["plen"], table["slen"],
+        table["has_hash"], table["masks"],
+        *rcompile._tokenize_topic(table, ["a.b"], 16)).as_text()
+    assert "module @jit_topic_match" in lowered
+    table = rcompile.compile_exchange(
+        "headers", [("", "q", {"x-match": "all", "k": 1})]).headers
+    lowered = headers.lower(
+        table["req"], table["rcount"], table["is_all"], table["masks"],
+        rcompile._tokenize_headers(table, [{"k": 1}], 16)).as_text()
+    assert "module @jit_headers_match" in lowered
+
+
+async def _http(port: int, path: str) -> "tuple[int, bytes]":
+    reader, writer = await asyncio.open_connection("127.0.0.1", port)
+    writer.write(f"GET {path} HTTP/1.1\r\nHost: localhost\r\n\r\n".encode())
+    await writer.drain()
+    raw = await asyncio.wait_for(reader.read(-1), 5)  # the server closes
+    writer.close()
+    head, _, body = raw.partition(b"\r\n\r\n")
+    return int(head.split()[1]), body
+
+
+def test_the_admin_surfaces_carry_every_new_name(event_loop):
+    async def run():
+        broker = Broker()
+        rt = ProfileRuntime(metrics=broker.metrics, slow_callback_ms=0,
+                            gc_hook=False, broker=broker)
+        broker.profile = rt
+        admin = AdminServer(broker, port=0)
+        await admin.start()
+        try:
+            broker.metrics.router_dispatch_ns = 1234
+            status, body = await _http(admin.bound_port, "/admin/overview")
+            served = json.loads(body)["metrics"]
+            assert status == 200
+            assert set(Metrics.ROUTER_LAUNCH) <= set(served)
+            assert served["router_dispatch_ns"] == 1234
+            status, body = await _http(admin.bound_port, "/metrics")
+            text = body.decode()
+            for name in Metrics.ROUTER_LAUNCH:
+                assert f"# TYPE chanamq_{name} counter" in text
+            assert "chanamq_router_dispatch_ns 1234" in text
+            status, body = await _http(admin.bound_port, "/admin/profile")
+            block = json.loads(body)["router"]
+            assert set(block) == {"kernel_launches", *Metrics.ROUTER_LAUNCH}
+            assert block["router_dispatch_ns"] == 1234
+        finally:
+            await admin.stop()
+
+    assert profile.ACTIVE is None  # the block needs the ledger's page only
+    event_loop.run_until_complete(run())
+
+
+def test_span_without_a_device_never_imports_jax():
+    code = (
+        "import sys\n"
+        "from chanamq_tpu import device\n"
+        "from chanamq_tpu.broker.broker import Broker\n"
+        "broker = Broker(router_backend='python')\n"
+        "with device.span('router.lookup') as one:\n"
+        "    pass\n"
+        "assert device.span('conn.ingress') is device.span('router.decode')\n"
+        "assert device.claimed() is None\n"
+        "print('jax' in sys.modules)\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, capture_output=True,
+        text=True, timeout=120, env=dict(os.environ, PYTHONPATH=ROOT))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "False"
+
+
+def test_span_outside_a_profiler_session_is_the_shared_noop():
+    device.claim()
+    assert device.span("router.lookup") is device.span("conn.ingress")
+
+
+def _host_events(trace_dir: str) -> dict:
+    """thread line name -> [(name, start_ns, end_ns)] of /host:CPU."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    lines: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                lines.setdefault(line.name, []).append(
+                    (e.name, int(e.start_ns),
+                     int(e.start_ns) + int(e.duration_ns)))
+    return lines
+
+
+@pytest.mark.skipif(not native_ext.pipeline_available(),
+                    reason="the router batches only behind the native scan")
+def test_a_profiler_trace_holds_the_spans_flat_on_the_loops_line(
+        event_loop, tmp_path):
+    """A rehearsal of the benchmark's traced run on the CPU: a real
+    `jax.profiler` session, a flush driven through a real connection."""
+    import jax
+
+    async def drive(port: int) -> None:
+        c = await AMQPClient.connect("127.0.0.1", port)
+        ch = await c.channel()
+        await ch.exchange_declare("ex", "topic")
+        await ch.queue_declare("q1")
+        await ch.queue_bind("q1", "ex", "a.*.c")
+        got = []
+        await ch.basic_consume("q1", got.append, no_ack=True)
+        await ch.confirm_select()
+        for burst in range(3):
+            for i in range(64):
+                ch.basic_publish(b"m", exchange="ex",
+                                 routing_key=f"a.{burst}-{i}.c")
+            await ch.wait_unconfirmed_below(1)
+        for _ in range(200):
+            if len(got) == 192:
+                break
+            await asyncio.sleep(0.01)
+        assert len(got) == 192
+        await c.close()
+
+    async def run() -> None:
+        server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0)
+        await server.start()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            await drive(server.bound_port)
+        finally:
+            jax.profiler.stop_trace()
+            await server.stop()
+        assert server.broker.metrics.router_kernel_launches >= 1
+
+    event_loop.run_until_complete(run())
+    lines = _host_events(str(tmp_path))
+    every = [e for events in lines.values() for e in events]
+    # bare names: nothing of the `name#key=value#` form
+    assert not [e for e in every if e[0].startswith(SPANS) and "#" in e[0]]
+    ours = {name: [line for line, events in lines.items()
+                   if any(e[0] == name for e in events)] for name in SPANS}
+    loop_line = ours["router.tokenize"]
+    assert len(loop_line) == 1
+    assert all(found == loop_line for found in ours.values()), ours
+    # flat and disjoint: no program span starts before the last one ended,
+    # and JAX's two events of the launch lie between tokenize and decode
+    spans = sorted((e for e in lines[loop_line[0]] if e[0] in SPANS),
+                   key=lambda e: e[1])
+    for before, after in zip(spans, spans[1:]):
+        assert before[2] <= after[1], (before, after)
+    names = {e[0] for e in lines[loop_line[0]]}
+    assert "PjitFunction(topic_match)" in names
+    launch = next(e for e in lines[loop_line[0]]
+                  if e[0] == "PjitFunction(topic_match)")
+    assert not [s for s in spans if s[1] < launch[2] and launch[1] < s[2]]
