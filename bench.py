@@ -1418,6 +1418,7 @@ def run_route_spec(quick: bool = False) -> dict:
         for backend in ("jax", "python"):
             route_batch(compiled, items[:batch], backend)  # warm (jit)
             compiled._route_memo.clear()
+            compiled._mask_memo.clear()
             # cold: every key unseen, the all-miss tokenize+kernel path
             t0 = time.perf_counter()
             for i in range(0, len(uniq_items), batch):

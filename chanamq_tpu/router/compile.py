@@ -87,7 +87,7 @@ class CompiledExchange:
     """Immutable compiled snapshot of one exchange's binding table."""
 
     __slots__ = ("kind", "generation", "exact", "always", "bit_names",
-                 "wild", "headers", "_route_memo")
+                 "wild", "headers", "_route_memo", "_mask_memo")
 
     def __init__(self, kind: str, generation: int) -> None:
         self.kind = kind
@@ -100,10 +100,13 @@ class CompiledExchange:
         self.bit_names: tuple = ()
         self.wild: Optional[dict] = None      # topic wildcard tables
         self.headers: Optional[dict] = None   # headers-exchange tables
-        # bounded result memo: topic keys it by bare routing key (the
+        # bounded key memo, topic only: bare routing key -> names (the
         # match is a pure function of the key within this compiled
-        # generation), headers by the kernel's mask bytes
+        # generation)
         self._route_memo: dict = {}
+        # bounded mask memo, topic and headers: a kernel row's bytes ->
+        # always | the names of its bits, decoded once per distinct mask
+        self._mask_memo: dict = {}
 
     @property
     def kernel_rows(self) -> int:
@@ -118,14 +121,43 @@ class CompiledExchange:
     def _decode_mask(self, row: np.ndarray) -> frozenset:
         names = []
         bit_names = self.bit_names
-        for wi in range(row.shape[0]):
-            w = int(row[wi])
+        for wi, w in enumerate(row.tolist()):
             base = wi << 5
             while w:
                 low = w & -w
                 names.append(bit_names[base + low.bit_length() - 1])
                 w ^= low
         return frozenset(names)
+
+    def _decode_rows(self, rows: np.ndarray, n: int) -> tuple:
+        """The kernel's first ``n`` mask rows as ``always | names``, a
+        frozenset a row, and how many masks had to be decoded for it. One
+        ``tobytes`` for the launch; a row then costs a bytes slice and a
+        memo hit, an all-zero row not even that. Rows with one mask share
+        one frozenset, whose hash the queue cache computes once."""
+        width = rows.shape[1] * rows.itemsize
+        buf = rows[:n].tobytes()
+        zero = bytes(width)
+        memo = self._mask_memo
+        always = self.always or _EMPTY  # the one empty set, whoever built it
+        out = []
+        decoded = 0
+        for j, off in enumerate(range(0, n * width, width)):
+            mk = buf[off:off + width]
+            if mk == zero:
+                out.append(always)
+                continue
+            names = memo.get(mk)
+            if names is None:
+                names = self._decode_mask(rows[j])
+                if always:
+                    names = always | names
+                decoded += 1
+                if len(memo) >= _MEMO_CAP:
+                    memo.clear()
+                memo[mk] = names
+            out.append(names)
+        return out, decoded
 
 
 def compile_exchange(
@@ -483,7 +515,6 @@ def route_batch(
     if kind == "fanout":
         always = compiled.always
         return [always] * len(items)
-    memo = compiled._route_memo
     if kind == "direct":
         exact = compiled.exact
         return [exact.get(k, _EMPTY) for k, _ in items]
@@ -495,6 +526,7 @@ def route_batch(
         # message and only never-seen keys pay tokenize + kernel
         with device.span("router.lookup"):
             wild = compiled.wild
+            memo = compiled._route_memo
             out = [None] * len(items)
             miss: dict = {}  # unique unseen keys -> their positions
             for i, (key, _) in enumerate(items):
@@ -528,14 +560,18 @@ def route_batch(
         else:
             rows = _topic_kernel(np, *args)
         with device.span("router.decode"):
-            for j, key in enumerate(uniq):
-                names = (compiled.exact.get(key, _EMPTY) | compiled.always
-                         | compiled._decode_mask(rows[j]))
+            masked, decoded = compiled._decode_rows(rows, len(uniq))
+            exact = compiled.exact.get
+            for (key, idxs), names in zip(miss.items(), masked):
+                hit = exact(key)
+                if hit is not None:
+                    names = hit | names if names else hit
                 memo[key] = names
-                for i in miss[key]:
+                for i in idxs:
                     out[i] = names
         if t_rows and metrics is not None:
             metrics.router_decode_ns += time.perf_counter_ns() - t_rows
+            metrics.router_mask_decodes += decoded
         return out
 
     if kind == "headers":
@@ -555,19 +591,10 @@ def route_batch(
         else:
             rows = _headers_kernel(np, *args)
         with device.span("router.decode"):
-            out = []
-            for i in range(len(items)):
-                row = rows[i]
-                mk = row.tobytes()
-                names = memo.get(mk)
-                if names is None:
-                    names = compiled.always | compiled._decode_mask(row)
-                    if len(memo) >= _MEMO_CAP:
-                        memo.clear()
-                    memo[mk] = names
-                out.append(names)
+            out, decoded = compiled._decode_rows(rows, len(items))
         if t_rows and metrics is not None:
             metrics.router_decode_ns += time.perf_counter_ns() - t_rows
+            metrics.router_mask_decodes += decoded
         return out
 
     raise Uncompilable(f"unknown exchange type {kind!r}")

@@ -58,7 +58,7 @@ class Metrics:
     ROUTER_LAUNCH = (
         "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
         "router_decode_ns", "router_kernel_keys", "router_kernel_rows",
-        "router_h2d_bytes", "router_route_ns",
+        "router_h2d_bytes", "router_mask_decodes", "router_route_ns",
     )
 
     def __init__(self) -> None:
@@ -250,7 +250,8 @@ class Metrics:
         # the mask decode and memo fill after it; rows that carry a real
         # key or header set and rows after padding to the bucket; bytes of
         # the host arrays handed to the call (an argument already on the
-        # device counts 0). Per flush: route_pending's whole window.
+        # device counts 0); rows whose mask the mask memo did not hold and
+        # Python had to decode. Per flush: route_pending's whole window.
         self.router_tokenize_ns = 0
         self.router_dispatch_ns = 0
         self.router_wait_ns = 0
@@ -258,6 +259,7 @@ class Metrics:
         self.router_kernel_keys = 0
         self.router_kernel_rows = 0
         self.router_h2d_bytes = 0
+        self.router_mask_decodes = 0
         self.router_route_ns = 0
         # native batch egress (native/chanamq_native.cpp): delivery
         # batches rendered by chana_encode_deliveries, the messages and

@@ -10,6 +10,7 @@ generated bind/unbind/route sequences, including ``#`` edge cases."""
 import asyncio
 import random
 
+import numpy as np
 import pytest
 
 from chanamq_tpu import native_ext
@@ -192,6 +193,155 @@ def test_multi_hash_uncompilable_and_caps():
     got = _route_all_backends(
         compiled, [("exact.7", None), ("wild.x", None), ("nope", None)])
     assert [set(g) for g in got] == [{"q7"}, {"qw"}, set()]
+
+
+# ---------------------------------------------------------------------------
+# the batch mask decode: once per distinct mask, not once per key
+# ---------------------------------------------------------------------------
+
+
+def _wide_table(kind: str, words: int, rng):
+    """A table whose destination mask is `words` uint32 wide, its last word
+    part filled, a non-empty `always`, and a batch for it with duplicates,
+    rows that match nothing and (topic) keys that hit an exact pattern and
+    a wildcard row at once. Returns (oracle matcher, compiled, items)."""
+    queues = [f"q{i:04d}" for i in range(32 * words - 3)]
+    if kind == "topic":
+        m = TopicMatcher()
+        patterns = [(f"w{j}.#", f"t{j % 7}.*.s{j}", f"#.z{j}",
+                     f"*.k{j}.#")[j % 4] for j in range(64)]
+        for i, queue in enumerate(queues):
+            m.bind(patterns[i % 64], queue)
+        m.bind("#", "qall")
+        for j in range(8):      # exact patterns a wildcard row matches too
+            m.bind(f"w{j}.k{j}", f"qx{j}")
+            m.bind(f"alone.{j}", f"qx{j}")
+        keys = [rng.choice((f"w{j}.k{j}", f"w{j}.u.v", f"t{j % 7}.x.s{j}",
+                            f"m.n.z{j}", f"x.k{j}", f"alone.{j % 8}",
+                            f"miss.{j}", ""))
+                for j in (rng.randrange(64) for _ in range(48))]
+        keys += [f"w{j}.k{j}" for j in range(8)]
+        items = [(key, None) for key in keys]
+    else:
+        m = HeadersMatcher()
+        for i, queue in enumerate(queues):
+            m.bind("", queue, {"x-match": ("all", "any")[i % 2],
+                               f"h{i % 5}": i % 11, "g": i % 3})
+        m.bind("", "qall", {"x-match": "all"})
+        items = [("", {f"h{rng.randrange(6)}": rng.randrange(12),
+                       "g": rng.randrange(4)}) for _ in range(12)]
+        items += items[:4] + [("", None), ("", {"nope": 1})]
+    compiled = compile_exchange(kind, m.bindings(), max_wildcards=4096)
+    return m, compiled, items
+
+
+def _kernel_rows(compiled, items):
+    """The numpy twin's mask rows for `items`, padded to the bucket."""
+    b = rcompile._bucket(len(items), 16)
+    if compiled.kind == "topic":
+        t = compiled.wild
+        return rcompile._topic_kernel(
+            np, t["pre"], t["suf"], t["plen"], t["slen"],
+            t["has_hash"], t["masks"],
+            *rcompile._tokenize_topic(t, [k for k, _ in items], b))
+    t = compiled.headers
+    return rcompile._headers_kernel(
+        np, t["req"], t["rcount"], t["is_all"], t["masks"],
+        rcompile._tokenize_headers(t, [h for _, h in items], b))
+
+
+@pytest.mark.parametrize("words", [1, 16, 128])
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_batch_mask_decode_equals_the_row_decode(kind, words):
+    rng = random.Random(words * 31 + len(kind))
+    m, compiled, items = _wide_table(kind, words, rng)
+    table = compiled.wild if kind == "topic" else compiled.headers
+    assert table["mask_words"] == words and compiled.always == {"qall"}
+    n = len(items)
+    rows = _kernel_rows(compiled, items).copy()
+    assert rows.shape == (rcompile._bucket(n, 16), words) and rows.shape[0] > n
+    # rows past n are padding: set every bit, so that decoding one would
+    # look up a queue the table does not have
+    rows[n:] = 0xFFFFFFFF
+    with pytest.raises(IndexError):
+        compiled._decode_mask(rows[n])
+    by_row = [compiled.always | compiled._decode_mask(rows[j])
+              for j in range(n)]
+    zero = [j for j in range(n) if not rows[j].any()]
+    distinct = {rows[j].tobytes() for j in range(n)} - {bytes(4 * words)}
+    assert zero and len(distinct) > 1
+    got, decoded = compiled._decode_rows(rows, n)
+    assert got == by_row and decoded == len(distinct)
+    assert set(compiled._mask_memo) == distinct
+    assert all(got[j] is compiled.always for j in zero)
+    again, decoded = compiled._decode_rows(rows, n)
+    assert decoded == 0 and all(a is b for a, b in zip(again, got))
+    # and the whole route: exact | always | mask names, against the matcher
+    routed = route_batch(compiled, items, "python")
+    assert len(routed) == n
+    for (key, headers), names in zip(items, routed):
+        assert names == m.route(key, headers), (key, headers)
+    if kind == "topic":
+        # w0.k0: an exact pattern, the row w0.# and the lone '#' at once
+        at_once = routed[items.index(("w0.k0", None))]
+        assert {"qx0", "q0000", "qall"} <= at_once
+
+
+def test_mask_memo_is_capped_and_answers_stay_equal():
+    """More distinct masks than `_MEMO_CAP`: the memo is cleared, never
+    grows past the cap, and every answer still equals the matcher's."""
+    m = HeadersMatcher()
+    for i in range(14):
+        m.bind("", f"q{i}", {"x-match": "any", f"h{i}": 1})
+    compiled = compile_exchange("headers", m.bindings())
+    rng = random.Random(14)
+    subsets = rng.sample(range(1, 1 << 14), rcompile._MEMO_CAP + 900)
+    msgs = [{f"h{i}": 1 for i in range(14) if bits >> i & 1}
+            for bits in subsets]
+    seen = 0
+    for start in range(0, len(msgs), 1024):
+        chunk = msgs[start:start + 1024]
+        got = route_batch(compiled, [("", h) for h in chunk], "python")
+        for headers, names in zip(chunk, got):
+            assert names == m.route("", headers)
+        assert len(compiled._mask_memo) <= rcompile._MEMO_CAP
+        seen += len(chunk)
+    assert seen > rcompile._MEMO_CAP > len(compiled._mask_memo)
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_both_backends_decode_one_batch_to_identical_sets(kind):
+    m, compiled, items = _wide_table(kind, 16, random.Random(5))
+    py = route_batch(compiled, items, "python")
+    compiled._route_memo.clear()
+    compiled._mask_memo.clear()
+    jx = route_batch(compiled, items, "jax")
+    assert compiled._mask_memo  # the jit kernel's rows were decoded anew
+    assert py == jx
+    assert all(type(names) is frozenset for names in py + jx)
+
+
+def test_keys_with_one_mask_share_one_frozenset():
+    """No exact hit and no `always`: the mask memo's own frozenset is the
+    answer for every key that carries the mask, so the engine's queue
+    cache hashes it once; an all-zero row is the one empty set."""
+    m = TopicMatcher()
+    m.bind("a.*", "q1")
+    m.bind("a.#", "q2")
+    m.bind("a.b", "q3")
+    compiled = compile_exchange("topic", m.bindings())
+    first = route_batch(compiled, [("a.x", None), ("a.y", None),
+                                   ("a.b", None), ("zz", None)])
+    assert first[0] is first[1] and first[0] == {"q1", "q2"}
+    assert first[2] == {"q1", "q2", "q3"} and first[2] is not first[0]
+    assert first[3] is rcompile._EMPTY
+    later = route_batch(compiled, [("a.z", None), ("yy", None)])
+    assert later[0] is first[0] and later[1] is rcompile._EMPTY
+    # an exact hit on a row that matches nothing else is the exact set
+    m.bind("solo", "q4")
+    compiled = compile_exchange("topic", m.bindings())
+    got = route_batch(compiled, [("solo", None)])
+    assert got[0] is compiled.exact["solo"]
 
 
 # ---------------------------------------------------------------------------

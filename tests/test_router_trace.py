@@ -28,7 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PER_LAUNCH = (
     "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
     "router_decode_ns", "router_kernel_keys", "router_kernel_rows",
-    "router_h2d_bytes")
+    "router_h2d_bytes", "router_mask_decodes")
 PER_FLUSH = ("router_route_ns",)
 SPANS = ("conn.ingress", "router.lookup", "router.tokenize", "router.decode",
          "broker.enqueue", "conn.confirms")
@@ -122,6 +122,46 @@ def test_counters_advance_once_a_launch_and_agree_with_the_batch(
         assert again["router_h2d_bytes"] == 2 * up
 
 
+def _masked(kind: str, marks: list, tag: str = "") -> list:
+    """One row a mark for `_broker`'s table: 1 reaches q1, 2 reaches q2,
+    3 both (topic only), 0 neither; every topic key but 3's is distinct."""
+    rows = []
+    for i, mark in enumerate(marks):
+        if kind == "topic":  # a.* -> q1, #.z -> q2
+            key = (f"m.n{tag}{i}", f"a.k{tag}{i}", f"m{tag}{i}.z", "a.z")[mark]
+            props = BasicProperties()
+        else:
+            key, props = "", BasicProperties(headers={"k": mark})
+        rows.append(("ex", key, props, b"x", None, None, False))
+    return rows
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_mask_decodes_count_the_distinct_new_masks_of_a_launch(
+        event_loop, kind):
+    broker = _broker(event_loop, kind)
+    router, metrics = broker.router, broker.metrics
+    first = _masked(kind, [1, 1, 0, 1, 0])
+    router.route_pending("/", first)
+    assert metrics.router_kernel_launches == 1
+    assert metrics.router_mask_decodes == 1  # three rows, one mask; 0 is free
+    # the same batch again, the key memo gone: a launch, nothing to decode
+    compiled = router._compiled[("/", "ex")]
+    compiled._route_memo.clear()
+    router.route_pending("/", first)
+    assert metrics.router_kernel_launches == 2
+    assert metrics.router_mask_decodes == 1
+    # unseen masks only: q2's, and for topic q1+q2's
+    marks = [2, 1, 2, 0] + ([3] if kind == "topic" else [])
+    router.route_pending("/", _masked(kind, marks, "later"))
+    assert metrics.router_kernel_launches == 3
+    assert metrics.router_mask_decodes == (3 if kind == "topic" else 2)
+    assert len(compiled._mask_memo) == metrics.router_mask_decodes
+    assert Metrics().snapshot()["router_mask_decodes"] == 0
+    assert broker.metrics.snapshot()["router_mask_decodes"] == \
+        metrics.router_mask_decodes
+
+
 @pytest.mark.parametrize("kind", ["topic", "headers"])
 def test_the_numpy_backend_advances_no_launch_counter(event_loop, kind):
     broker = _broker(event_loop, kind)
@@ -153,6 +193,11 @@ def test_the_profile_page_reads_the_counters_per_launch(event_loop, kind):
     for stage in ("tokenize", "dispatch", "wait", "decode"):
         assert per[f"{stage}_us"] == round(
             block[f"router_{stage}_ns"] / 1e3, 1)
+    # every topic key reaches q1 alone; the header sets reach q1, q2 or
+    # nothing, and a row that reaches nothing is never decoded
+    masks = 1 if kind == "topic" else 2
+    assert block["router_mask_decodes"] == masks
+    assert per["mask_memo_hit_pct"] == round(100.0 * (1 - masks / real), 1)
 
 
 def test_the_kernels_have_names():
@@ -203,6 +248,7 @@ def test_the_admin_surfaces_carry_every_new_name(event_loop):
             for name in Metrics.ROUTER_LAUNCH:
                 assert f"# TYPE chanamq_{name} counter" in text
             assert "chanamq_router_dispatch_ns 1234" in text
+            assert "# TYPE chanamq_router_mask_decodes counter" in text
             status, body = await _http(admin.bound_port, "/admin/profile")
             block = json.loads(body)["router"]
             assert set(block) == {"kernel_launches", *Metrics.ROUTER_LAUNCH}
