@@ -19,6 +19,9 @@ late it writes every thread's Python stack to DIR/stalls.txt while the loop
 is still stuck, and afterwards how long the answer took: a run that reads
 low says where the loop was (PERF.md 6, "stalls").
 
+The log opens with the arguments the broker was started with and, where a
+configuration states broker options, the --config file that holds them.
+
 Each handler only starts a thread: a Python signal handler runs between two
 bytecodes of whatever the loop is doing, possibly inside JAX, and must not
 call into it from there.
@@ -163,6 +166,14 @@ def main() -> None:
     os.makedirs(args.control, exist_ok=True)
     TraceControl(args.control).install()
     server_args = [a for a in args.server_args if a != "--"]
+    # the broker's log opens with what it was started with: its arguments
+    # and, where a configuration states broker options, the file of them
+    print(f"broker_launch: server arguments {server_args}", file=sys.stderr)
+    if "--config" in server_args:
+        with open(server_args[server_args.index("--config") + 1],
+                  encoding="utf-8") as f:
+            print(f"broker_launch: --config holds {json.load(f)}",
+                  file=sys.stderr, flush=True)
     threading.Thread(
         target=watch_stalls, daemon=True, args=(
             args.control,

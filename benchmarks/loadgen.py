@@ -23,10 +23,16 @@ CLOCK_MONOTONIC ns (one clock for every process of the host) and 4 bytes of
 stream position. It starts as soon as it is connected — that is the
 warm-up — and stops at --end-ns; the window opens at --start-ns.
 
-A consumer subscribes `no_ack` to its share of the queues, records (queue,
-position, sent, received) of every delivery, answers `count` on its
-standard input with how many it has, and on `stop` writes them to
+A consumer subscribes to its share of the queues, records (queue, position,
+sent, received) of every delivery, answers `count` on its standard input
+with how many it has, and on `stop` writes them to
 <out>/consumer-<index>.npz and ends.
+
+What the configuration's `applied` section states (reference.applied) is
+applied here: `delivery_mode` on every publish's properties; `consumer_ack`
+as basic.qos and manual acks, one every `multiple_every` deliveries of the
+channel and one last at `stop`. Without the section a publish carries the
+pool entry's headers or no properties at all, and consumers are `no_ack`.
 
 Last stdout line of either: one JSON object for the parent.
 """
@@ -64,8 +70,12 @@ async def producer(args, cfg: dict, mix: dict) -> dict:
     pool = reference.build_pool(cfg, table, mix)
     draws = reference.stream_draws(mix, args.seed)
     keys = [key for key, _ in pool]
-    props = [BasicProperties(headers=h) if h is not None else None
-             for _, h in pool]
+    # without a delivery_mode a message without headers carries no
+    # properties at all
+    mode = reference.applied(cfg)["delivery_mode"]
+    bare = BasicProperties(delivery_mode=mode) if mode is not None else None
+    props = [BasicProperties(headers=h, delivery_mode=mode)
+             if h is not None else bare for _, h in pool]
     exchange = table["exchange"]
     step, window, refill = (
         mix["producers"], mix["confirm_window"], mix["refill_below"])
@@ -171,19 +181,31 @@ async def consumer(args, cfg: dict, mix: dict) -> dict:
     received = array.array("q")
     now_ns = time.monotonic_ns
     unpack = BODY.unpack
+    ack = reference.applied(cfg)["consumer_ack"]
+    every = ack["multiple_every"] if ack else 0
+    acks = owed = last_tag = 0
 
     def on_message(msg) -> None:
+        nonlocal acks, owed, last_tag
         got = now_ns()
         stamp, seq = unpack(msg.body)
         pairs.append((queue_id[msg.consumer_tag] << 32) | seq)
         sent.append(stamp)
         received.append(got)
+        if every:
+            last_tag = msg.delivery_tag
+            owed += 1
+            if owed >= every:
+                ch.basic_ack(last_tag, multiple=every > 1)
+                acks, owed = acks + 1, 0
 
     conn = await AMQPClient.connect("127.0.0.1", args.port)
     ch = await conn.channel()
+    if ack:
+        await ch.basic_qos(prefetch_count=ack["prefetch"])
     for queue in mine:
         await ch.basic_consume(queue, on_message, consumer_tag=queue,
-                               no_ack=True)
+                               no_ack=not ack)
     loop = asyncio.get_running_loop()
     stop = asyncio.Event()
 
@@ -198,6 +220,10 @@ async def consumer(args, cfg: dict, mix: dict) -> dict:
     threading.Thread(target=commands, daemon=True).start()
     print(json.dumps({"ready": len(mine)}), flush=True)
     await stop.wait()
+    if owed:  # the last ack: nothing this consumer holds stays unsettled
+        ch.basic_ack(last_tag, multiple=True)
+        acks, owed = acks + 1, 0
+        await conn.drain()
     await conn.close()
     path = os.path.join(args.out, f"consumer-{args.index}.npz")
     np.savez(path,
@@ -205,7 +231,7 @@ async def consumer(args, cfg: dict, mix: dict) -> dict:
              sent=np.frombuffer(sent, dtype=np.int64),
              received=np.frombuffer(received, dtype=np.int64))
     return {"role": "consumer", "index": args.index, "queues": len(mine),
-            "delivered": len(pairs), "file": path}
+            "delivered": len(pairs), "acks": acks, "file": path}
 
 
 def main() -> None:

@@ -12,7 +12,10 @@ roofline_share    100 x least time for the launches' work / their op time;
                   does not count those, so the share reads high by the
                   memo's and the duplicates' part of the message bytes
                   (fresh keys: about 7% of the messages, 5% of the share).
-                  A cell the memo serves does not list the metric
+                  A cell the memo serves does not list the metric.
+                  Over a graph of exchanges it reads nothing: the rows
+                  that reach the kernel are those of the flattened closure,
+                  which roofline.py does not count yet
 
 Off the TPU (a rehearsal under JAX_PLATFORMS=cpu) it reads nothing: a number
 from a CPU run is never written under the name of a device metric.
@@ -37,6 +40,8 @@ def read(params: dict, ctx: dict):
     if what == "op_us_per_launch":
         return 1e6 * trace["device_op_s"] / trace["launches"]
     if what == "roofline_share":
+        if ctx["table"].get("exchange_bindings"):
+            return None
         peak = ctx["peaks"][ctx["device_kind"]]
         msgs = delta(ctx, "span", params["msgs"]) / trace["launches"]
         least = roofline.least_seconds(roofline.launch_bytes(

@@ -3,14 +3,22 @@ reach, the seeded message stream, the comparison that decides `correct`, its
 controls, and the percentile/rate arithmetic.
 
 Nothing here imports the program (`chanamq_tpu`) or jax. The matchers are the
-AMQP 0-9-1 definitions written out: a topic pattern is matched word by word
-(`*` exactly one word, `#` zero or more), a headers binding by `x-match`
-all/any over its arguments. `topic_matches` / `headers_matches` are the
-one-pattern-one-message definitions; `expected_sets` evaluates a whole pool
-against a whole table with numpy, one binding at a time over all messages
-(65,536 keys x 10,512 patterns is minutes of set-up in a Python double
-loop), and the tests hold the two to each other and to the program's
-matchers.
+AMQP 0-9-1 definitions written out: a direct binding matches the identical
+routing key, a fanout binding everything, a topic pattern is matched word by
+word (`*` exactly one word, `#` zero or more), a headers binding by `x-match`
+all/any over its arguments; over exchange-to-exchange bindings a message is
+walked breadth first from the exchange it was published to, every hop
+matched against its ORIGINAL routing key and headers (RabbitMQ's e2e
+semantics). `*_matches` are the one-binding-one-message definitions and
+`expected_sets_plain` the walk written out; `expected_sets` evaluates a whole
+pool against a whole table with numpy, one binding at a time over all
+messages (65,536 keys x 10,512 patterns is minutes of set-up in a Python
+double loop), and the tests hold the two to each other and to the program's
+matchers and graph walk.
+
+`applied` resolves what else a configuration file states of its deployment
+(durability, consumer acknowledgements, broker options) with its defaults,
+for run.py and loadgen.py alike.
 """
 
 from __future__ import annotations
@@ -54,6 +62,42 @@ def load_traffic(name: str, scale: str = "full") -> dict:
     return mix
 
 
+APPLIED_DEFAULTS = {"durable": False, "delivery_mode": None,
+                    "consumer_ack": None, "broker_options": {}}
+
+
+def applied(cfg: dict) -> dict:
+    """The configuration's `applied` section, every key present. Absent,
+    each means what every run did before the section existed:
+
+    durable         false: exchanges and queues are declared transient
+    delivery_mode   null: the property is not sent (a topic publish carries
+                    no properties at all); 2 marks every publish persistent
+    consumer_ack    null: consumers subscribe `no_ack`; {"prefetch": n,
+                    "multiple_every": k}: basic.qos(prefetch_count=n),
+                    manual acks, one every k deliveries of a channel (with
+                    `multiple` when k > 1) and one last at `stop`
+    broker_options  {}: the broker starts with no option; `chana.mq.*` keys
+                    reach it as a --config file
+    """
+    stated = cfg.get("applied", {})
+    unknown = set(stated) - set(APPLIED_DEFAULTS)
+    if unknown:
+        raise ValueError(f"unknown keys in `applied`: {sorted(unknown)}")
+    out = dict(APPLIED_DEFAULTS, **stated)
+    if out["delivery_mode"] not in (None, 1, 2):
+        raise ValueError(f"delivery_mode {out['delivery_mode']!r}")
+    ack = out["consumer_ack"]
+    if ack is not None and (
+            set(ack) != {"prefetch", "multiple_every"}
+            or ack["prefetch"] < 0 or ack["multiple_every"] < 1):
+        raise ValueError(f"consumer_ack {ack!r}")
+    stray = [k for k in out["broker_options"] if not k.startswith("chana.mq.")]
+    if stray:
+        raise ValueError(f"broker_options that are no chana.mq.* key: {stray}")
+    return out
+
+
 def table_module(cfg: dict):
     """benchmarks/tables/<generator>.py: `table(params)` and
     `pool(params, table, n, rng)` for one shape of deployment."""
@@ -62,7 +106,12 @@ def table_module(cfg: dict):
 
 def build_table(cfg: dict) -> dict:
     """{"exchange", "type", "queues": [names], "bindings": [(key, queue,
-    args-or-None)]} — a pure function of the configuration file."""
+    args-or-None)]} — a pure function of the configuration file. A graph
+    adds "exchanges": [(name, type)] (every exchange, the published one
+    among them), "queue_bindings": [(exchange, queue, key, args)] for queues
+    bound to the other exchanges, and "exchange_bindings": [(source,
+    destination, key, args)]; `exchange` stays the one published to and
+    `bindings` its own."""
     return table_module(cfg).table(cfg["table"])
 
 
@@ -113,6 +162,16 @@ def stream_draws(mix: dict, seed: int) -> np.ndarray:
 # -- the definitions -----------------------------------------------------------
 
 
+def direct_matches(binding_key: str, key: str) -> bool:
+    """AMQP direct match: the binding's key is the routing key."""
+    return binding_key == key
+
+
+def fanout_matches() -> bool:
+    """AMQP fanout match: every binding takes every message."""
+    return True
+
+
 def topic_matches(pattern: str, key: str) -> bool:
     """AMQP topic match of one pattern against one routing key."""
     pat, words = pattern.split("."), key.split(".")
@@ -142,16 +201,62 @@ def headers_matches(args: dict, headers: "dict | None") -> bool:
     return all(hits)
 
 
+def binding_matches(kind: str, binding_key: str, args: "dict | None",
+                    key: str, headers: "dict | None") -> bool:
+    """One binding of an exchange of type `kind` against one message."""
+    if kind == "direct":
+        return direct_matches(binding_key, key)
+    if kind == "fanout":
+        return fanout_matches()
+    if kind == "topic":
+        return topic_matches(binding_key, key)
+    if kind == "headers":
+        return headers_matches(args or {}, headers)
+    raise ValueError(f"no reference matcher for a {kind!r} exchange")
+
+
+def table_exchanges(table: dict) -> list:
+    """[(name, type)] of every exchange of a table: a table that lists none
+    is its one exchange."""
+    return table.get("exchanges", [(table["exchange"], table["type"])])
+
+
+def exchange_graph(table: dict) -> dict:
+    """{exchange: (type, [(key, queue, args)], [(key, destination, args)])}."""
+    graph = {name: (kind, [], []) for name, kind in table_exchanges(table)}
+    graph[table["exchange"]][1].extend(table["bindings"])
+    for exchange, queue, key, args in table.get("queue_bindings", []):
+        graph[exchange][1].append((key, queue, args))
+    for source, destination, key, args in table.get("exchange_bindings", []):
+        graph[source][2].append((key, destination, args))
+    return graph
+
+
 def expected_sets_plain(table: dict, pool: list) -> list:
-    """The definitions applied pair by pair (tests and small tables)."""
+    """The definitions applied pair by pair (tests and small tables): each
+    message walked breadth first from the published exchange, every hop
+    matched against its original key and headers, an exchange visited once
+    (so a cycle ends), a destination that is not declared leading nowhere,
+    a queue reached by several paths counted once."""
+    graph = exchange_graph(table)
     out = []
     for key, headers in pool:
-        if table["type"] == "topic":
-            out.append(frozenset(q for pat, q, _ in table["bindings"]
-                                 if topic_matches(pat, key)))
-        else:
-            out.append(frozenset(q for _, q, args in table["bindings"]
-                                 if headers_matches(args or {}, headers)))
+        queues: set = set()
+        visited: set = set()
+        frontier = [table["exchange"]]
+        while frontier:
+            hop = []
+            for name in frontier:
+                if name in visited or name not in graph:
+                    continue
+                visited.add(name)
+                kind, to_queues, to_exchanges = graph[name]
+                queues.update(q for k, q, args in to_queues
+                              if binding_matches(kind, k, args, key, headers))
+                hop.extend(d for k, d, args in to_exchanges
+                           if binding_matches(kind, k, args, key, headers))
+            frontier = hop
+        out.append(frozenset(queues))
     return out
 
 
@@ -273,25 +378,63 @@ class Expected:
         return out
 
 
+def _hits(kind: str, bindings: list, pool: list,
+          id_bits: "int | None") -> "list[np.ndarray]":
+    """For each (key, target, args) binding of one exchange of type `kind`
+    the indexes of the pool entries it matches."""
+    if kind == "topic":
+        return _topic_hits([b[0] for b in bindings], [p[0] for p in pool],
+                           id_bits)
+    if kind == "headers":
+        return _headers_hits(bindings, pool, id_bits)
+    if kind == "fanout":
+        return [np.arange(len(pool))] * len(bindings)
+    if kind == "direct":
+        def name(key):
+            return key if id_bits is None else _narrow(key, id_bits)
+        position: dict = {}
+        for i, (key, _) in enumerate(pool):
+            position.setdefault(name(key), []).append(i)
+        none: list = []
+        return [np.array(position.get(name(b[0]), none), dtype=np.int64)
+                for b in bindings]
+    raise ValueError(f"no reference matcher for a {kind!r} exchange")
+
+
 def expected_sets(table: dict, pool: list,
                   id_bits: "int | None" = None) -> Expected:
     """What the reference says every pool entry reaches. `id_bits` is the
-    control's matcher (see `control_pairs`)."""
+    control's matcher (see `control_pairs`). Over a graph, `at[exchange]`
+    marks the entries that reach each exchange: it starts as all of them at
+    the published exchange and grows along the exchange bindings until
+    nothing moves, which is the breadth-first walk of every entry at once
+    (what a hop matches depends on the message alone, never on its path)."""
     queue_id = {q: i for i, q in enumerate(table["queues"])}
-    bindings = table["bindings"]
-    kind = table["type"]
-    if kind == "topic":
-        hits = _topic_hits([b[0] for b in bindings], [p[0] for p in pool],
-                           id_bits)
-    elif kind == "headers":
-        hits = _headers_hits(bindings, pool, id_bits)
-    else:
-        raise ValueError(f"no reference matcher for a {kind!r} exchange")
     n_queues = len(queue_id)
-    reach = np.unique(np.concatenate(
-        [hit * n_queues + queue_id[queue]
-         for (_, queue, _), hit in zip(bindings, hits)]
-        + [np.zeros(0, dtype=np.int64)]))
+    graph = exchange_graph(table)
+    at = {name: np.zeros(len(pool), dtype=bool) for name in graph}
+    at[table["exchange"]][:] = True
+    hops = [(source, destination, hit)
+            for source, (kind, _, to_exchanges) in graph.items()
+            for (_, destination, _), hit in zip(
+                to_exchanges, _hits(kind, to_exchanges, pool, id_bits))
+            if destination in graph]
+    moved = True
+    while moved:
+        moved = False
+        for source, destination, hit in hops:
+            new = hit[at[source][hit] & ~at[destination][hit]]
+            if new.size:
+                at[destination][new] = moved = True
+    reach = [np.zeros(0, dtype=np.int64)]
+    for name, (kind, to_queues, _) in graph.items():
+        here = None if at[name].all() else at[name]
+        for (_, queue, _), hit in zip(
+                to_queues, _hits(kind, to_queues, pool, id_bits)):
+            if here is not None:
+                hit = hit[here[hit]]
+            reach.append(hit * n_queues + queue_id[queue])
+    reach = np.unique(np.concatenate(reach))
     entry, queues = reach // n_queues, reach % n_queues
     offsets = np.zeros(len(pool) + 1, dtype=np.int64)
     np.cumsum(np.bincount(entry, minlength=len(pool)), out=offsets[1:])
@@ -301,8 +444,12 @@ def expected_sets(table: dict, pool: list,
 # -- the comparison ------------------------------------------------------------
 
 # every number compared is a count of broken promises; the configuration
-# states exactly-once to exactly the bound queues, so every limit is 0
-LIMITS = {"unconfirmed": 0, "missing": 0, "unexpected": 0, "duplicates": 0}
+# states exactly-once to exactly the bound queues, so every limit is 0.
+# `unsettled` is compared only where the configuration's consumers
+# acknowledge: the messages the broker still holds, ready or unacked, after
+# the consumers' last ack
+LIMITS = {"unconfirmed": 0, "missing": 0, "unexpected": 0, "duplicates": 0,
+          "unsettled": 0}
 
 
 def compare(expected_pairs: np.ndarray, delivered_pairs: np.ndarray,
@@ -328,12 +475,12 @@ def compare(expected_pairs: np.ndarray, delivered_pairs: np.ndarray,
 
 
 def is_correct(numbers: dict) -> bool:
-    return all(numbers[name] <= limit for name, limit in LIMITS.items())
+    return all(value <= LIMITS[name] for name, value in numbers.items())
 
 
 def compared_report(numbers: dict) -> dict:
     return {name: {"value": numbers[name], "limit": LIMITS[name]}
-            for name in LIMITS}
+            for name in LIMITS if name in numbers}
 
 
 # -- controls: the reference in the program's place, one guarantee broken ------

@@ -4,7 +4,10 @@
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell names a configuration (benchmarks/configs/<config>.json: the
-deployment's binding table and guarantees) and a traffic mix
+deployment's binding table, which may be a graph of exchanges, and in its
+`applied` section whether it is durable, whether its publishes are
+persistent, how its consumers acknowledge and which options its broker is
+started with: reference.applied) and a traffic mix
 (benchmarks/traffic/<traffic>.json). This parent never imports jax. It
 builds native/, starts ONE broker child that holds the chip (through
 broker_launch.py, which is `chanamq_tpu.broker.server` with the profiler's
@@ -133,14 +136,33 @@ class BrokerChild:
     """The one process that holds the chip, its output in a file."""
 
     def __init__(self, out_dir: str, fault: "str | None", seed: int,
-                 cores: "set | None") -> None:
+                 cores: "set | None", applied: dict) -> None:
         self.seed, self.cores = seed, cores
+        self.out_dir, self.applied = out_dir, applied
         self.port = free_port()
         self.admin_port = free_port()
         self.control = os.path.join(out_dir, "control")
         self.log_path = os.path.join(out_dir, "broker.log")
         self.fault = fault
         self.proc: "subprocess.Popen | None" = None
+
+    def server_args(self) -> list:
+        """What the configuration states of its broker, as server.main()'s
+        own arguments: its options in a --config file, and for a durable
+        deployment a --store; both inside the run's directory, which every
+        run (the priming run too) empties first, so each starts from an
+        empty store."""
+        args: list = []
+        if self.applied["broker_options"]:
+            options = os.path.join(self.out_dir, "broker_options.json")
+            with open(options, "w", encoding="utf-8") as f:
+                json.dump(self.applied["broker_options"], f, indent=1)
+            args += ["--config", options]
+        if self.applied["durable"]:
+            os.makedirs(os.path.join(self.out_dir, "store"))
+            args += ["--store",
+                     os.path.join(self.out_dir, "store", "broker.db")]
+        return args
 
     def start(self) -> None:
         env = dict(os.environ)
@@ -161,7 +183,7 @@ class BrokerChild:
             command += ["--fault", self.fault]
         command += ["--", "--host", "127.0.0.1", "--port", str(self.port),
                     "--admin-port", str(self.admin_port),
-                    "--log-level", "INFO"]
+                    "--log-level", "INFO", *self.server_args()]
         with open(self.log_path, "wb") as log_file:
             self.proc = subprocess.Popen(
                 command, cwd=ROOT, env=env, stdin=subprocess.DEVNULL,
@@ -249,28 +271,40 @@ def build_native() -> None:
 # -- topology and load ---------------------------------------------------------
 
 
-async def declare(port: int, table: dict) -> None:
-    """Exchange, queues, then bindings, spread over DECLARE_CONNS
+async def declare(port: int, table: dict, applied: dict) -> None:
+    """Exchanges, queues, then bindings (a graph's exchange-to-exchange
+    bindings last, once every exchange exists), spread over DECLARE_CONNS
     connections (one connection spends a round trip per bind)."""
     from chanamq_tpu.client import AMQPClient
 
+    flags = {"durable": True} if applied["durable"] else {}
+    root = table["exchange"]
+    queue_binds = [(root, queue, key, args)
+                   for key, queue, args in table["bindings"]]
+    queue_binds += table.get("queue_bindings", [])
     conns = [await AMQPClient.connect("127.0.0.1", port)
              for _ in range(DECLARE_CONNS)]
     try:
         chans = [await conn.channel() for conn in conns]
-        await chans[0].exchange_declare(table["exchange"], table["type"])
+        for name, kind in reference.table_exchanges(table):
+            await chans[0].exchange_declare(name, kind, **flags)
 
         async def queues(i: int) -> None:
             for queue in table["queues"][i::DECLARE_CONNS]:
-                await chans[i].queue_declare(queue)
+                await chans[i].queue_declare(queue, **flags)
 
         async def binds(i: int) -> None:
-            for key, queue, args in table["bindings"][i::DECLARE_CONNS]:
-                await chans[i].queue_bind(
-                    queue, table["exchange"], key, arguments=args)
+            for exchange, queue, key, args in queue_binds[i::DECLARE_CONNS]:
+                await chans[i].queue_bind(queue, exchange, key, arguments=args)
 
-        await asyncio.gather(*(queues(i) for i in range(DECLARE_CONNS)))
-        await asyncio.gather(*(binds(i) for i in range(DECLARE_CONNS)))
+        async def exchange_binds(i: int) -> None:
+            for source, destination, key, args in table.get(
+                    "exchange_bindings", [])[i::DECLARE_CONNS]:
+                await chans[i].exchange_bind(
+                    destination, source, key, arguments=args)
+
+        for step in (queues, binds, exchange_binds):
+            await asyncio.gather(*(step(i) for i in range(DECLARE_CONNS)))
     finally:
         for conn in conns:
             await conn.close()
@@ -485,6 +519,7 @@ def run(args, state: dict) -> dict:
         bench = json.load(f)
     cell = find_cell(bench, args.workload)
     cfg = reference.load_config(cell["config"], args.scale)
+    applied = reference.applied(cfg)
     mix = reference.load_traffic(cell["traffic"], args.scale)
     out_dir = args.out or os.path.join(
         ROOT, "bench_out", f"{args.workload}-{args.seed}-t{args.trace}")
@@ -494,14 +529,15 @@ def run(args, state: dict) -> dict:
         f"workload={args.workload} config={cell['config']} "
         f"traffic={cell['traffic']} seed={args.seed} seconds={args.seconds} "
         f"trace={args.trace} scale={args.scale} control={args.control} "
-        f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', 'unset')}")
+        f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', 'unset')} "
+        f"applied={json.dumps(applied)}")
 
     import chanamq_tpu  # noqa: F401 — fail here when run without the repo
 
     build_native()
     broker_cores, loadgen_cores = place(mix["producers"] + mix["consumers"])
     broker = state["broker"] = BrokerChild(
-        out_dir, args.fault, args.seed, broker_cores)
+        out_dir, args.fault, args.seed, broker_cores, applied)
     t_boot = time.monotonic()
     broker.start()
 
@@ -531,10 +567,14 @@ def run(args, state: dict) -> dict:
         f"reference_ready_after={t_reference:.1f}s")
 
     t_declare = time.monotonic()
-    asyncio.run(declare(broker.port, table))
+    asyncio.run(declare(broker.port, table, applied))
     say(f"declared: queues={len(table['queues'])} "
         f"bindings={len(table['bindings'])} over {DECLARE_CONNS} connections "
-        f"in {time.monotonic() - t_declare:.1f}s")
+        f"in {time.monotonic() - t_declare:.1f}s"
+        + (f" exchanges={len(table['exchanges'])} "
+           f"queue_bindings={len(table.get('queue_bindings', []))} "
+           f"exchange_bindings={len(table.get('exchange_bindings', []))}"
+           if "exchanges" in table else ""))
 
     loadgen = state["loadgen"] = LoadChildren(
         args, cell, mix, broker.port, out_dir, loadgen_cores)
@@ -585,6 +625,28 @@ def run(args, state: dict) -> dict:
     received = np.concatenate([f["received"] for f in files])
     published = int(sum(r["published"] for r in reports))
     confirmed = int(sum(r["confirmed"] for r in reports))
+    # what the broker itself says of the guarantees the configuration adds,
+    # read after the consumers' last ack and before SIGTERM
+    held: dict = {}
+    if applied["consumer_ack"]:
+        ready = sum(v["messages"] for v in final["vhosts"].values())
+        unacked = final["metrics"]["queue_unacked"]
+        held["unsettled"] = int(ready + unacked)
+        say(f"settled: acks={sum(r['acks'] for r in consumer_reports)} "
+            f"ready={ready} unacked={unacked}")
+    if applied["durable"]:
+        say("durability (counters of the broker's log since boot; one append "
+            "is one record of any kind, not one message and queue): "
+            + " ".join(f"{k}={final['metrics'][k]}" for k in (
+                "wal_appends", "wal_append_bytes", "wal_commits",
+                "wal_fsyncs", "wal_commit_errors")))
+
+    def compared(delivered: np.ndarray) -> "tuple[dict, np.ndarray]":
+        numbers, bad = reference.compare(
+            expected_pairs, delivered, published, confirmed)
+        numbers.update(held)
+        return numbers, bad
+
     if args.control:
         # the program's own answers first, then every control in its place;
         # the result line is the named control's
@@ -594,13 +656,11 @@ def run(args, state: dict) -> dict:
                 control, table, pool, seqs, entries, expected,
                 mix["confirm_window"])
         for name, stood_in in controls.items():
-            numbers, _ = reference.compare(
-                expected_pairs, stood_in, published, confirmed)
+            numbers, _ = compared(stood_in)
             say(f"control {name}: correct={reference.is_correct(numbers)} "
                 f"{numbers}")
         pairs = controls[args.control]
-    numbers, bad_seqs = reference.compare(
-        expected_pairs, pairs, published, confirmed)
+    numbers, bad_seqs = compared(pairs)
     correct = reference.is_correct(numbers)
 
     window_seqs = seqs[in_window]

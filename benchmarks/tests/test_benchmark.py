@@ -22,6 +22,7 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 sys.path.insert(0, ROOT)
+sys.path.insert(0, HERE)
 
 import reference  # noqa: E402
 import roofline  # noqa: E402
@@ -70,7 +71,7 @@ def test_topic_definition_on_the_grammar():
              ("a.#.c.#", "a.x.c", True), ("a.b", "a.b.c", False)]
     for pattern, key, want in cases:
         assert reference.topic_matches(pattern, key) is want, (pattern, key)
-    table = {"type": "topic", "queues": ["q"],
+    table = {"exchange": "x", "type": "topic", "queues": ["q"],
              "bindings": [(p, "q", None) for p, _, _ in cases]}
     pool = [(k, None) for _, k, _ in cases]
     fast = reference.expected_sets(table, pool)
@@ -128,7 +129,14 @@ def test_percentile_rate_and_compare_arithmetic():
     assert not reference.is_correct(numbers)
     assert sorted(bad.tolist()) == [1, 2, 3]
     report = reference.compared_report(numbers)
-    assert list(report) == list(reference.LIMITS)
+    # the four every cell compares; `unsettled` only where consumers ack
+    assert list(report) == list(reference.LIMITS)[:4] == list(numbers)
+    assert reference.is_correct(dict(numbers, missing=0, unexpected=0,
+                                     duplicates=0, unconfirmed=0))
+    acked = reference.compared_report(dict(numbers, unsettled=3))
+    assert list(acked) == list(reference.LIMITS)
+    assert acked["unsettled"] == {"value": 3, "limit": 0}
+    assert not reference.is_correct({"unsettled": 1})
     assert all(v["limit"] == 0 for v in report.values())
 
 
@@ -227,7 +235,9 @@ def run_cell(workload: str, *extra: str) -> "tuple[int, dict | None, str]":
         last = json.loads(lines[-1])
     except (ValueError, IndexError):
         last = None
-    return proc.returncode, last, proc.stdout[-3000:] + proc.stderr[-3000:]
+    # the whole of standard output: a traced result line grows with every
+    # per-layer metric a cell carries, and the lines before it are looked for
+    return proc.returncode, last, proc.stdout + proc.stderr[-3000:]
 
 
 @pytest.mark.parametrize("cell,trace", [
@@ -323,3 +333,10 @@ def test_a_fault_under_the_timed_path_is_not_correct(fault, shows_in, cell):
     assert last["correct"] is False, output
     assert 0 < last["failed"] <= last["attempted"]
     assert sum(last["compared"][name]["value"] for name in shows_in) > 0
+
+
+# tier-1 collects this module by name (tests/test_benchmarks_suite.py loads
+# test_benchmark and test_launch_metrics and copies their test functions), so
+# the tests of the files beside it ride along here
+from deployment_cases import *  # noqa: E402,F401,F403
+from graph_cases import *  # noqa: E402,F401,F403
