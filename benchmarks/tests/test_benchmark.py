@@ -338,5 +338,6 @@ def test_a_fault_under_the_timed_path_is_not_correct(fault, shows_in, cell):
 # tier-1 collects this module by name (tests/test_benchmarks_suite.py loads
 # test_benchmark and test_launch_metrics and copies their test functions), so
 # the tests of the files beside it ride along here
+from added_cell_cases import *  # noqa: E402,F401,F403
 from deployment_cases import *  # noqa: E402,F401,F403
 from graph_cases import *  # noqa: E402,F401,F403

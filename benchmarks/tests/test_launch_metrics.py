@@ -42,7 +42,13 @@ def test_a_launch_metric_names_a_reader_and_matches_its_entry(name):
     assert entry["source"] == "program_counter"
     assert entry["better"] == "lower"
     paced = name.endswith(".paced")
-    assert entry["workloads"] == (["topic_paced"] if paced else SATURATED)
+    # the saturated cells of PR 28 are a floor: a cell added since joins the
+    # list; a paced cell joins a `.paced` twin only by a `benchmark` PR
+    if paced:
+        assert entry["workloads"] == ["topic_paced"]
+    else:
+        assert set(SATURATED) <= set(entry["workloads"])
+        assert "topic_paced" not in entry["workloads"]
     assert entry["moves"] == ("deliver_latency_p50_ms" if paced
                               else "delivered_msgs_per_s")
     assert spec["params"].get("over", "window") == "window"
