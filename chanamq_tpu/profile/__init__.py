@@ -21,7 +21,8 @@ Three coupled parts, all always-cheap enough to leave on in production:
   subsystem plus the fraction of process CPU the ledger attributes, and a
   ``router`` block: the launch counters ``Metrics`` keeps whether the
   ledger is on or not (``chanamq_router_{tokenize,dispatch,wait,decode,
-  route}_ns``, ``_kernel_{keys,rows}``, ``_h2d_bytes``, ``_mask_decodes``;
+  route}_ns``, ``_kernel_{keys,rows}``, ``_h2d_bytes``, ``_table_uploads``,
+  ``_mask_decodes``;
   stamped in router/compile.py ``_launch`` and ``route_batch``) and, under
   ``per_launch``, the ``route`` stage split per device launch by them.
   The ledger's ``route`` window and ``router_route_ns`` are one pair of

@@ -209,6 +209,8 @@ class ProfileRuntime:
                 "useful_row_pct": round(
                     100.0 * m.router_kernel_keys / m.router_kernel_rows, 1),
                 "h2d_bytes": round(m.router_h2d_bytes / launches),
+                "table_resident_pct": round(
+                    100.0 * (1 - m.router_table_uploads / launches), 2),
                 "mask_memo_hit_pct": round(
                     100.0 * (1 - m.router_mask_decodes / m.router_kernel_keys),
                     1),

@@ -41,10 +41,21 @@ always-available fallback for that exchange.
 All array dims (pattern rows, prefix/suffix width, batch size, header
 counts) are padded up to power-of-two buckets so jit retraces stay bounded
 as tables and batches grow.
+
+What a launch hands the device (backend="jax"): the batch, as ONE int32
+array (a topic batch packs its prefix words, suffix words and word count
+into ``[b, p+s+1]``; the jitted wrapper slices it), and nothing else. A
+snapshot's tables go up once, at its first launch, and stay on the device
+for the snapshot's life (``CompiledExchange._resident``): a snapshot is
+immutable and is replaced, never edited, so a launch can only ever see its
+own generation's table. On a TPU every host array handed to a jitted call
+costs the calling thread ~0.1 ms, whatever its size (PERF.md section 6,
+PR 32).
 """
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Iterable, Optional
 
@@ -60,6 +71,11 @@ MISS = -3   # message cell: out-of-vocab word, or beyond the message length
 MAX_PATTERN_WORDS = 32
 
 _EMPTY: frozenset = frozenset()
+
+# the kernels' table operands (keys of ``wild`` / ``headers``), in the
+# kernels' argument order; the batch's operands follow them
+_TOPIC_TABLES = ("pre", "suf", "plen", "slen", "has_hash", "masks")
+_HEADERS_TABLES = ("req", "rcount", "is_all", "masks")
 
 # decoded (mask -> names) and routed (key -> names) memo caps, per compiled
 # snapshot; snapshots are immutable so entries never go stale, the cap only
@@ -87,7 +103,7 @@ class CompiledExchange:
     """Immutable compiled snapshot of one exchange's binding table."""
 
     __slots__ = ("kind", "generation", "exact", "always", "bit_names",
-                 "wild", "headers", "_route_memo", "_mask_memo")
+                 "wild", "headers", "_route_memo", "_mask_memo", "_resident")
 
     def __init__(self, kind: str, generation: int) -> None:
         self.kind = kind
@@ -107,6 +123,10 @@ class CompiledExchange:
         # bounded mask memo, topic and headers: a kernel row's bytes ->
         # always | the names of its bits, decoded once per distinct mask
         self._mask_memo: dict = {}
+        # kernel_tables() as device arrays: put on the chip by the first
+        # backend="jax" launch through this snapshot (``_launch``) and
+        # handed to every launch after; freed with the snapshot
+        self._resident: Optional[tuple] = None
 
     @property
     def kernel_rows(self) -> int:
@@ -115,6 +135,13 @@ class CompiledExchange:
         if self.headers is not None:
             return self.headers["n"]
         return 0
+
+    def kernel_tables(self) -> tuple:
+        """The match kernel's table operands, in its argument order: the
+        numpy arrays the compiler built, which the numpy twin reads."""
+        if self.wild is not None:
+            return tuple(self.wild[k] for k in _TOPIC_TABLES)
+        return tuple(self.headers[k] for k in _HEADERS_TABLES)
 
     # -- mask decode -------------------------------------------------------
 
@@ -336,11 +363,19 @@ def _topic_kernel(xp, pre_t, suf_t, plen, slen, has_h, masks,
     return xp.bitwise_or.reduce(hit, axis=1)                      # [B,W]
 
 
-def _tokenize_topic(wild: dict, keys: list, b: int):
+def _split_topic(packed, p: int, s: int) -> tuple:
+    """A topic launch's one batch operand ``[b, p+s+1]`` as the kernel's
+    three: the first ``p`` words, the last ``s`` words right-aligned, the
+    word count. Basic slices: views into a numpy array (the tokenizer fills
+    it through them, the numpy twin reads them), static slices under jit."""
+    return packed[:, :p], packed[:, p:p + s], packed[:, p + s]
+
+
+def _tokenize_topic(wild: dict, keys: list, b: int) -> np.ndarray:
     p, s, vocab = wild["p"], wild["s"], wild["vocab"]
-    pre_m = np.full((b, p), MISS, dtype=np.int32)
-    suf_m = np.full((b, s), MISS, dtype=np.int32)
-    mlen = np.zeros(b, dtype=np.int32)
+    packed = np.full((b, p + s + 1), MISS, dtype=np.int32)
+    pre_m, suf_m, mlen = _split_topic(packed, p, s)
+    mlen[:] = 0
     get = vocab.get
     for i, key in enumerate(keys):
         words = key.split(".") if key else [""]
@@ -350,7 +385,7 @@ def _tokenize_topic(wild: dict, keys: list, b: int):
             pre_m[i, j] = get(words[j], MISS)
         for j in range(min(m, s)):
             suf_m[i, s - 1 - j] = get(words[m - 1 - j], MISS)
-    return pre_m, suf_m, mlen
+    return packed
 
 
 # -- headers ---------------------------------------------------------------
@@ -442,42 +477,55 @@ def _tokenize_headers(table: dict, headers_list: list, b: int):
 
 # -- batch evaluation ------------------------------------------------------
 
-_JIT_TOPIC = None
-_JIT_HEADERS = None
+_JIT = None  # (topic_match, headers_match, put): built by the first launch
 
 
-def _jit_kernels():
-    global _JIT_TOPIC, _JIT_HEADERS
-    if _JIT_TOPIC is None:
+def _jit_kernels() -> tuple:
+    """The two jitted kernels, and ``put``: host arrays onto the device
+    this process claimed."""
+    global _JIT
+    if _JIT is None:
         import jax
         import jax.numpy as jnp
 
         # functions with names, not lambdas: a call's host event reads
         # PjitFunction(topic_match), its module jit_topic_match, and the
         # ops carry the scope, so a trace tells the two kernels apart
-        def topic_match(*args):
+        def topic_match(pre_t, suf_t, plen, slen, has_h, masks, packed):
             with jax.named_scope("router.topic_match"):
-                return _topic_kernel(jnp, *args)
+                return _topic_kernel(
+                    jnp, pre_t, suf_t, plen, slen, has_h, masks,
+                    *_split_topic(packed, pre_t.shape[1], suf_t.shape[1]))
 
         def headers_match(*args):
             with jax.named_scope("router.headers_match"):
                 return _headers_kernel(jnp, *args)
 
-        _JIT_TOPIC = jax.jit(topic_match)
-        _JIT_HEADERS = jax.jit(headers_match)
-    return _JIT_TOPIC, _JIT_HEADERS
+        _JIT = (jax.jit(topic_match), jax.jit(headers_match),
+                functools.partial(jax.device_put, device=jax.devices()[0]))
+    return _JIT
 
 
-def _launch(kern, args: tuple, keys: int, t_tok: int, metrics) -> tuple:
+def _launch(compiled: CompiledExchange, batch: np.ndarray, keys: int,
+            t_tok: int, metrics) -> tuple:
     """One jitted kernel call and the wait for its rows, on the calling
     thread (the event loop's), stamped once at each boundary: ``t_tok`` is
     when the tokenizer started, ``keys`` how many rows carry a real key.
-    Returns ``(rows, t_rows)``, ``t_rows`` being when the rows were on the
-    host, from where the caller times its decode. The jitted call and
-    ``np.asarray`` write JAX's own events into a profiler trace, so no
-    span is opened here."""
+    The call is handed ``batch``, one host array, and nothing else: the
+    snapshot's tables are on the device from its first launch on, whose
+    dispatch holds their upload. Returns ``(rows, t_rows)``, ``t_rows``
+    being when the rows were on the host, from where the caller times its
+    decode. The jitted call and ``np.asarray`` write JAX's own events into
+    a profiler trace, so no span is opened here."""
+    topic, headers, put = _jit_kernels()
+    kern = topic if compiled.kind == "topic" else headers
     t_call = time.perf_counter_ns()
-    result = kern(*args)
+    tables = compiled._resident
+    uploaded: tuple = ()
+    if tables is None:
+        uploaded = compiled.kernel_tables()
+        tables = compiled._resident = put(uploaded)
+    result = kern(*tables, batch)
     t_back = time.perf_counter_ns()
     rows = np.asarray(result)
     t_rows = time.perf_counter_ns()
@@ -488,8 +536,10 @@ def _launch(kern, args: tuple, keys: int, t_tok: int, metrics) -> tuple:
         metrics.router_wait_ns += t_rows - t_back
         metrics.router_kernel_keys += keys
         metrics.router_kernel_rows += rows.shape[0]
-        metrics.router_h2d_bytes += sum(
-            a.nbytes for a in args if isinstance(a, np.ndarray))
+        metrics.router_h2d_bytes += batch.nbytes
+        if uploaded:
+            metrics.router_table_uploads += 1
+            metrics.router_h2d_bytes += sum(a.nbytes for a in uploaded)
     return rows, t_rows
 
 
@@ -508,7 +558,8 @@ def route_batch(
     registry) counts each jitted kernel call in ``router_kernel_launches``
     — the one series that tells a flush that reached the device from one
     the key memo, a host dict or the numpy twin served — and, with it,
-    what that launch cost the calling thread (``_launch``). The stretches
+    what that launch cost the calling thread (``_launch``, which also puts
+    the snapshot's tables on the device the first time). The stretches
     either side of the call carry ``device.span`` names, flat: lookup,
     tokenize, (JAX's own two events), decode."""
     kind = compiled.kind
@@ -550,15 +601,14 @@ def route_batch(
             b = _bucket(len(uniq), 16)
         t_tok = time.perf_counter_ns()
         with device.span("router.tokenize"):
-            pre_m, suf_m, mlen = _tokenize_topic(wild, uniq, b)
-        args = (wild["pre"], wild["suf"], wild["plen"], wild["slen"],
-                wild["has_hash"], wild["masks"], pre_m, suf_m, mlen)
+            packed = _tokenize_topic(wild, uniq, b)
         t_rows = 0
         if backend == "jax":
-            rows, t_rows = _launch(
-                _jit_kernels()[0], args, len(uniq), t_tok, metrics)
+            rows, t_rows = _launch(compiled, packed, len(uniq), t_tok, metrics)
         else:
-            rows = _topic_kernel(np, *args)
+            rows = _topic_kernel(
+                np, *compiled.kernel_tables(),
+                *_split_topic(packed, wild["p"], wild["s"]))
         with device.span("router.decode"):
             masked, decoded = compiled._decode_rows(rows, len(uniq))
             exact = compiled.exact.get
@@ -582,14 +632,11 @@ def route_batch(
         t_tok = time.perf_counter_ns()
         with device.span("router.tokenize"):
             pids = _tokenize_headers(table, [h for _, h in items], b)
-        args = (table["req"], table["rcount"], table["is_all"],
-                table["masks"], pids)
         t_rows = 0
         if backend == "jax":
-            rows, t_rows = _launch(
-                _jit_kernels()[1], args, len(items), t_tok, metrics)
+            rows, t_rows = _launch(compiled, pids, len(items), t_tok, metrics)
         else:
-            rows = _headers_kernel(np, *args)
+            rows = _headers_kernel(np, *compiled.kernel_tables(), pids)
         with device.span("router.decode"):
             out, decoded = compiled._decode_rows(rows, len(items))
         if t_rows and metrics is not None:

@@ -58,7 +58,8 @@ class Metrics:
     ROUTER_LAUNCH = (
         "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
         "router_decode_ns", "router_kernel_keys", "router_kernel_rows",
-        "router_h2d_bytes", "router_mask_decodes", "router_route_ns",
+        "router_h2d_bytes", "router_table_uploads", "router_mask_decodes",
+        "router_route_ns",
     )
 
     def __init__(self) -> None:
@@ -242,16 +243,20 @@ class Metrics:
         self.router_parity_mismatches = 0
         self.router_batch_size = Histogram()
         # the launch from inside (router/compile.py route_batch, backend
-        # jax only; each advances once per jitted kernel call, so every
-        # ratio to router_kernel_launches is per device launch): wall ns
-        # of the tokenizer, of the jitted call until it returns (the
-        # arguments' DevicePuts and the enqueue), of np.asarray on the
+        # jax only; all but router_table_uploads advance once per jitted
+        # kernel call, so every ratio to router_kernel_launches is per
+        # device launch): wall ns of the tokenizer, of the jitted call until
+        # it returns (the batch's DevicePut and the enqueue; at a
+        # snapshot's first launch its tables' too), of np.asarray on the
         # result (the loop blocked on the device and the copy back), of
         # the mask decode and memo fill after it; rows that carry a real
         # key or header set and rows after padding to the bucket; bytes of
-        # the host arrays handed to the call (an argument already on the
-        # device counts 0); rows whose mask the mask memo did not hold and
-        # Python had to decode. Per flush: route_pending's whole window.
+        # the host arrays handed over (the batch every launch; the binding
+        # table once per compiled snapshot, at its first launch, after
+        # which it is on the device and counts 0) and how many times a
+        # snapshot's tables were put on the device; rows whose mask the
+        # mask memo did not hold and Python had to decode. Per flush:
+        # route_pending's whole window.
         self.router_tokenize_ns = 0
         self.router_dispatch_ns = 0
         self.router_wait_ns = 0
@@ -259,6 +264,7 @@ class Metrics:
         self.router_kernel_keys = 0
         self.router_kernel_rows = 0
         self.router_h2d_bytes = 0
+        self.router_table_uploads = 0
         self.router_mask_decodes = 0
         self.router_route_ns = 0
         # native batch egress (native/chanamq_native.cpp): delivery

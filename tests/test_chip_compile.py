@@ -25,7 +25,7 @@ from chanamq_tpu.models.forecaster import (  # noqa: E402
     ForecasterConfig, forward, init_momentum, init_params, make_train_step,
 )
 from chanamq_tpu.router.compile import (  # noqa: E402
-    MAX_PATTERN_WORDS, _headers_kernel, _topic_kernel,
+    MAX_PATTERN_WORDS, _headers_kernel, _split_topic, _topic_kernel,
 )
 
 HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
@@ -94,15 +94,25 @@ def _shapes(sharding, *specs):
     pytest.param(MAX_BATCH, MAX_ROWS, MAX_PATTERN_WORDS, MAX_PATTERN_WORDS,
                  MAX_MASK_WORDS, id="caps"),
 ])
+@pytest.mark.parametrize("packed", [False, True], ids=["body", "launch"])
 def test_topic_kernel_compiles_for_v5e(one_chip, batch, rows, pre, suf,
-                                       mask_words):
-    _compile(lambda *a: _topic_kernel(jnp, *a), *_shapes(
-        one_chip,
+                                       mask_words, packed):
+    """The kernel's body over its three batch operands, and the program a
+    launch really runs: the batch as one array, sliced under the jit."""
+    tables = [
         ((rows, pre), jnp.int32), ((rows, suf), jnp.int32),
         ((rows,), jnp.int32), ((rows,), jnp.int32), ((rows,), jnp.bool_),
-        ((rows, mask_words), jnp.uint32),
-        ((batch, pre), jnp.int32), ((batch, suf), jnp.int32),
-        ((batch,), jnp.int32)))
+        ((rows, mask_words), jnp.uint32)]
+    if packed:
+        _compile(
+            lambda *a: _topic_kernel(
+                jnp, *a[:6], *_split_topic(a[6], pre, suf)),
+            *_shapes(one_chip, *tables, ((batch, pre + suf + 1), jnp.int32)))
+    else:
+        _compile(lambda *a: _topic_kernel(jnp, *a), *_shapes(
+            one_chip, *tables,
+            ((batch, pre), jnp.int32), ((batch, suf), jnp.int32),
+            ((batch,), jnp.int32)))
 
 
 @pytest.mark.parametrize("batch,rows,required,present,mask_words", [

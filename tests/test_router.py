@@ -241,13 +241,13 @@ def _kernel_rows(compiled, items):
     if compiled.kind == "topic":
         t = compiled.wild
         return rcompile._topic_kernel(
-            np, t["pre"], t["suf"], t["plen"], t["slen"],
-            t["has_hash"], t["masks"],
-            *rcompile._tokenize_topic(t, [k for k, _ in items], b))
-    t = compiled.headers
+            np, *compiled.kernel_tables(),
+            *rcompile._split_topic(
+                rcompile._tokenize_topic(t, [k for k, _ in items], b),
+                t["p"], t["s"]))
     return rcompile._headers_kernel(
-        np, t["req"], t["rcount"], t["is_all"], t["masks"],
-        rcompile._tokenize_headers(t, [h for _, h in items], b))
+        np, *compiled.kernel_tables(),
+        rcompile._tokenize_headers(compiled.headers, [h for _, h in items], b))
 
 
 @pytest.mark.parametrize("words", [1, 16, 128])
@@ -342,6 +342,232 @@ def test_keys_with_one_mask_share_one_frozenset():
     compiled = compile_exchange("topic", m.bindings())
     got = route_batch(compiled, [("solo", None)])
     assert got[0] is compiled.exact["solo"]
+
+
+# ---------------------------------------------------------------------------
+# what a launch hands over: the table resident per snapshot, the batch one
+# array
+# ---------------------------------------------------------------------------
+
+
+def _small_table(kind: str):
+    """(matcher, items) that reach the kernel: wildcard rows of every
+    shape plus an exact pattern, or headers bindings of both modes."""
+    if kind == "topic":
+        m = TopicMatcher()
+        for i, pattern in enumerate(
+                ["a.*", "#.z", "w.k.#", "*.b.#", "a.b", "t.*.s"]):
+            m.bind(pattern, f"q{i}")
+        keys = ["a.b", "a.z", "w.k", "w.k.x.y", "x.b", "t.u.s", "", "m.n.o.z",
+                "nowhere.at.all"]
+        return m, [(k, None) for k in keys]
+    m = HeadersMatcher()
+    m.bind("", "q0", {"x-match": "all", "h": 1, "g": "s"})
+    m.bind("", "q1", {"x-match": "any", "h": 2, "g": "t"})
+    m.bind("", "q2", {"x-match": "any", "f": True})
+    msgs = [{"h": 1, "g": "s"}, {"h": 1}, {"h": 2}, {"g": "t", "f": True},
+            {}, None, {"other": 1}, {"h": 1, "g": "s", "f": True}]
+    return m, [("", h) for h in msgs]
+
+
+def _oracle(matcher, items):
+    return [matcher.route(k, h) for k, h in items]
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_a_snapshot_launched_twice_answers_alike_and_uploads_once(kind):
+    from chanamq_tpu.utils.metrics import Metrics
+
+    m, items = _small_table(kind)
+    twin = route_batch(compile_exchange(kind, m.bindings()), items, "python")
+    compiled = compile_exchange(kind, m.bindings())
+    metrics = Metrics()
+    assert compiled._resident is None  # nothing goes up before a launch
+    first = route_batch(compiled, items, "jax", metrics)
+    resident = compiled._resident
+    host = compiled.kernel_tables()
+    assert len(resident) == len(host)
+    for on_device, on_host in zip(resident, host):
+        assert not isinstance(on_device, np.ndarray)
+        assert on_device.dtype == on_host.dtype
+        assert np.array_equal(np.asarray(on_device), on_host)
+    compiled._route_memo.clear()  # topic: or the key memo answers
+    second = route_batch(compiled, items, "jax", metrics)
+    assert first == second == twin
+    assert [set(n) for n in first] == _oracle(m, items)
+    assert metrics.router_kernel_launches == 2
+    assert metrics.router_table_uploads == 1
+    assert compiled._resident is resident
+    # the numpy tables stay where they were, for the twin and the tests
+    assert all(isinstance(t, np.ndarray) for t in compiled.kernel_tables())
+
+
+def _mk_broker(loop, kind: str):
+    """An exchange `ex` of `kind` with `_small_table`'s bindings."""
+    broker = Broker()
+    run = loop.run_until_complete
+    run(broker.create_vhost("/"))
+    run(broker.declare_exchange("/", "ex", kind))
+    m, items = _small_table(kind)
+    for key, queue, args in m.bindings():
+        if queue not in broker.vhosts["/"].queues:
+            run(broker.declare_queue("/", queue))
+        run(broker.bind_queue("/", queue, "ex", key, args))
+    broker.router.min_batch = 1
+    return broker, items
+
+
+def _flush(broker, items) -> list:
+    entries = [("ex", key, BasicProperties(headers=headers), b"x", None,
+                None, False) for key, headers in items]
+    routes, _, _ = broker.router.route_pending("/", entries)
+    return [{q.name for q in queues} for queues in routes]
+
+
+@pytest.mark.parametrize("change", ["bind", "unbind", "queue_delete"])
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_a_new_generation_answers_from_its_own_table(event_loop, kind, change):
+    """The stale-table case: after a bind, an unbind or a queue delete the
+    next launch must match against the NEW table on the device, while the
+    snapshot a flush already holds keeps answering from its own."""
+    broker, items = _mk_broker(event_loop, kind)
+    run = event_loop.run_until_complete
+    matcher = broker.vhosts["/"].exchanges["ex"].matcher
+    before = _oracle(matcher, items)
+    assert _flush(broker, items) == before
+    old = broker.router._compiled[("/", "ex")]
+    assert broker.metrics.router_table_uploads == 1
+
+    if change == "bind":
+        run(broker.declare_queue("/", "qn"))
+        if kind == "topic":
+            run(broker.bind_queue("/", "qn", "ex", "#.b"))
+        else:
+            run(broker.bind_queue("/", "qn", "ex", "",
+                                  {"x-match": "any", "h": 1}))
+    elif change == "unbind":
+        key, queue, args = next(
+            b for b in matcher.bindings() if b[1] == "q1")
+        run(broker.unbind_queue("/", queue, "ex", key, args))
+    else:
+        run(broker.delete_queue("/", "q0"))
+    after = _oracle(matcher, items)
+    assert after != before  # the change is one these messages can see
+
+    assert _flush(broker, items) == after
+    new = broker.router._compiled[("/", "ex")]
+    assert new is not old and new.generation > old.generation
+    assert broker.metrics.router_table_uploads == 2
+    assert new._resident is not None and new._resident is not old._resident
+    # a flush that resolved the old snapshot before the change still routes
+    # against the old table, on the device
+    old._route_memo.clear()
+    launches = broker.metrics.router_kernel_launches
+    stale = route_batch(old, items, "jax", broker.metrics)
+    assert [set(n) for n in stale] == before
+    assert broker.metrics.router_kernel_launches == launches + 1
+    assert broker.metrics.router_table_uploads == 2
+
+
+def _parents_tokenizer(wild: dict, keys: list, b: int):
+    """`_tokenize_topic` as it was while a launch carried three operands."""
+    p, s, vocab = wild["p"], wild["s"], wild["vocab"]
+    pre_m = np.full((b, p), rcompile.MISS, dtype=np.int32)
+    suf_m = np.full((b, s), rcompile.MISS, dtype=np.int32)
+    mlen = np.zeros(b, dtype=np.int32)
+    for i, key in enumerate(keys):
+        words = key.split(".") if key else [""]
+        m = len(words)
+        mlen[i] = m
+        for j in range(min(m, p)):
+            pre_m[i, j] = vocab.get(words[j], rcompile.MISS)
+        for j in range(min(m, s)):
+            suf_m[i, s - 1 - j] = vocab.get(words[m - 1 - j], rcompile.MISS)
+    return pre_m, suf_m, mlen
+
+
+@pytest.mark.parametrize("keys", [
+    ["a", "c.d", ""],                               # shorter than p and s
+    ["a.b.c.d", "x.b.c.y"],                         # p words: equal
+    ["a.b", "e.f"],                                 # s words: equal
+    ["a.b.c.d.e.f.g", "z.z.z.z.z.z.z.z.z.e.f"],     # longer than both
+    [""],                                           # the empty key alone
+    ["a.b.c.d", "", "e.f", "q", "a..f", "a.b.c.d.e.f"],
+], ids=["shorter", "equal_p", "equal_s", "longer", "empty", "mixed"])
+def test_the_packed_operand_slices_into_the_three_the_kernel_takes(keys):
+    import jax
+
+    m = TopicMatcher()
+    m.bind("a.b.c.*", "q0")   # p = 4
+    m.bind("#.e.f", "q1")     # s = 2
+    m.bind("a.#.f", "q2")
+    compiled = compile_exchange("topic", m.bindings())
+    wild = compiled.wild
+    p, s = wild["p"], wild["s"]
+    assert (p, s) == (4, 2)
+    b = rcompile._bucket(len(keys), 16)
+    packed = rcompile._tokenize_topic(wild, keys, b)
+    assert packed.shape == (b, p + s + 1) and packed.dtype == np.int32
+    assert packed.flags["C_CONTIGUOUS"]
+    want = _parents_tokenizer(wild, keys, b)
+    for got, ref in zip(rcompile._split_topic(packed, p, s), want):
+        assert got.shape == ref.shape and np.array_equal(got, ref)
+    # and as the jitted wrapper slices it, on the device
+    on_device = jax.jit(
+        lambda x: rcompile._split_topic(x, p, s))(packed)
+    for got, ref in zip(on_device, want):
+        assert np.array_equal(np.asarray(got), ref)
+    got = _route_all_backends(compiled, [(k, None) for k in keys])
+    assert [set(n) for n in got] == [m.route(k) for k in keys]
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_the_numpy_backend_never_imports_jax_and_never_uploads(kind):
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys\n"
+        "from chanamq_tpu.router.compile import compile_exchange, route_batch\n"
+        "from chanamq_tpu.utils.metrics import Metrics\n"
+        f"kind = {kind!r}\n"
+        "if kind == 'topic':\n"
+        "    bindings = [('a.*', 'q0', None), ('#.z', 'q1', None)]\n"
+        "    items = [(f'a.k{i}', None) for i in range(20)] + [('m.z', None)]\n"
+        "else:\n"
+        "    bindings = [('', 'q0', {'x-match': 'any', 'h': 1})]\n"
+        "    items = [('', {'h': i % 2}) for i in range(20)]\n"
+        "compiled = compile_exchange(kind, bindings)\n"
+        "metrics = Metrics()\n"
+        "out = route_batch(compiled, items, 'python', metrics)\n"
+        "assert sum(1 for names in out if names) >= 10\n"
+        "assert compiled._resident is None\n"
+        "assert metrics.router_table_uploads == 0\n"
+        "assert metrics.router_h2d_bytes == 0\n"
+        "print('jax' in sys.modules)\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True,
+        text=True, timeout=120, env=dict(os.environ, PYTHONPATH=root))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "False"
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_a_table_over_the_cap_raises_and_uploads_nothing(event_loop, kind):
+    broker, items = _mk_broker(event_loop, kind)
+    router, metrics = broker.router, broker.metrics
+    router.max_wildcards = 2  # `_small_table` has more kernel rows
+    matcher = broker.vhosts["/"].exchanges["ex"].matcher
+    with pytest.raises(Uncompilable):
+        compile_exchange(kind, matcher.bindings(), max_wildcards=2)
+    assert _flush(broker, items) == _oracle(matcher, items)
+    assert isinstance(router._compiled[("/", "ex")], str)  # the reason
+    assert metrics.router_fallback_msgs == len(items)
+    assert metrics.router_kernel_launches == 0
+    assert metrics.router_table_uploads == 0
+    assert metrics.router_h2d_bytes == 0
 
 
 # ---------------------------------------------------------------------------

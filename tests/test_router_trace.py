@@ -28,7 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PER_LAUNCH = (
     "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
     "router_decode_ns", "router_kernel_keys", "router_kernel_rows",
-    "router_h2d_bytes", "router_mask_decodes")
+    "router_h2d_bytes", "router_table_uploads", "router_mask_decodes")
 PER_FLUSH = ("router_route_ns",)
 SPANS = ("conn.ingress", "router.lookup", "router.tokenize", "router.decode",
          "broker.enqueue", "conn.confirms")
@@ -72,20 +72,17 @@ def _entries(kind: str, n: int, duplicates: int, tag: str = "") -> list:
     return rows
 
 
-def _arg_bytes(broker: Broker, kind: str, real_rows: int) -> "tuple[int, int]":
-    """(bucket, bytes handed up) one launch of `real_rows` must count."""
+def _arg_bytes(broker: Broker, kind: str, real_rows: int) -> "tuple[int, int, int]":
+    """(bucket, bytes of the snapshot's tables, bytes of one batch of
+    `real_rows`): a snapshot's first launch hands up both, every later one
+    the batch alone."""
     compiled = broker.router._compiled[("/", "ex")]
     b = rcompile._bucket(real_rows, 16)
     if kind == "topic":
-        wild = compiled.wild
-        tables = [wild[k] for k in ("pre", "suf", "plen", "slen", "has_hash",
-                                    "masks")]
-        words = 4 * b * (wild["p"] + wild["s"] + 1)
+        words = 4 * b * (compiled.wild["p"] + compiled.wild["s"] + 1)
     else:
-        table = compiled.headers
-        tables = [table[k] for k in ("req", "rcount", "is_all", "masks")]
         words = 4 * b * 2  # one header a message: the bucket's floor of 2
-    return b, sum(t.nbytes for t in tables) + words
+    return b, sum(t.nbytes for t in compiled.kernel_tables()), words
 
 
 @pytest.mark.parametrize("kind", ["topic", "headers"])
@@ -97,11 +94,13 @@ def test_counters_advance_once_a_launch_and_agree_with_the_batch(
     router.route_pending("/", _entries(kind, n, d))
     # a topic launch carries each unseen key once; headers has no key memo
     real = n - d if kind == "topic" else n
-    bucket, up = _arg_bytes(broker, kind, real)
+    bucket, tables, words = _arg_bytes(broker, kind, real)
     assert metrics.router_kernel_launches == 1
     assert metrics.router_kernel_keys == real
     assert metrics.router_kernel_rows == bucket
-    assert metrics.router_h2d_bytes == up
+    # a generation's first launch: its tables go up with the batch
+    assert metrics.router_table_uploads == 1
+    assert metrics.router_h2d_bytes == tables + words
     first = metrics.router_launch()
     assert all(first[name] > 0 for name in (
         "router_tokenize_ns", "router_dispatch_ns", "router_wait_ns",
@@ -116,10 +115,45 @@ def test_counters_advance_once_a_launch_and_agree_with_the_batch(
     if kind == "topic":  # the key memo serves every position: no launch
         assert metrics.router_kernel_launches == 1
         assert all(again[name] == first[name] for name in PER_LAUNCH)
-    else:  # every headers flush reaches the kernel
+    else:  # every headers flush reaches the kernel: the batch alone goes up
         assert metrics.router_kernel_launches == 2
         assert again["router_kernel_keys"] == 2 * n
-        assert again["router_h2d_bytes"] == 2 * up
+        assert again["router_h2d_bytes"] == tables + 2 * words
+        assert again["router_table_uploads"] == 1
+
+
+@pytest.mark.parametrize("kind", ["topic", "headers"])
+def test_the_table_goes_up_once_a_generation(event_loop, kind):
+    """Later launches through a snapshot hand up the batch alone; a bind
+    makes a new generation, whose first launch uploads its own tables."""
+    broker = _broker(event_loop, kind)
+    router, metrics = broker.router, broker.metrics
+    n = 20
+    for flush in range(3):  # fresh keys every flush: three launches
+        router.route_pending("/", _entries(kind, n, 0, f"f{flush}-"))
+    bucket, tables, words = _arg_bytes(broker, kind, n)
+    assert metrics.router_kernel_launches == 3
+    assert metrics.router_table_uploads == 1
+    assert metrics.router_h2d_bytes == tables + 3 * words
+    old = router._compiled[("/", "ex")]
+
+    run = event_loop.run_until_complete
+    run(broker.declare_queue("/", "q3"))
+    if kind == "topic":
+        run(broker.bind_queue("/", "q3", "ex", "a.*.much.longer.pattern"))
+    else:
+        run(broker.bind_queue("/", "q3", "ex", "",
+                              {"x-match": "all", "k": 1, "j": 2, "i": 3}))
+    router.route_pending("/", _entries(kind, n, 0, "g-"))
+    new = router._compiled[("/", "ex")]
+    assert new is not old and new.generation > old.generation
+    _, new_tables, new_words = _arg_bytes(broker, kind, n)
+    assert new_tables > tables  # a wider table: not the old one's bytes
+    assert metrics.router_kernel_launches == 4
+    assert metrics.router_table_uploads == 2
+    assert metrics.router_h2d_bytes == \
+        tables + 3 * words + new_tables + new_words
+    assert old._resident is not None and new._resident is not old._resident
 
 
 def _masked(kind: str, marks: list, tag: str = "") -> list:
@@ -184,11 +218,13 @@ def test_the_profile_page_reads_the_counters_per_launch(event_loop, kind):
     n, d = 20, 5
     broker.router.route_pending("/", _entries(kind, n, d))
     real = n - d if kind == "topic" else n
-    bucket, up = _arg_bytes(broker, kind, real)
+    bucket, tables, words = _arg_bytes(broker, kind, real)
     block = rt.snapshot()["router"]
     per = block["per_launch"]
     assert block["kernel_launches"] == 1
-    assert per["keys"] == real and per["h2d_bytes"] == up
+    assert per["keys"] == real and per["h2d_bytes"] == tables + words
+    assert block["router_table_uploads"] == 1
+    assert per["table_resident_pct"] == 0.0  # the one launch uploaded
     assert per["useful_row_pct"] == round(100.0 * real / bucket, 1)
     for stage in ("tokenize", "dispatch", "wait", "decode"):
         assert per[f"{stage}_us"] == round(
@@ -198,23 +234,27 @@ def test_the_profile_page_reads_the_counters_per_launch(event_loop, kind):
     masks = 1 if kind == "topic" else 2
     assert block["router_mask_decodes"] == masks
     assert per["mask_memo_hit_pct"] == round(100.0 * (1 - masks / real), 1)
+    # a second launch through the snapshot finds its table on the device
+    broker.router.route_pending("/", _entries(kind, n, d, "again-"))
+    per = rt.snapshot()["router"]["per_launch"]
+    assert per["table_resident_pct"] == 50.0
+    assert per["h2d_bytes"] == round((tables + 2 * words) / 2)
 
 
 def test_the_kernels_have_names():
     """The host event reads PjitFunction(topic_match), the module
     jit_topic_match: a trace tells the two kernels apart."""
-    table = rcompile.compile_exchange("topic", [("a.*", "q", None)]).wild
-    topic, headers = rcompile._jit_kernels()
+    compiled = rcompile.compile_exchange("topic", [("a.*", "q", None)])
+    topic, headers, _put = rcompile._jit_kernels()
     lowered = topic.lower(
-        table["pre"], table["suf"], table["plen"], table["slen"],
-        table["has_hash"], table["masks"],
-        *rcompile._tokenize_topic(table, ["a.b"], 16)).as_text()
+        *compiled.kernel_tables(),
+        rcompile._tokenize_topic(compiled.wild, ["a.b"], 16)).as_text()
     assert "module @jit_topic_match" in lowered
-    table = rcompile.compile_exchange(
-        "headers", [("", "q", {"x-match": "all", "k": 1})]).headers
+    compiled = rcompile.compile_exchange(
+        "headers", [("", "q", {"x-match": "all", "k": 1})])
     lowered = headers.lower(
-        table["req"], table["rcount"], table["is_all"], table["masks"],
-        rcompile._tokenize_headers(table, [{"k": 1}], 16)).as_text()
+        *compiled.kernel_tables(),
+        rcompile._tokenize_headers(compiled.headers, [{"k": 1}], 16)).as_text()
     assert "module @jit_headers_match" in lowered
 
 
