@@ -1,7 +1,8 @@
 """The chaos soak: a 3-node replicated workload under a seeded fault plan.
 
-Shared by ``bench.py --chaos`` and ``tests/test_chaos.py`` so the tier-1
-smoke and the test suite assert the same invariants:
+Called by ``tests/test_chaos.py`` and ``tests/test_shard.py``; the other
+runners of this module by ``tests/test_flow.py`` and ``tests/test_soaks.py``.
+The invariants:
 
 1. **No confirmed message lost** — every body whose publisher confirm
    arrived is delivered to the consumer at least once.
@@ -1932,11 +1933,11 @@ async def _elastic_run(seed: int) -> dict:
 
 
 async def run_elastic_soak(seed: int) -> dict:
-    """Elasticity chaos soak (``bench.py --elastic``): the same seeded
-    episode — join-triggered rebalance, graceful drain to ``left``,
-    kill -9 mid-drain, partition healing into a fenced stale owner — run
-    TWICE with the same seed. The report's ``violations`` list is empty
-    iff every run held:
+    """Elasticity chaos soak (``tests/test_soaks.py``, ``elastic``): the
+    same seeded episode — join-triggered rebalance, graceful drain to
+    ``left``, kill -9 mid-drain, partition healing into a fenced stale
+    owner — run TWICE with the same seed. The report's ``violations`` list
+    is empty iff every run held:
 
     1. **Zero confirmed loss** — every confirm-gated body is consumable
        from its queue's final holder, across a join move, two drains, a
@@ -2512,9 +2513,9 @@ async def _tenant_run(seed: int) -> dict:
 
 
 async def run_tenant_soak(seed: int) -> dict:
-    """Noisy-neighbor tenancy soak (``bench.py --tenant``): the seeded
-    three-tenant episode run TWICE with the same seed. ``violations`` is
-    empty iff every run held:
+    """Noisy-neighbor tenancy soak (``tests/test_soaks.py``, ``tenant``):
+    the seeded three-tenant episode run TWICE with the same seed.
+    ``violations`` is empty iff every run held:
 
     1. **Quota throttles the aggressor, not the victim** — the token
        bucket gates on exactly the 16th publish, each registry tick
@@ -2550,9 +2551,9 @@ async def run_tenant_soak(seed: int) -> dict:
 
 async def run_tenant_churn(cycles: int = 10000, *,
                            amqp_every: int = 100) -> dict:
-    """Tenant-churn leak check (``bench.py --tenant-churn``): ``cycles``
-    define/remove rounds against a live registry — every ``amqp_every``-th
-    round also creates the tenant's vhost, authenticates as its user,
+    """Tenant-churn leak check (``tests/test_soaks.py``, ``tenant_churn``):
+    ``cycles`` define/remove rounds against a live registry — every
+    ``amqp_every``-th round also creates the tenant's vhost, authenticates as its user,
     declares/publishes confirmed, disconnects and deletes the vhost. At
     the end every registry index, auth view, accounted byte and vhost
     must be exactly back at baseline: a surviving slot is a leak in the

@@ -2,11 +2,11 @@
 
 The control plane (rpc.py) serializes every payload through the generic
 AMQP field-table codec over ONE connection per peer — fine for queue
-declares and membership gossip, ruinous for the per-message hot path
-(BENCH_r05: the 2-node numbers ran at well under half of single-node
-throughput). This module is the data plane the bench trajectory asked for,
-in the spirit of RPCAcc's "strip generic serialization out of the RPC hot
-path" and the Pulsar paper's broker-to-broker batching (PAPERS.md):
+declares and membership gossip, ruinous for the per-message hot path: a
+table encode and decode and a round trip for every message. This module is
+the per-message path, in the spirit of RPCAcc's "strip generic
+serialization out of the RPC hot path" and the Pulsar paper's
+broker-to-broker batching (PAPERS.md):
 
 - **Binary zero-copy frames.** Message bodies and property headers travel
   as length-prefixed raw bytes. Encode never joins them into a frame (the

@@ -4,8 +4,8 @@ The split mirrors ``chanamq_tpu/models``: the engine is a deterministic
 function of one input snapshot plus its own hysteresis counters — no
 clocks, no broker references, no I/O — so the same telemetry series
 always produces the same decision log (asserted byte-for-byte in
-tests/test_control.py and by ``bench.py --control``), and any logged
-decision can be replayed from the inputs recorded alongside it.
+tests/test_control.py and by tests/test_soaks.py's ``control`` case), and
+any logged decision can be replayed from the inputs recorded alongside it.
 
 Three decision kinds, evaluated in a fixed order each tick:
 

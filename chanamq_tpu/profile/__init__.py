@@ -9,8 +9,9 @@ Three coupled parts, all always-cheap enough to leave on in production:
   sampling decision on the hot path: every seam is gated on the same
   module-level ``ACTIVE is None`` check chaos and trace use, and the
   per-message stages accumulate at batch granularity wherever a batch
-  exists (router flush, dispatch pass, scan pass), so the enabled cost
-  stays inside the 2% budget ``bench.py --profile-overhead`` enforces.
+  exists (router flush, dispatch pass, scan pass), so the enabled cost is
+  paid per batch, not per message (what it comes to on the chip's host:
+  ROADMAP.md D7).
 - a **sampling wall profiler + stall attribution**: an off-loop thread
   samples ``sys._current_frames()`` into folded-stack counts (flamegraph
   collapsed format at ``GET /admin/profile/stacks``), doubles as the

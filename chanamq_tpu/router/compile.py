@@ -6,7 +6,8 @@ dictionaries for the parts where a hash lookup already wins, and dense
 tokenized matrices for the parts where a data-parallel kernel wins — and
 evaluates whole publish batches against it.
 
-Compilation strategy (driven by 1-core measurements, see BENCH_r08.json):
+Compilation strategy (a dict probe needs no launch, and a launch costs the
+event loop 1.5 ms or more whatever it carries: PERF.md §5):
 
 - **Exact patterns** (direct bindings; topic patterns without wildcards)
   stay a host dict ``routing_key -> queue names``. A dict probe is ~0.1µs;

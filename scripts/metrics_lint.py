@@ -17,7 +17,7 @@ A name counts as documented when README.md contains it verbatim, via a
 brace group (`chanamq_slo_{budget_remaining,burn_rate}`), or via a
 prefix wildcard (`chanamq_stream_*`). Run with no arguments from
 anywhere inside the repo; exits 1 listing every undocumented series so
-tier1.sh can gate on it.
+scripts/tier1.sh can gate on it.
 """
 
 from __future__ import annotations

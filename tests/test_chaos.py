@@ -560,9 +560,12 @@ async def test_promotion_after_dropped_ship_batch_heals_via_resync():
 # The seeded soak: every invariant under partition + crash + slow store
 # ---------------------------------------------------------------------------
 
-async def test_seeded_soak_holds_all_invariants():
+@pytest.mark.parametrize("wal", [False, True], ids=["memory", "wal"])
+async def test_seeded_soak_holds_all_invariants(wal):
+    """`wal`: every node's store WAL-fronted SQLite, so that a confirm
+    waits for the group commit's fsync while the faults play."""
     report = await asyncio.wait_for(
-        run_soak(42, messages=80, stream_records=30), timeout=120)
+        run_soak(42, messages=80, stream_records=30, wal=wal), timeout=150)
     assert report["violations"] == []
     assert report["crashed"] is True
     assert report["promotions"] == 1

@@ -1,9 +1,9 @@
 """Node lifecycle tests: graceful drain/decommission, handoff retry and
 rollback, holdership fencing epochs, and follower retirement.
 
-The chaos-soak twin (bench.py --elastic) exercises the same machinery at
-cluster scale under a seeded fault plan; these tests pin the individual
-contracts so a regression is named, not just detected."""
+The chaos-soak twin (tests/test_soaks.py, ``elastic``) exercises the same
+machinery at cluster scale under a seeded fault plan; these tests pin the
+individual contracts so a regression is named, not just detected."""
 
 import asyncio
 

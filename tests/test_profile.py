@@ -1,13 +1,12 @@
-"""Continuous profiling: cost ledger, sampler/watchdog/GC hooks, admin
-surface, and the bench-trajectory regression verdict.
+"""Continuous profiling: cost ledger, sampler/watchdog/GC hooks and the
+admin surface.
 
 Covers the PR-14 observability subsystem end to end: the fixed-stage
 accumulators against a hand-driven oracle, the ``ACTIVE is None``
 disabled path, folded-stack sampling of a synthetic busy loop, the
 event-loop stall watchdog (capture + ring + counter + structured log
 line), GC pause attribution, the /admin/profile route conventions
-alongside the PR-6 telemetry ones, Prometheus export, and the pure
-``regress_evaluate`` verdict on doctored trajectory records.
+alongside the PR-6 telemetry ones, and the Prometheus export.
 """
 
 import asyncio
@@ -19,7 +18,6 @@ import time
 
 import pytest
 
-import bench
 from chanamq_tpu import profile
 from chanamq_tpu.broker.server import BrokerServer
 from chanamq_tpu.client import AMQPClient
@@ -398,63 +396,3 @@ async def test_prometheus_profile_series(profile_stack):
         assert f'stage="{name}"' in text, name
     assert "chanamq_profile_samples_total" in text
     assert "chanamq_profile_gc_pauses_total" in text
-
-
-# ---------------------------------------------------------------------------
-# regression verdict on doctored trajectory records
-# ---------------------------------------------------------------------------
-
-
-def _rec(wall, cpu, scenario="s"):
-    return {"scenario": scenario, "us_per_msg": wall, "cpu_us_per_msg": cpu}
-
-
-def test_regress_both_over_fails():
-    v = bench.regress_evaluate(_rec(130.0, 23.0), _rec(100.0, 20.0))
-    assert v["wall_over"] and v["cpu_over"] and v["regressed"]
-
-
-def test_regress_single_band_noise_passes():
-    # wall spiked (steal burst) but CPU held: not a regression
-    v = bench.regress_evaluate(_rec(130.0, 20.5), _rec(100.0, 20.0))
-    assert v["wall_over"] and not v["cpu_over"] and not v["regressed"]
-    # CPU crept but wall held: not a regression either
-    v = bench.regress_evaluate(_rec(105.0, 25.0), _rec(100.0, 20.0))
-    assert v["cpu_over"] and not v["wall_over"] and not v["regressed"]
-
-
-def test_regress_wall_only_fallback():
-    # old baseline without the CPU ledger: wall alone decides
-    v = bench.regress_evaluate(_rec(130.0, 23.0),
-                               {"scenario": "s", "us_per_msg": 100.0})
-    assert v["regressed"]
-    v = bench.regress_evaluate(_rec(115.0, 23.0),
-                               {"scenario": "s", "us_per_msg": 100.0})
-    assert not v["regressed"]
-
-
-def test_regress_boundary_is_strict():
-    # exactly at the band edge is NOT over — strictly greater regresses
-    v = bench.regress_evaluate(_rec(120.0, 22.0), _rec(100.0, 20.0))
-    assert not v["wall_over"] and not v["cpu_over"] and not v["regressed"]
-
-
-def test_trajectory_baseline_env_matching(tmp_path):
-    env = bench._env_fingerprint()
-    path = tmp_path / "traj.jsonl"
-    other = dict(env, cores=(env["cores"] or 0) + 64)
-    lines = [
-        {"scenario": "s", "us_per_msg": 10.0, "env": env, "ts": 1},
-        {"scenario": "s", "us_per_msg": 99.0, "env": other, "ts": 2},
-        {"scenario": "t", "us_per_msg": 55.0, "env": env, "ts": 3},
-        {"scenario": "s", "us_per_msg": 12.0, "env": env, "ts": 4},
-    ]
-    with open(path, "w") as f:
-        for rec in lines:
-            f.write(json.dumps(rec) + "\n")
-        f.write("not json\n")  # corrupt tail lines are skipped, not fatal
-    base = bench.trajectory_baseline("s", str(path))
-    # latest matching-env line for the scenario wins
-    assert base["ts"] == 4 and base["us_per_msg"] == 12.0
-    assert bench.trajectory_baseline("missing", str(path)) is None
-    assert bench.trajectory_baseline("s", str(tmp_path / "ghost")) is None
