@@ -14,11 +14,13 @@ from __future__ import annotations
 import enum
 import struct
 import time
+from bisect import bisect_left
 from typing import TYPE_CHECKING, Any, Optional
 
 from .. import events, trace
 from ..amqp.command import AMQCommand
 from ..amqp.constants import FRAME_OVERHEAD
+from ..amqp.frame import ENC_META
 from ..amqp.methods import Basic
 from .entities import Delivery, Queue, QueuedMessage
 
@@ -26,6 +28,16 @@ if TYPE_CHECKING:  # pragma: no cover
     from .connection import AMQPConnection
 
 _FRAME_HDR = struct.Struct(">BHI").pack
+_ENC_META_PACK = ENC_META.pack
+
+
+def _exrk_of(msg) -> bytes:
+    """The length-prefixed exchange + routing-key slice of basic.deliver,
+    built once for a message whose publish frame did not leave it."""
+    ex = msg.exchange.encode("utf-8")
+    rk = msg.routing_key.encode("utf-8")
+    exrk = msg.exrk_raw = bytes((len(ex),)) + ex + bytes((len(rk),)) + rk
+    return exrk
 
 
 class ChannelMode(enum.Enum):
@@ -40,7 +52,7 @@ class Consumer:
     __slots__ = (
         "tag", "channel", "queue", "no_ack", "exclusive", "arguments",
         "priority", "unacked_count", "unacked_size", "buffered_bytes",
-        "slow", "_deliver_prefix",
+        "slow", "takes_runs", "_deliver_prefix",
     )
 
     def __init__(
@@ -70,6 +82,12 @@ class Consumer:
         # over the bound (detected once per episode, see can_take)
         self.buffered_bytes = 0
         self.slow = False
+        # a dispatch pass may hand this consumer the queue's head run in
+        # one loop (ServerChannel.deliver_run): only the plain local no_ack
+        # consumer, whose deliveries leave nothing outstanding. A subclass
+        # delivers per message, as does the cluster's RemoteConsumer, which
+        # has no such attribute
+        self.takes_runs = no_ack and type(self) is Consumer
         # precomputed basic.deliver method-payload prefix:
         # class 60, method 60, shortstr consumer-tag
         tag_b = tag.encode("utf-8")
@@ -233,10 +251,7 @@ class ServerChannel:
             # flush point (connection.flush_egress)
             exrk = msg.exrk_raw
             if exrk is None:
-                ex = msg.exchange.encode("utf-8")
-                rk = msg.routing_key.encode("utf-8")
-                exrk = msg.exrk_raw = (
-                    bytes((len(ex),)) + ex + bytes((len(rk),)) + rk)
+                exrk = _exrk_of(msg)
             conn.egress_deliver(
                 self.id, consumer._deliver_prefix, tag, qm.redelivered,
                 exrk, msg.header_payload(), body)
@@ -273,6 +288,137 @@ class ServerChannel:
         consumer.unacked_size += len(body)
         return delivery
 
+    def deliver_run(self, consumer: Consumer, queue: Queue, messages) -> int:
+        """The head run of a dispatch pass: Queue._dispatch's loop body and
+        deliver() above as one loop, for the one plain no_ack consumer
+        (`takes_runs`) of a FIFO queue. What those read that cannot change
+        inside a synchronous pass is read once; the counters they bump per
+        message are added once a stretch; the publish->deliver histogram
+        takes one clock read a run. Pops and buffers head messages up to
+        the first one for which a per-message check is not trivially true
+        (dead, any TTL, a passivated body, the write watermark, the
+        consumer-buffer bound) and leaves that one, unpopped, to the
+        per-message loop: a prefix of the one pass, never a second policy.
+        Returns the deliveries made; 0 when the pass as a whole is not a
+        run's (no native encoder, channel flow off, a trace sampler, a
+        firehose tap, a tenant latency histogram).
+
+        A stretch ends with the run, or at a message whose last reference
+        went: unrefer_n's tail may cross a flow stage, whose listeners
+        write to connections (Connection.Unblocked) and so flush what is
+        pending. Every count is therefore handed over before the tail, and
+        the connection's buffer is read anew after it: between stretches
+        the broker's state is the per-message path's at that message."""
+        conn = self.connection
+        if (conn._egress is None or not self.flow_active or self.closed
+                or trace.ACTIVE is not None):
+            return 0
+        fh = events.FIREHOSE
+        if fh is not None and fh.tap_bindings:
+            return 0
+        tenant = conn.tenant
+        if tenant is not None and tenant.latency_hist is not None:
+            return 0
+        broker = conn.broker
+        metrics = broker.metrics
+        limit = broker.flow_consumer_buffer
+        frame_max = conn.frame_max
+        chunk = frame_max - FRAME_OVERHEAD if frame_max else 0
+        prefix = consumer._deliver_prefix
+        plen = len(prefix)
+        fixed = 25 + plen
+        cid = self.id
+        hist = metrics.publish_to_deliver_us
+        buckets = hist.buckets
+        bounds = hist.BOUNDS
+        now_ns = time.perf_counter_ns()
+        popleft = messages.popleft
+        delivered = 0
+        while messages:
+            pend = conn._egress_pending
+            opened = not pend
+            room = first_room = conn.egress_room()
+            tag = first_tag = self._delivery_tag
+            buffered = consumer.buffered_bytes
+            top_offset = queue.last_consumed
+            top = last_ref = None
+            ready = nbytes = waited_ns = 0
+            try:
+                while messages:
+                    qm = messages[0]
+                    msg = qm.message
+                    body = msg.body
+                    size = qm.body_size
+                    if (qm.dead or qm.expire_at_ms is not None
+                            or body is None or room <= 0
+                            or (limit and buffered
+                                and buffered + size > limit)):
+                        break
+                    exrk = msg.exrk_raw
+                    if exrk is None:
+                        exrk = _exrk_of(msg)
+                    header = msg.header_raw
+                    if header is None:
+                        header = msg.header_payload()
+                    popleft()
+                    tag += 1
+                    elen = len(exrk)
+                    hlen = len(header)
+                    blen = len(body)
+                    pend += (_ENC_META_PACK(
+                        cid, tag, 1 if qm.redelivered else 0,
+                        plen, elen, hlen, blen), prefix, exrk, header, body)
+                    # exact wire size, as egress_deliver counts it
+                    if not blen:
+                        room -= fixed + elen + hlen
+                    elif chunk:
+                        room -= (fixed + elen + hlen + blen
+                                 + 8 * -(-blen // chunk))
+                    else:
+                        room -= fixed + elen + hlen + blen + 8
+                    ready += size
+                    nbytes += blen
+                    buffered += blen
+                    waited = now_ns - msg.published_ns
+                    waited_ns += waited
+                    buckets[bisect_left(bounds, waited / 1000.0)] += 1
+                    offset = qm.offset
+                    if offset > top_offset:
+                        top_offset = offset
+                        top = qm
+                    left = msg.refer_count = msg.refer_count - 1
+                    if left <= 0 and (msg.accounted or msg.persisted
+                                      or msg.paged):
+                        last_ref = msg
+                        break
+            finally:
+                n = tag - first_tag
+                if n:
+                    delivered += n
+                    self._delivery_tag = tag
+                    if opened:
+                        conn.egress_opened()
+                    conn._egress_records += n
+                    conn._egress_bytes += first_room - room
+                    conn.delivered_msgs += n
+                    if limit:
+                        consumer.buffered_bytes = buffered
+                    metrics.delivered_msgs += n
+                    metrics.delivered_bytes += nbytes
+                    metrics.dispatch_run_msgs += n
+                    hist.count += n
+                    hist.total_us += waited_ns // 1000
+                    queue.ready_bytes -= ready
+                    if queue._counted:
+                        broker.queue_depth -= n
+                    queue.n_delivered += n
+                    if top is not None:
+                        queue._advance_watermark(top)
+            if last_ref is None:
+                break
+            broker.unrefer_n(last_ref, 0)
+        return delivered
+
     def _render_deliver(
         self, consumer: Consumer, tag: int, redelivered: bool, msg, body: bytes
     ) -> bytes:
@@ -280,10 +426,7 @@ class ServerChannel:
         # publish frame when possible, else built once and cached
         exrk = msg.exrk_raw
         if exrk is None:
-            ex = msg.exchange.encode("utf-8")
-            rk = msg.routing_key.encode("utf-8")
-            exrk = msg.exrk_raw = (
-                bytes((len(ex),)) + ex + bytes((len(rk),)) + rk)
+            exrk = _exrk_of(msg)
         method_payload = b"".join((
             consumer._deliver_prefix,
             tag.to_bytes(8, "big"),

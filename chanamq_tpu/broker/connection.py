@@ -354,10 +354,7 @@ class AMQPConnection:
         stream/cluster delivery paths)."""
         pend = self._egress_pending
         if not pend:
-            self.broker.egress_dirty.add(self)
-            if not self._egress_guard_scheduled:
-                self._egress_guard_scheduled = True
-                asyncio.get_event_loop().call_soon(self._egress_guard)
+            self.egress_opened()
         plen = len(prefix)
         elen = len(exrk)
         hlen = len(header)
@@ -376,6 +373,21 @@ class AMQPConnection:
             else:
                 size += blen + 8
         self._egress_bytes += size
+
+    def egress_opened(self) -> None:
+        """The first record of a batch was (or is about to be) buffered:
+        name this connection to the dispatch pass's end-of-pass flush and
+        arm the call_soon guard."""
+        self.broker.egress_dirty.add(self)
+        if not self._egress_guard_scheduled:
+            self._egress_guard_scheduled = True
+            asyncio.get_event_loop().call_soon(self._egress_guard)
+
+    def egress_room(self) -> int:
+        """Wire bytes this connection may still buffer before it is
+        write_saturated (<= 0: it is). ServerChannel.deliver_run counts
+        its run's own bytes down from it."""
+        return WRITE_HIGH_WATERMARK - self._out_bytes - self._egress_bytes
 
     def _egress_guard(self) -> None:
         # safety net for deliveries buffered outside a queue dispatch pass

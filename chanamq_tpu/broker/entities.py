@@ -613,12 +613,21 @@ class Queue:
         # other ledger window can interleave inside it.
         prof = profile.ACTIVE
         t_pass = 0
-        n_before = 0
+        n_before = self.n_delivered
         if prof is not None:
             t_pass = time.thread_time_ns()
-            n_before = self.n_delivered
         new_unacks: list[tuple[int, int, int, Optional[int]]] = []
         messages = self.messages
+        consumers = self.consumers
+        if (len(consumers) == 1 and messages
+                and getattr(consumers[0], "takes_runs", False)
+                and self.max_priority is None and not self.single_active
+                and self._prio_groups is None):
+            # the head run: one plain no_ack consumer of a FIFO queue takes
+            # every head message for which each check below comes out
+            # trivially true in one loop (ServerChannel.deliver_run); the
+            # loop below goes on from the first message it left
+            consumers[0].channel.deliver_run(consumers[0], self, messages)
         while messages and self.consumers:
             # expiry is checked on the head inline (no clock read for the
             # overwhelming TTL-less case); head checks and the pop below
@@ -673,12 +682,14 @@ class Queue:
             for conn in list(dirty):
                 conn.flush_egress()
             dirty.clear()
+        delivered = self.n_delivered - n_before
+        if delivered:
+            self.broker.metrics.dispatch_passes += 1
         if prof is not None:
             dt = time.thread_time_ns() - t_pass
             sns, sc = prof.stage_ns, prof.stage_calls
             sns[profile.DISPATCH] += dt
             sc[profile.DISPATCH] += 1
-            delivered = self.n_delivered - n_before
             if delivered:
                 sns[profile.DELIVER] += dt
                 sc[profile.DELIVER] += delivered

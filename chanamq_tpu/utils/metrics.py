@@ -277,6 +277,13 @@ class Metrics:
         self.native_egress_bytes = 0
         self.native_egress_fallbacks = 0
         self.native_pool_exhausted = 0
+        # the classic queues' dispatch passes (broker/entities.py
+        # Queue._dispatch): passes that delivered anything, and the
+        # deliveries made inside a head run (ServerChannel.deliver_run)
+        # rather than one by one. With delivered_msgs: deliveries a pass,
+        # and the share of them the run takes
+        self.dispatch_passes = 0
+        self.dispatch_run_msgs = 0
         # continuous profiling (chanamq_tpu/profile/): stack-sampler
         # samples taken, event-loop callbacks caught over the slow
         # threshold, and collector pauses seen by the gc hook. All zero
@@ -522,6 +529,8 @@ class Metrics:
             "native_egress_bytes": self.native_egress_bytes,
             "native_egress_fallbacks": self.native_egress_fallbacks,
             "native_pool_exhausted": self.native_pool_exhausted,
+            "dispatch_passes": self.dispatch_passes,
+            "dispatch_run_msgs": self.dispatch_run_msgs,
             "profile_samples_total": self.profile_samples_total,
             "profile_slow_callbacks_total": self.profile_slow_callbacks_total,
             "profile_gc_pauses_total": self.profile_gc_pauses_total,

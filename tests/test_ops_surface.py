@@ -515,6 +515,50 @@ async def test_prometheus_metrics_endpoint(stack):
     await c.close()
 
 
+async def test_dispatch_counters_on_both_surfaces(stack):
+    """`dispatch_passes` and `dispatch_run_msgs` are on /admin/overview and
+    /metrics and add up against `delivered_msgs`: the no_ack consumer's
+    deliveries are made inside head runs, the acked consumer's one by one."""
+    server, admin = stack
+    c = await AMQPClient.connect("127.0.0.1", server.bound_port)
+    ch = await c.channel()
+    got = {"run_q": [], "acked_q": []}
+    await ch.queue_declare("run_q")
+    await ch.queue_declare("acked_q")
+    for i in range(40):
+        ch.basic_publish(b"r%d" % i, routing_key="run_q")
+    for i in range(15):
+        ch.basic_publish(b"a%d" % i, routing_key="acked_q")
+    await ch.basic_consume("run_q", got["run_q"].append, no_ack=True)
+    await ch.basic_consume("acked_q", got["acked_q"].append, no_ack=False)
+    for i in range(40, 60):
+        ch.basic_publish(b"r%d" % i, routing_key="run_q")
+    for _ in range(100):
+        if len(got["run_q"]) == 60 and len(got["acked_q"]) == 15:
+            break
+        await asyncio.sleep(0.02)
+    assert [m.body for m in got["run_q"]] == [b"r%d" % i for i in range(60)]
+    assert [m.body for m in got["acked_q"]] == [b"a%d" % i for i in range(15)]
+
+    status, overview = await http_req(admin.bound_port, "/admin/overview")
+    assert status == 200
+    metrics = overview["metrics"]
+    assert metrics["delivered_msgs"] == 75
+    assert metrics["dispatch_run_msgs"] == 60
+    # the backlog of 40 went in one pass; no pass is empty, none counted twice
+    assert 2 <= metrics["dispatch_passes"] <= 75 - 39
+
+    status, _ctype, text = await http_text(admin.bound_port, "/metrics")
+    assert status == 200
+    prom = dict(line.rsplit(" ", 1) for line in text.splitlines()
+                if line.startswith("chanamq_dispatch_"))
+    assert prom == {
+        "chanamq_dispatch_passes": str(metrics["dispatch_passes"]),
+        "chanamq_dispatch_run_msgs": "60",
+    }
+    await c.close()
+
+
 async def test_vhost_permissions_enforced():
     """chana.mq.auth.permissions: a user with an allowlist may open only
     those vhosts; users absent from the map stay unrestricted."""
