@@ -499,6 +499,7 @@ class ServerChannel:
         self.unacked.pop(delivery.delivery_tag, None)
         self._release_budget(delivery)
         self.connection.acked_msgs += 1
+        self.connection.broker.metrics.acked_msgs += 1
         delivery.queue.ack(delivery)
         delivery.queue.schedule_dispatch()
 

@@ -2127,8 +2127,7 @@ class AMQPConnection:
             if channel.mode is ChannelMode.TX:
                 self._tx_stash_settles(channel, "ack", deliveries)
             else:
-                for delivery in deliveries:
-                    channel.ack(delivery)
+                self._ack_all(channel, deliveries)
         elif isinstance(method, am.Basic.Nack):
             deliveries = channel.resolve_tags(method.delivery_tag, method.multiple)
             self._check_settled_tags(channel, method, deliveries)
@@ -2214,9 +2213,18 @@ class AMQPConnection:
         self._fused_skip = 1
         deliveries = channel.resolve_tags(tag, multiple)
         self._check_settled_raw(channel, deliveries, tag, multiple, 60, 80)
+        self._ack_all(channel, deliveries)
+        return 1
+
+    def _ack_all(self, channel: ServerChannel, deliveries: list) -> None:
+        """Settle what one Basic.Ack frame covers (`multiple` included),
+        timed as one: `settle_ns` over `acked_msgs` is the settle path's
+        wall an acknowledged delivery. A counter and no profiler span: a
+        fused ack is handled inside `conn.ingress`, and spans stay flat."""
+        t0 = time.perf_counter_ns()
         for delivery in deliveries:
             channel.ack(delivery)
-        return 1
+        self.broker.metrics.settle_ns += time.perf_counter_ns() - t0
 
     def _arm_confirm(self, channel: ServerChannel) -> Optional[int]:
         self._has_published = True

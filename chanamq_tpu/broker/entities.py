@@ -31,7 +31,7 @@ import time
 from collections import deque
 from typing import TYPE_CHECKING, Any, Callable, Optional
 
-from .. import profile, trace
+from .. import device, profile, trace
 from ..amqp.properties import BasicProperties
 from ..semantics.priority import PriorityFan
 from ..store.api import StoredMessage
@@ -559,26 +559,31 @@ class Queue:
                 self._wm_dirty = True
                 asyncio.get_event_loop().call_soon(self._persist_watermark)
 
+    # the two callbacks a dispatch pass of a durable queue schedules carry
+    # one profiler span name, `store.deliver`: a span a callback (a queue and
+    # a loop tick), never one a message; the pass itself keeps none (PR 28)
     def _flush_row_deletes(self) -> None:
         offsets, self._row_del_buf = self._row_del_buf, []
         if offsets and not self.deleted:
-            self.broker.store_bg(
-                self.broker.store.delete_queue_msgs_offsets(
-                    self.vhost, self.name, offsets))
-            if self.repl is not None:
-                self.repl.append("row_del", {"offs": offsets})
+            with device.span("store.deliver"):
+                self.broker.store_bg(
+                    self.broker.store.delete_queue_msgs_offsets(
+                        self.vhost, self.name, offsets))
+                if self.repl is not None:
+                    self.repl.append("row_del", {"offs": offsets})
 
     def _persist_watermark(self) -> None:
         self._wm_dirty = False
         if self.deleted:
             return
-        self.broker.store_bg(
-            self.broker.store.update_queue_last_consumed(
-                self.vhost, self.name, self.last_consumed
+        with device.span("store.deliver"):
+            self.broker.store_bg(
+                self.broker.store.update_queue_last_consumed(
+                    self.vhost, self.name, self.last_consumed
+                )
             )
-        )
-        if self.repl is not None:
-            self.repl.append("watermark", {"wm": self.last_consumed})
+            if self.repl is not None:
+                self.repl.append("watermark", {"wm": self.last_consumed})
 
     def flush_store_buffers(self) -> None:
         """Flush per-tick coalescing buffers now (shutdown path)."""
@@ -932,13 +937,17 @@ class Queue:
             prof.stage_calls[profile.SETTLE] += 1
 
     def _flush_unack_deletes(self) -> None:
+        # one callback a queue and a loop tick for every ack it took since
+        # (`_settle_store`): the settle rows' hand-over to the store, under
+        # the profiler span `store.settle`
         ids, self._unack_del_buf = self._unack_del_buf, []
         if ids and not self.deleted:
-            self.broker.store_bg(
-                self.broker.store.delete_queue_unacks(self.vhost, self.name, ids)
-            )
-            if self.repl is not None:
-                self.repl.append("unack_del", {"ids": ids})
+            with device.span("store.settle"):
+                self.broker.store_bg(
+                    self.broker.store.delete_queue_unacks(
+                        self.vhost, self.name, ids))
+                if self.repl is not None:
+                    self.repl.append("unack_del", {"ids": ids})
 
     def drop(self, delivery: Delivery) -> None:
         """Reject without requeue: same store cleanup as ack, then the

@@ -156,6 +156,10 @@ class Metrics:
         # write-ahead log engine (chanamq_tpu/wal/): append/commit volume,
         # checkpoint + recovery accounting, stream-segment tier offload and
         # key compaction. All zero unless chana.mq.wal.enabled with a store.
+        # wal_appends counts one RECORD of any kind (a declare, a bind, a
+        # message with its first queue, each further queue, a watermark, a
+        # settle, a whole tx_batch), not one message: what counts one
+        # persisted message on one queue is wal_queue_msg_records, below
         self.wal_appends = 0
         self.wal_append_bytes = 0
         self.wal_commits = 0
@@ -178,6 +182,25 @@ class Metrics:
         self.wal_tx_batches = 0
         self.wal_tx_batch_ops = 0
         self.wal_commit_us = Histogram()
+        # the log per message and queue, and the settle path (PR 35). One
+        # message-and-queue row handed to the log, whichever path frames it
+        # (the fused insert_published, a plain insert_queue_msg, each such
+        # op of a sealed tx_batch; an aborted scope adds nothing), and the
+        # same rows again once the commit that covers them has returned
+        # from its write + fsync (a failed commit adds nothing): records -
+        # committed is what a crash now would lose. wal_settle_rows: ids
+        # handed to delete_queue_unacks, the row an ack of a persistent
+        # delivery on a durable queue removes. wal_commit_ns: wall of every
+        # commit's executor job (write + fsync), the histogram's own
+        # stamps. acked_msgs: deliveries acknowledged (ServerChannel.ack),
+        # broker-wide; settle_ns: wall of handling the Basic.Ack frames
+        # that settled them, one pair of clock reads a frame
+        self.wal_queue_msg_records = 0
+        self.wal_queue_msgs_committed = 0
+        self.wal_settle_rows = 0
+        self.wal_commit_ns = 0
+        self.acked_msgs = 0
+        self.settle_ns = 0
         # multi-process sharding (chanamq_tpu/shard/): cross-shard UDS
         # pushes, ownership re-hashes observed on sibling death, and the
         # restart generation the supervisor hands a respawned worker.
@@ -500,6 +523,12 @@ class Metrics:
             "wal_memtable_hits": self.wal_memtable_hits,
             "wal_tx_batches": self.wal_tx_batches,
             "wal_tx_batch_ops": self.wal_tx_batch_ops,
+            "wal_queue_msg_records": self.wal_queue_msg_records,
+            "wal_queue_msgs_committed": self.wal_queue_msgs_committed,
+            "wal_settle_rows": self.wal_settle_rows,
+            "wal_commit_ns": self.wal_commit_ns,
+            "acked_msgs": self.acked_msgs,
+            "settle_ns": self.settle_ns,
             "wal_commit_p50_us": self.wal_commit_us.percentile_us(0.50),
             "wal_commit_p99_us": self.wal_commit_us.percentile_us(0.99),
             "wal_commit_mean_us": self.wal_commit_us.mean_us,

@@ -46,6 +46,23 @@ barriers whose windows overlap it raise (same per-caller attribution
 contract as SqliteStore seq intervals); a failed inner write surfaces
 through ``error_count`` (readiness) and blocks the checkpoint from
 advancing — the WAL keeps the truth until the index catches up.
+
+What the counters count (``Metrics``): ``wal_appends`` is one framed
+RECORD of any kind — a declare, a bind, a message with its first queue,
+each further queue, a watermark, a settle, a whole ``tx_batch`` — so it
+is not a count of messages.  One persisted message on one queue is
+``wal_queue_msg_records`` (+1 per message-and-queue row handed to the
+log, whichever of the three write paths frames it: the fused
+``insert_published``, a plain ``insert_queue_msg``, each such op of a
+sealed ``tx_batch``; an aborted scope adds nothing) and, once the commit
+that covers the row has returned from its write + fsync,
+``wal_queue_msgs_committed``.  ``wal_settle_rows`` counts the ids handed
+to ``delete_queue_unacks`` (the row an ack removes); ``wal_commit_ns``
+the wall of every successful commit's executor job.
+
+Loop-side work shows in a profiler trace as ``device.span`` rows:
+``wal.commit`` (the two halves of a commit either side of the executor
+call) and ``wal.checkpoint`` (the memtable drain's loop-side part).
 """
 
 from __future__ import annotations
@@ -58,7 +75,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import replace as dc_replace
 from typing import Optional
 
-from .. import profile, trace
+from .. import device, profile, trace
 from ..store.api import StoreService
 from ..utils.metrics import Metrics
 from .codec import (
@@ -95,6 +112,8 @@ _TRACE_CAP = 128
 # drained batches below this run the coalescer inline; larger ones go to
 # an executor thread so the fold never stalls the event loop
 _COALESCE_INLINE = 64
+# the ops that log one message on one queue (wal_queue_msg_records)
+_QUEUE_MSG_OPS = frozenset(("insert_queue_msg", "insert_published"))
 
 # ops that commute with every key the coalescer tracks (message ids,
 # queue-log rows, unack rows) — they pass through without resetting the
@@ -309,6 +328,9 @@ class WalStore(StoreService):
         self._buf_bytes = 0
         self._buf_last_lsn = 0
         self._buf_traces: list = []
+        # message-and-queue rows among _buf's frames: swapped with _buf at
+        # commit and added to wal_queue_msgs_committed once it is durable
+        self._buf_queue_msgs = 0
         self._durable_lsn = 0    # last LSN on stable storage
         self._resolved_lsn = 0   # last LSN whose commit was attempted
         self._checkpoint_lsn = 0
@@ -446,6 +468,9 @@ class WalStore(StoreService):
         m = self.metrics
         m.wal_appends += 1
         m.wal_append_bytes += n
+        if op in _QUEUE_MSG_OPS:
+            self._buf_queue_msgs += 1
+            m.wal_queue_msg_records += 1
         if not self._wake.is_set():
             self._wake.set()
 
@@ -590,26 +615,32 @@ class WalStore(StoreService):
             self._drain_task = None
 
     async def _drain_run(self) -> None:
-        self._drain_kicked = False
-        if self._stash is not None:
-            self._flush_stash()
-        ops = self._pending
-        self._pending = []
-        self._pending_bytes = 0
-        # rotate the overlay: the outgoing generation keeps serving reads
-        # for one more interval (its rows reach the inner FIFO below, but
-        # a dict hit beats the executor round trip); the one before ages out
-        self._mem_prev = self._mem_msgs
-        self._mem_msgs = {}
+        # loop-side halves under one span name, `wal.checkpoint` (a drain
+        # runs at each checkpoint and when the memtable overgrows); the
+        # coalescer's executor trip between them is not the loop's time
+        with device.span("wal.checkpoint"):
+            self._drain_kicked = False
+            if self._stash is not None:
+                self._flush_stash()
+            ops = self._pending
+            self._pending = []
+            self._pending_bytes = 0
+            # rotate the overlay: the outgoing generation keeps serving
+            # reads for one more interval (its rows reach the inner FIFO
+            # below, but a dict hit beats the executor round trip); the one
+            # before ages out
+            self._mem_prev = self._mem_msgs
+            self._mem_msgs = {}
         if len(ops) >= _COALESCE_INLINE:
             loop = self._loop or asyncio.get_running_loop()
             net, elided = await loop.run_in_executor(None, _coalesce_ops, ops)
         else:
             net, elided = _coalesce_ops(ops)
-        self._forward(net)
-        m = self.metrics
-        m.wal_memtable_drains += 1
-        m.wal_memtable_elided += elided
+        with device.span("wal.checkpoint"):
+            self._forward(net)
+            m = self.metrics
+            m.wal_memtable_drains += 1
+            m.wal_memtable_elided += elided
 
     def _mem_get(self, msg_id):
         gen = self._mem_msgs
@@ -643,18 +674,23 @@ class WalStore(StoreService):
             pass
 
     async def _commit_once(self) -> None:
-        if self._stash is not None:
-            self._flush_stash()
-        frames = self._buf
-        if not frames:
-            self._resolve_waiters()
-            return
-        self._buf = []
-        self._buf_bytes = 0
-        traces = self._buf_traces
-        self._buf_traces = []
-        target = self._buf_last_lsn
-        data = b"".join(frames)
+        # the loop-side halves carry one span name, `wal.commit`; the
+        # executor's write + fsync between them is not the loop's time
+        with device.span("wal.commit"):
+            if self._stash is not None:
+                self._flush_stash()
+            frames = self._buf
+            if not frames:
+                self._resolve_waiters()
+                return
+            self._buf = []
+            self._buf_bytes = 0
+            queue_msgs = self._buf_queue_msgs
+            self._buf_queue_msgs = 0
+            traces = self._buf_traces
+            self._buf_traces = []
+            target = self._buf_last_lsn
+            data = b"".join(frames)
         writer = self._writer
         fsync = self.sync_mode == "fsync"
         seg_cap = self.segment_bytes
@@ -684,31 +720,34 @@ class WalStore(StoreService):
             self._resolve_waiters()
             return
         t1 = time.perf_counter_ns()
-        self._durable_lsn = target
-        self._resolved_lsn = target
-        m = self.metrics
-        m.wal_commits += 1
-        if fsync:
-            m.wal_fsyncs += 1
-        m.wal_commit_us.observe_us((t1 - t0) / 1000.0)
-        if rolled is not None:
-            self._sealed.append(
-                (writer.first_lsn, writer.last_lsn, writer.path, writer.size))
-            self._sealed_bytes += writer.size
-            self._writer = rolled
-            m.wal_segments_sealed += 1
-        if traces:
-            act = trace.ACTIVE
-            node = act.node if act is not None else "local"
-            for tr in traces:
-                tr.span(trace.WAL_COMMIT, t0, t1, node)
-        prof = profile.ACTIVE
-        if prof is not None:
-            # commit wall time is executor-side fsync work; one call per
-            # batch commit, so ns/calls reads as µs per commit batch
-            prof.stage_ns[profile.WAL_COMMIT] += t1 - t0
-            prof.stage_calls[profile.WAL_COMMIT] += 1
-        self._resolve_waiters()
+        with device.span("wal.commit"):
+            self._durable_lsn = target
+            self._resolved_lsn = target
+            m = self.metrics
+            m.wal_commits += 1
+            if fsync:
+                m.wal_fsyncs += 1
+            m.wal_queue_msgs_committed += queue_msgs
+            m.wal_commit_ns += t1 - t0
+            m.wal_commit_us.observe_us((t1 - t0) / 1000.0)
+            if rolled is not None:
+                self._sealed.append((writer.first_lsn, writer.last_lsn,
+                                     writer.path, writer.size))
+                self._sealed_bytes += writer.size
+                self._writer = rolled
+                m.wal_segments_sealed += 1
+            if traces:
+                act = trace.ACTIVE
+                node = act.node if act is not None else "local"
+                for tr in traces:
+                    tr.span(trace.WAL_COMMIT, t0, t1, node)
+            prof = profile.ACTIVE
+            if prof is not None:
+                # commit wall time is executor-side fsync work; one call per
+                # batch commit, so ns/calls reads as µs per commit batch
+                prof.stage_ns[profile.WAL_COMMIT] += t1 - t0
+                prof.stage_calls[profile.WAL_COMMIT] += 1
+            self._resolve_waiters()
 
     # -- checkpoint + segment truncation -------------------------------------
 
@@ -975,6 +1014,10 @@ class WalStore(StoreService):
         m.wal_append_bytes += n
         m.wal_tx_batches += 1
         m.wal_tx_batch_ops += len(ops)
+        queue_msgs = sum(1 for name, _ in ops if name in _QUEUE_MSG_OPS)
+        if queue_msgs:
+            self._buf_queue_msgs += queue_msgs
+            m.wal_queue_msg_records += queue_msgs
         if not self._wake.is_set():
             self._wake.set()
         return lsn
@@ -1159,8 +1202,9 @@ class WalStore(StoreService):
                              list(offsets))
 
     def delete_queue_unacks(self, vhost, queue, msg_ids):
-        return self._through("delete_queue_unacks", vhost, queue,
-                             list(msg_ids))
+        ids = list(msg_ids)
+        self.metrics.wal_settle_rows += len(ids)
+        return self._through("delete_queue_unacks", vhost, queue, ids)
 
     def archive_queue(self, vhost, queue):
         return self._through("archive_queue", vhost, queue)

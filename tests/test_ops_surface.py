@@ -559,6 +559,47 @@ async def test_dispatch_counters_on_both_surfaces(stack):
     await c.close()
 
 
+async def test_log_and_settle_counters_on_both_surfaces(stack):
+    """The six counters of the log per message and queue and of the settle
+    path are on /admin/overview and, typed as counters, on /metrics.
+    Without a store the log's stay 0 while acks are still counted."""
+    server, admin = stack
+    c = await AMQPClient.connect("127.0.0.1", server.bound_port)
+    ch = await c.channel()
+    got = []
+    await ch.queue_declare("settle_q")
+    for i in range(15):
+        ch.basic_publish(b"a%d" % i, routing_key="settle_q")
+    await ch.basic_consume("settle_q", got.append, no_ack=False)
+    for _ in range(100):
+        if len(got) == 15:
+            break
+        await asyncio.sleep(0.02)
+    ch.basic_ack(got[9].delivery_tag, multiple=True)  # one frame, ten acks
+    for msg in got[10:]:
+        ch.basic_ack(msg.delivery_tag)
+    for _ in range(100):
+        if server.broker.metrics.acked_msgs == 15:
+            break
+        await asyncio.sleep(0.02)
+
+    status, overview = await http_req(admin.bound_port, "/admin/overview")
+    assert status == 200
+    metrics = overview["metrics"]
+    assert metrics["acked_msgs"] == 15 and metrics["settle_ns"] > 0
+    log_side = ("wal_queue_msg_records", "wal_queue_msgs_committed",
+                "wal_settle_rows", "wal_commit_ns")
+    assert [metrics[name] for name in log_side] == [0, 0, 0, 0]
+
+    status, _ctype, text = await http_text(admin.bound_port, "/metrics")
+    assert status == 200
+    lines = text.splitlines()
+    for name in log_side + ("acked_msgs", "settle_ns"):
+        assert f"# TYPE chanamq_{name} counter" in lines
+        assert f"chanamq_{name} {metrics[name]}" in lines
+    await c.close()
+
+
 async def test_vhost_permissions_enforced():
     """chana.mq.auth.permissions: a user with an allowlist may open only
     those vhosts; users absent from the map stay unrestricted."""

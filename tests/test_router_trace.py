@@ -344,13 +344,50 @@ def _host_events(trace_dir: str) -> dict:
     return lines
 
 
+def _flat_spans_on_the_loops_line(trace_dir: str, names: tuple) -> tuple:
+    """(the loop's line's events, its spans of `names` in time order) of a
+    trace in which every one of `names` was written: bare, all on the one
+    line that carries `router.tokenize`, flat and disjoint."""
+    lines = _host_events(trace_dir)
+    every = [e for events in lines.values() for e in events]
+    # bare names: nothing of the `name#key=value#` form
+    assert not [e for e in every if e[0].startswith(names) and "#" in e[0]]
+    ours = {name: [line for line, events in lines.items()
+                   if any(e[0] == name for e in events)] for name in names}
+    loop_line = ours["router.tokenize"]
+    assert len(loop_line) == 1
+    assert all(found == loop_line for found in ours.values()), ours
+    on_loop = lines[loop_line[0]]
+    spans = sorted((e for e in on_loop if e[0] in names), key=lambda e: e[1])
+    # flat and disjoint: no program span starts before the last one ended
+    for before, after in zip(spans, spans[1:]):
+        assert before[2] <= after[1], (before, after)
+    return on_loop, spans
+
+
+async def _traced(trace_dir, server: BrokerServer, drive) -> None:
+    """`drive(port)` against `server` inside a real `jax.profiler` session
+    that records host events only, as the benchmark's traced run does."""
+    import jax
+
+    await server.start()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        await drive(server.bound_port)
+    finally:
+        jax.profiler.stop_trace()
+        await server.stop()
+
+
 @pytest.mark.skipif(not native_ext.pipeline_available(),
                     reason="the router batches only behind the native scan")
 def test_a_profiler_trace_holds_the_spans_flat_on_the_loops_line(
         event_loop, tmp_path):
     """A rehearsal of the benchmark's traced run on the CPU: a real
     `jax.profiler` session, a flush driven through a real connection."""
-    import jax
 
     async def drive(port: int) -> None:
         c = await AMQPClient.connect("127.0.0.1", port)
@@ -373,38 +410,88 @@ def test_a_profiler_trace_holds_the_spans_flat_on_the_loops_line(
         assert len(got) == 192
         await c.close()
 
-    async def run() -> None:
-        server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0)
-        await server.start()
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        options.host_tracer_level = 2
-        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
-        try:
-            await drive(server.bound_port)
-        finally:
-            jax.profiler.stop_trace()
-            await server.stop()
-        assert server.broker.metrics.router_kernel_launches >= 1
-
-    event_loop.run_until_complete(run())
-    lines = _host_events(str(tmp_path))
-    every = [e for events in lines.values() for e in events]
-    # bare names: nothing of the `name#key=value#` form
-    assert not [e for e in every if e[0].startswith(SPANS) and "#" in e[0]]
-    ours = {name: [line for line, events in lines.items()
-                   if any(e[0] == name for e in events)] for name in SPANS}
-    loop_line = ours["router.tokenize"]
-    assert len(loop_line) == 1
-    assert all(found == loop_line for found in ours.values()), ours
-    # flat and disjoint: no program span starts before the last one ended,
-    # and JAX's two events of the launch lie between tokenize and decode
-    spans = sorted((e for e in lines[loop_line[0]] if e[0] in SPANS),
-                   key=lambda e: e[1])
-    for before, after in zip(spans, spans[1:]):
-        assert before[2] <= after[1], (before, after)
-    names = {e[0] for e in lines[loop_line[0]]}
+    server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0)
+    event_loop.run_until_complete(_traced(tmp_path, server, drive))
+    assert server.broker.metrics.router_kernel_launches >= 1
+    on_loop, spans = _flat_spans_on_the_loops_line(str(tmp_path), SPANS)
+    # JAX's two events of the launch lie between tokenize and decode
+    names = {e[0] for e in on_loop}
     assert "PjitFunction(topic_match)" in names
-    launch = next(e for e in lines[loop_line[0]]
-                  if e[0] == "PjitFunction(topic_match)")
+    launch = next(e for e in on_loop if e[0] == "PjitFunction(topic_match)")
     assert not [s for s in spans if s[1] < launch[2] and launch[1] < s[2]]
+
+
+DURABLE_SPANS = ("wal.commit", "store.settle", "store.deliver",
+                 "wal.checkpoint")
+
+
+@pytest.mark.skipif(not native_ext.pipeline_available(),
+                    reason="the router batches only behind the native scan")
+def test_a_profiler_trace_holds_the_durable_paths_spans_flat(
+        event_loop, tmp_path):
+    """The same rehearsal over a durable deployment (PR 35): durable queue,
+    persistent publishes confirmed at the log's commit, a consumer that
+    acknowledges every delivery, checkpoints every 50 ms. The log's, the
+    store's and the settle path's loop-side work is on the loop's line under
+    four names, flat among the others, a span a callback and never one a
+    message."""
+    from chanamq_tpu.store.sqlite import SqliteStore
+    from chanamq_tpu.wal import WalStore
+
+    bursts, burst = 4, 64
+    messages = bursts * burst
+
+    store = WalStore(SqliteStore(str(tmp_path / "store.db")),
+                     flush_ms=1.0, checkpoint_ms=50.0)
+    server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0,
+                          store=store)
+    # as server.main() wires them: the log counts into the broker's registry
+    metrics = store.metrics = server.broker.metrics
+
+    async def drive(port: int) -> None:
+        c = await AMQPClient.connect("127.0.0.1", port)
+        ch = await c.channel()
+        await ch.exchange_declare("ex", "topic", durable=True)
+        await ch.queue_declare("q1", durable=True)
+        await ch.queue_bind("q1", "ex", "a.*.c")
+        got = []
+        await ch.basic_qos(prefetch_count=1000)
+
+        def on_msg(msg) -> None:
+            got.append(msg)
+            ch.basic_ack(msg.delivery_tag)
+
+        await ch.basic_consume("q1", on_msg, no_ack=False)
+        await ch.confirm_select()
+        for n in range(bursts):
+            for i in range(burst):
+                ch.basic_publish(
+                    b"m", exchange="ex", routing_key=f"a.{n}-{i}.c",
+                    properties=BasicProperties(delivery_mode=2))
+            await ch.wait_unconfirmed_below(1)
+            await asyncio.sleep(0.06)  # a checkpoint's drain in between
+        for _ in range(300):
+            if (metrics.wal_settle_rows == messages
+                    and metrics.wal_memtable_drains >= 2):
+                break
+            await asyncio.sleep(0.01)
+        assert len(got) == messages
+        assert metrics.acked_msgs == metrics.wal_settle_rows == messages
+        await c.close()
+
+    event_loop.run_until_complete(_traced(tmp_path / "trace", server, drive))
+    assert metrics.router_kernel_launches >= 1
+    assert metrics.wal_queue_msgs_committed == messages
+    assert metrics.wal_commit_errors == 0
+    # all four are emitted, on the loop's line and flat among the router's
+    _, spans = _flat_spans_on_the_loops_line(
+        str(tmp_path / "trace"), SPANS + DURABLE_SPANS)
+    count = {name: sum(1 for e in spans if e[0] == name)
+             for name in DURABLE_SPANS}
+    # a commit is two spans (either side of the executor's write + fsync);
+    # a settle or a deliver callback is one a queue and a loop tick: far
+    # fewer than the messages they cover
+    assert 2 * bursts <= count["wal.commit"] <= messages // 4, count
+    assert 1 <= count["store.settle"] <= messages // 4, count
+    assert 1 <= count["store.deliver"] <= messages // 4, count
+    assert 2 <= count["wal.checkpoint"] <= 2 * (bursts + 8), count

@@ -74,6 +74,20 @@ def frame_offsets(path: str) -> list[int]:
     return offsets
 
 
+def fail_first_append(store: WalStore) -> None:
+    """The next commit's write raises; the ones after it go through."""
+    orig_append = store._writer.append
+    fail_once = [True]
+
+    def flaky_append(data, last_lsn):
+        if fail_once[0]:
+            fail_once[0] = False
+            raise OSError("disk on fire")
+        orig_append(data, last_lsn)
+
+    store._writer.append = flaky_append
+
+
 # ---------------------------------------------------------------------------
 # frame codec
 # ---------------------------------------------------------------------------
@@ -277,16 +291,7 @@ async def test_failed_commit_raises_only_overlapping_barriers(db_path):
     failed batch raises; a later barrier over a healthy batch succeeds."""
     s = make_store(db_path)
     await s.open()
-    orig_append = s._writer.append
-    fail_once = [True]
-
-    def flaky_append(data, last_lsn):
-        if fail_once[0]:
-            fail_once[0] = False
-            raise OSError("disk on fire")
-        orig_append(data, last_lsn)
-
-    s._writer.append = flaky_append
+    fail_first_append(s)
     lo = s.mark()
     s.insert_message_nowait(msg(1))
     with pytest.raises(RuntimeError):
@@ -525,3 +530,100 @@ async def test_broker_restart_hydrates_tiered_segments_on_cursor_read(db_path):
     assert store2.metrics.wal_tier_rehydrations >= 1
     await conn2.close()
     await srv2.stop()
+
+
+# ---------------------------------------------------------------------------
+# the counters per message and queue (PR 35): wal_queue_msg_records,
+# wal_queue_msgs_committed, wal_settle_rows against what the segment files hold
+# ---------------------------------------------------------------------------
+
+_ROW_OPS = (OP_INDEX["insert_queue_msg"], OP_INDEX["insert_published"])
+
+
+def rows_on_disk(store: WalStore) -> int:
+    """Message-and-queue rows a scan of the segment files finds, whichever
+    record carries them: a fused publish, a plain row, an op of a tx_batch."""
+    found = 0
+    for _first, path in list_segments(store.dir):
+        with open(path, "rb") as f:
+            payloads, _good, status = scan_frames(f.read())
+        assert status == "ok"
+        for payload in payloads:
+            _lsn, op, args = decode_payload(payload)
+            if op == OP_INDEX["tx_batch"]:
+                found += sum(1 for sub, _ in args[0] if sub in _ROW_OPS)
+            elif op in _ROW_OPS:
+                found += 1
+    return found
+
+
+def hand_rows(s: WalStore, path: str, first: int, awaited: bool) -> int:
+    """Hand message-and-queue rows to the log down one write path; returns
+    how many. `awaited`: the plain path may use insert_queue_msg's awaited
+    form too (never inside a scope that will be aborted: its barrier would
+    wait for a record that is never framed)."""
+    rows = 0
+    if path in ("fused", "tx_batch"):
+        for i in range(first, first + 6):  # blob + first queue: one record
+            s.insert_message_nowait(msg(i))
+            s.insert_queue_msg_nowait("/", "q", i + 1, i, 5, None)
+            rows += 1
+    if path in ("plain", "tx_batch"):
+        for i in range(first, first + 4):  # a further queue: a row alone
+            s.insert_queue_msg_nowait("/", "q2", i + 1, i, 5, None)
+            rows += 1
+        if awaited:
+            s._fire(s.insert_queue_msg("/", "q3", first + 1, first, 5, None))
+            rows += 1
+    return rows
+
+
+@pytest.mark.parametrize("outcome", ["commit_ok", "commit_fails", "tx_aborted"])
+@pytest.mark.parametrize("path", ["fused", "plain", "tx_batch"])
+async def test_queue_msg_counters_match_the_segment_files(db_path, path,
+                                                          outcome):
+    s = make_store(db_path)
+    await s.open()
+    m = s.metrics
+    if outcome == "commit_fails":
+        fail_first_append(s)
+    lo = s.mark()
+    scoped = path == "tx_batch" or outcome == "tx_aborted"
+    if scoped:
+        s.tx_begin()
+    handed = hand_rows(s, path, 0, awaited=outcome != "tx_aborted")
+    if outcome == "tx_aborted":
+        s.tx_abort()
+        handed = 0
+    elif scoped:
+        s.tx_seal()
+    assert m.wal_queue_msg_records == handed
+    assert m.wal_queue_msgs_committed == 0  # nothing is durable yet
+    hi = s.mark()
+    if outcome == "commit_fails":
+        with pytest.raises(RuntimeError):
+            await s.flush([(lo, hi)])
+        assert m.wal_commit_errors == 1
+        assert m.wal_queue_msgs_committed == 0 and rows_on_disk(s) == 0
+        # a later healthy batch commits its own rows and no others
+        lo = s.mark()
+        later = hand_rows(s, "fused", 100, awaited=False)
+        await s.flush([(lo, s.mark())])
+        assert m.wal_queue_msg_records == handed + later
+        assert m.wal_queue_msgs_committed == later == rows_on_disk(s)
+    else:
+        await s.flush([(lo, hi)])
+        assert m.wal_queue_msg_records == handed
+        assert m.wal_queue_msgs_committed == handed == rows_on_disk(s)
+    if path == "tx_batch" and outcome == "commit_ok":
+        assert m.wal_tx_batches == 1 and m.wal_appends == 1  # one record
+    # settle rows: ids a call, not calls; and no message-and-queue row
+    records = m.wal_queue_msg_records
+    lo = s.mark()
+    s._fire(s.delete_queue_unacks("/", "q", [1, 2, 3]))
+    s._fire(s.delete_queue_unacks("/", "q2", iter([4, 5, 6, 7])))
+    await s.flush([(lo, s.mark())])
+    assert m.wal_settle_rows == 7
+    assert m.wal_queue_msg_records == records
+    assert m.wal_commit_ns > 0 and m.wal_commits >= 1
+    await s.close()
