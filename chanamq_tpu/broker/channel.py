@@ -303,8 +303,11 @@ class ServerChannel:
         run's (no native encoder, channel flow off, a trace sampler, a
         firehose tap, a tenant latency histogram).
 
-        A stretch ends with the run, or at a message whose last reference
-        went: unrefer_n's tail may cross a flow stage, whose listeners
+        A stretch ends with the run, at a message whose last reference
+        went, or before a message that would make the connection's pending
+        batch outgrow one pooled buffer of the encoder (egress_deliver's
+        rule: the batch is rendered, and the next stretch opens a new
+        one). unrefer_n's tail may cross a flow stage, whose listeners
         write to connections (Connection.Unblocked) and so flush what is
         pending. Every count is therefore handed over before the tail, and
         the connection's buffer is read anew after it: between stretches
@@ -338,6 +341,8 @@ class ServerChannel:
             pend = conn._egress_pending
             opened = not pend
             room = first_room = conn.egress_room()
+            batch_room = conn._egress_cap - conn._egress_bytes
+            batch_full = False
             tag = first_tag = self._delivery_tag
             buffered = consumer.buffered_bytes
             top_offset = queue.last_consumed
@@ -360,22 +365,27 @@ class ServerChannel:
                     header = msg.header_raw
                     if header is None:
                         header = msg.header_payload()
-                    popleft()
-                    tag += 1
                     elen = len(exrk)
                     hlen = len(header)
                     blen = len(body)
+                    # exact wire size, as egress_deliver counts it
+                    if not blen:
+                        wire = fixed + elen + hlen
+                    elif chunk:
+                        wire = (fixed + elen + hlen + blen
+                                + 8 * -(-blen // chunk))
+                    else:
+                        wire = fixed + elen + hlen + blen + 8
+                    if wire > batch_room and pend:
+                        batch_full = True
+                        break
+                    popleft()
+                    tag += 1
                     pend += (_ENC_META_PACK(
                         cid, tag, 1 if qm.redelivered else 0,
                         plen, elen, hlen, blen), prefix, exrk, header, body)
-                    # exact wire size, as egress_deliver counts it
-                    if not blen:
-                        room -= fixed + elen + hlen
-                    elif chunk:
-                        room -= (fixed + elen + hlen + blen
-                                 + 8 * -(-blen // chunk))
-                    else:
-                        room -= fixed + elen + hlen + blen + 8
+                    room -= wire
+                    batch_room -= wire
                     ready += size
                     nbytes += blen
                     buffered += blen
@@ -414,9 +424,12 @@ class ServerChannel:
                     queue.n_delivered += n
                     if top is not None:
                         queue._advance_watermark(top)
-            if last_ref is None:
+            if batch_full:
+                conn.flush_egress()
+            elif last_ref is None:
                 break
-            broker.unrefer_n(last_ref, 0)
+            else:
+                broker.unrefer_n(last_ref, 0)
         return delivered
 
     def _render_deliver(

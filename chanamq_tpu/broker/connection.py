@@ -218,9 +218,14 @@ class AMQPConnection:
         # native batch egress: deliveries buffered as flat packed parts
         # (_ENC_META header + prefix/exrk/header/body slices, 5 parts per
         # record) and rendered in one chana_encode_deliveries_packed call
-        # at the dispatch-pass flush (or the call_soon guard for
-        # off-dispatch paths: streams, cluster stubs)
+        # when the dispatch drain ends (or the call_soon guard for
+        # off-drain paths: streams, cluster stubs)
         self._egress = broker.egress_encoder
+        # one pooled buffer of the encoder: the pending batch is rendered
+        # before a record that would make it outgrow one, so that a batch
+        # goes to the heap only when a single record is larger than that
+        self._egress_cap = (self._egress.buf_bytes
+                            if self._egress is not None else 0)
         self._egress_pending: list = []
         self._egress_records = 0
         self._egress_bytes = 0
@@ -348,21 +353,16 @@ class AMQPConnection:
                        body: bytes) -> None:
         """Buffer one basic.deliver as packed parts instead of rendering
         it: the whole batch renders in one native
-        chana_encode_deliveries_packed call at the flush point
-        (dispatch-pass end for classic queues — inside the
-        dispatch/deliver ledger window — or the call_soon guard for
-        stream/cluster delivery paths)."""
-        pend = self._egress_pending
-        if not pend:
-            self.egress_opened()
+        chana_encode_deliveries_packed call at the flush point: the end of
+        the dispatch drain for classic queues (Broker.drain_dispatch, once
+        a connection a loop tick, inside the dispatch/deliver ledger
+        window), the call_soon guard for what is buffered outside a drain
+        (stream and cluster delivery paths), or here, before a record that
+        would make the pending batch outgrow one pooled buffer."""
         plen = len(prefix)
         elen = len(exrk)
         hlen = len(header)
         blen = len(body)
-        pend += (_ENC_META_PACK(channel_id, tag, 1 if redelivered else 0,
-                                plen, elen, hlen, blen),
-                 prefix, exrk, header, body)
-        self._egress_records += 1
         # exact wire size, tracked so write_saturated (dispatch
         # backpressure) sees buffered records the moment they queue
         size = 25 + plen + elen + hlen
@@ -372,12 +372,22 @@ class AMQPConnection:
                 size += blen + 8 * -(-blen // (frame_max - 8))
             else:
                 size += blen + 8
+        pend = self._egress_pending
+        if pend and self._egress_bytes + size > self._egress_cap:
+            self.flush_egress()
+            pend = self._egress_pending
+        if not pend:
+            self.egress_opened()
+        pend += (_ENC_META_PACK(channel_id, tag, 1 if redelivered else 0,
+                                plen, elen, hlen, blen),
+                 prefix, exrk, header, body)
+        self._egress_records += 1
         self._egress_bytes += size
 
     def egress_opened(self) -> None:
         """The first record of a batch was (or is about to be) buffered:
-        name this connection to the dispatch pass's end-of-pass flush and
-        arm the call_soon guard."""
+        name this connection to the dispatch drain's closing flush and arm
+        the call_soon guard."""
         self.broker.egress_dirty.add(self)
         if not self._egress_guard_scheduled:
             self._egress_guard_scheduled = True
@@ -390,10 +400,10 @@ class AMQPConnection:
         return WRITE_HIGH_WATERMARK - self._out_bytes - self._egress_bytes
 
     def _egress_guard(self) -> None:
-        # safety net for deliveries buffered outside a queue dispatch pass
-        # (stream cursors, cluster stub renders): runs on the next loop
-        # iteration, after the dispatch-end flush has usually already
-        # drained the batch
+        # safety net for deliveries buffered outside a dispatch drain
+        # (stream cursors, cluster stub renders, a pass called directly):
+        # runs on the next loop iteration, when the drain's closing flush
+        # has usually already rendered the batch
         self._egress_guard_scheduled = False
         if self._egress_pending:
             self.flush_egress()

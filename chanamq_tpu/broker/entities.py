@@ -599,28 +599,29 @@ class Queue:
         if not self.messages or not self.consumers:
             return
         self._dispatch_scheduled = True
-        asyncio.get_event_loop().call_soon(self._dispatch)
+        # the queues that become ready in one loop tick run their passes
+        # from one callback (Broker.drain_dispatch), in the order they were
+        # scheduled; the first of a tick arms it
+        ready = self.broker.dispatch_ready
+        if not ready:
+            asyncio.get_event_loop().call_soon(self.broker.drain_dispatch)
+        ready.append(self)
 
-    def _dispatch(self) -> None:
+    def _dispatch(self) -> int:
         """One coalesced dispatch pass: round-robin messages to eligible
         consumers until either runs out (reference's fair poll,
         AMQChannel.scala:43-48 + FrameStage.scala:380-443, turned inside out
-        into an event-driven push)."""
+        into an event-driven push). Returns the deliveries it made.
+
+        The pass buffers its deliveries on their connections and renders
+        nothing: Broker.drain_dispatch, which runs the passes of a tick,
+        flushes each connection once when the last pass has run (a pass
+        called outside a drain leaves the flush to the connection's
+        call_soon guard)."""
         self._dispatch_scheduled = False
         if self.deleted:
-            return
-        # dispatch-pass ledger window: two stamps per coalesced pass, not
-        # per delivery. The pass is ~all delivery rendering, so the same
-        # window feeds both the top-level "dispatch" stage (calls=passes,
-        # thread-CPU so the attribution busy-sum stays steal-proof) and
-        # the fine "deliver" stage (calls=messages, so ns/calls reads
-        # as us per delivered message). The pass is synchronous, so no
-        # other ledger window can interleave inside it.
-        prof = profile.ACTIVE
-        t_pass = 0
+            return 0
         n_before = self.n_delivered
-        if prof is not None:
-            t_pass = time.thread_time_ns()
         new_unacks: list[tuple[int, int, int, Optional[int]]] = []
         messages = self.messages
         consumers = self.consumers
@@ -677,27 +678,10 @@ class Queue:
             if self.repl is not None:
                 self.repl.append(
                     "unacks", {"rows": [list(r) for r in new_unacks]})
-        # native batch egress: render every connection's buffered delivery
-        # records now, INSIDE the dispatch ledger window, so the encode
-        # cost stays attributed to dispatch/deliver (the per-connection
-        # call_soon guard only catches deliveries buffered outside a
-        # dispatch pass — streams, cluster stubs)
-        dirty = self.broker.egress_dirty
-        if dirty:
-            for conn in list(dirty):
-                conn.flush_egress()
-            dirty.clear()
         delivered = self.n_delivered - n_before
         if delivered:
             self.broker.metrics.dispatch_passes += 1
-        if prof is not None:
-            dt = time.thread_time_ns() - t_pass
-            sns, sc = prof.stage_ns, prof.stage_calls
-            sns[profile.DISPATCH] += dt
-            sc[profile.DISPATCH] += 1
-            if delivered:
-                sns[profile.DELIVER] += dt
-                sc[profile.DELIVER] += delivered
+        return delivered
 
     # -- passivation / hydration -------------------------------------------
 

@@ -304,9 +304,12 @@ class Metrics:
         # Queue._dispatch): passes that delivered anything, and the
         # deliveries made inside a head run (ServerChannel.deliver_run)
         # rather than one by one. With delivered_msgs: deliveries a pass,
-        # and the share of them the run takes
+        # and the share of them the run takes. dispatch_drains: the
+        # once-a-tick callbacks (Broker.drain_dispatch) that ran at least
+        # one such pass; dispatch_passes over it is passes a drain
         self.dispatch_passes = 0
         self.dispatch_run_msgs = 0
+        self.dispatch_drains = 0
         # continuous profiling (chanamq_tpu/profile/): stack-sampler
         # samples taken, event-loop callbacks caught over the slow
         # threshold, and collector pauses seen by the gc hook. All zero
@@ -560,6 +563,7 @@ class Metrics:
             "native_pool_exhausted": self.native_pool_exhausted,
             "dispatch_passes": self.dispatch_passes,
             "dispatch_run_msgs": self.dispatch_run_msgs,
+            "dispatch_drains": self.dispatch_drains,
             "profile_samples_total": self.profile_samples_total,
             "profile_slow_callbacks_total": self.profile_slow_callbacks_total,
             "profile_gc_pauses_total": self.profile_gc_pauses_total,

@@ -516,9 +516,11 @@ async def test_prometheus_metrics_endpoint(stack):
 
 
 async def test_dispatch_counters_on_both_surfaces(stack):
-    """`dispatch_passes` and `dispatch_run_msgs` are on /admin/overview and
-    /metrics and add up against `delivered_msgs`: the no_ack consumer's
-    deliveries are made inside head runs, the acked consumer's one by one."""
+    """`dispatch_passes`, `dispatch_drains` and `dispatch_run_msgs` are on
+    /admin/overview and /metrics and add up against `delivered_msgs`: the
+    no_ack consumer's deliveries are made inside head runs, the acked
+    consumer's one by one, and a drain (one callback a loop tick) runs one
+    pass or more."""
     server, admin = stack
     c = await AMQPClient.connect("127.0.0.1", server.bound_port)
     ch = await c.channel()
@@ -547,6 +549,8 @@ async def test_dispatch_counters_on_both_surfaces(stack):
     assert metrics["dispatch_run_msgs"] == 60
     # the backlog of 40 went in one pass; no pass is empty, none counted twice
     assert 2 <= metrics["dispatch_passes"] <= 75 - 39
+    # a drain is counted only when a pass of it delivered
+    assert 1 <= metrics["dispatch_drains"] <= metrics["dispatch_passes"]
 
     status, _ctype, text = await http_text(admin.bound_port, "/metrics")
     assert status == 200
@@ -554,8 +558,12 @@ async def test_dispatch_counters_on_both_surfaces(stack):
                 if line.startswith("chanamq_dispatch_"))
     assert prom == {
         "chanamq_dispatch_passes": str(metrics["dispatch_passes"]),
+        "chanamq_dispatch_drains": str(metrics["dispatch_drains"]),
         "chanamq_dispatch_run_msgs": "60",
     }
+    types = {line.split()[2]: line.split()[3] for line in text.splitlines()
+             if line.startswith("# TYPE chanamq_dispatch_")}
+    assert types["chanamq_dispatch_drains"] == types["chanamq_dispatch_passes"]
     await c.close()
 
 

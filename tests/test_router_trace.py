@@ -31,7 +31,7 @@ PER_LAUNCH = (
     "router_h2d_bytes", "router_table_uploads", "router_mask_decodes")
 PER_FLUSH = ("router_route_ns",)
 SPANS = ("conn.ingress", "router.lookup", "router.tokenize", "router.decode",
-         "broker.enqueue", "conn.confirms")
+         "broker.enqueue", "broker.dispatch", "conn.confirms")
 
 
 def test_the_registry_names_every_counter_once():
@@ -419,6 +419,12 @@ def test_a_profiler_trace_holds_the_spans_flat_on_the_loops_line(
     assert "PjitFunction(topic_match)" in names
     launch = next(e for e in on_loop if e[0] == "PjitFunction(topic_match)")
     assert not [s for s in spans if s[1] < launch[2] and launch[1] < s[2]]
+    # `broker.dispatch` is one span a drain (a synchronous callback, so never
+    # open across an await), whatever the drain delivered: as many as the
+    # drains that delivered, or more, and not one a delivery
+    metrics = server.broker.metrics
+    drains = sum(1 for e in spans if e[0] == "broker.dispatch")
+    assert 1 <= metrics.dispatch_drains <= drains < metrics.delivered_msgs
 
 
 DURABLE_SPANS = ("wal.commit", "store.settle", "store.deliver",
