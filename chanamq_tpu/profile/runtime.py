@@ -215,6 +215,15 @@ class ProfileRuntime:
                     100.0 * (1 - m.router_mask_decodes / m.router_kernel_keys),
                     1),
             }
+        if m.router_closure_flattens:
+            compiles = m.router_closure_compiles
+            block["closure"] = {
+                "compiles": compiles,
+                "flattens": m.router_closure_flattens,
+                "ms_per_compile": round(
+                    m.router_closure_flatten_ns * 1e-6 / compiles, 3)
+                if compiles else None,
+            }
         return block
 
     def snapshot(self) -> dict:

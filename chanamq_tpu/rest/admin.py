@@ -858,6 +858,7 @@ class AdminServer:
         "router_compiles",
         "router_fallback_msgs", "router_parity_mismatches",
         *Metrics.ROUTER_LAUNCH,
+        *Metrics.ROUTER_CLOSURE,
         "wal_queue_msg_records", "wal_queue_msgs_committed",
         "wal_settle_rows", "wal_commit_ns", "acked_msgs", "settle_ns",
         "profile_samples_total", "profile_slow_callbacks_total",

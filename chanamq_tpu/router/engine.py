@@ -212,26 +212,56 @@ class TensorRouter:
         edges evaluate it at the known key, and a genuine-wildcard edge
         composes with exact/always sub-entries only. Anything else
         (wildcard-over-wildcard, headers, alternate-exchange fallbacks,
-        recovered cycles) raises Uncompilable and stays on the walk."""
-        exact: dict[str, set] = {}
-        always: set = set()
-        wild: dict[str, set] = {}
-        self._flatten(vhost, vhost_name, root, root,
-                      exact, always, wild, (root,))
-        return rcompile.compile_effective(
-            exact, always, wild, generation=self.generation,
-            max_wildcards=self.max_wildcards, max_queues=self.max_queues)
+        recovered cycles) raises Uncompilable and stays on the walk.
+
+        Each member is flattened ONCE per compile, however many hops lead
+        to it (4,000 exact-key hops into one direct exchange read its
+        sub-closure 4,000 times and build it once): `subs` holds every
+        member's finished ``(exact, always, wild)``, read-only from then
+        on, and None for a member still open on the current path."""
+        metrics = self.broker.metrics
+        subs: dict = {}
+        t0 = time.perf_counter_ns()
+        try:
+            exact, always, wild = self._flatten(
+                vhost, vhost_name, root, root, subs)
+            comp = rcompile.compile_effective(
+                exact, always, wild, generation=self.generation,
+                max_wildcards=self.max_wildcards,
+                max_queues=self.max_queues)
+        finally:
+            metrics.router_closure_flattens += len(subs)
+            metrics.router_closure_flatten_ns += time.perf_counter_ns() - t0
+        metrics.router_closure_compiles += 1
+        return comp
 
     def _flatten(self, vhost, vhost_name: str, root: str, name: str,
-                 exact: dict, always: set, wild: dict, path: tuple) -> None:
+                 subs: dict) -> tuple:
+        """`name`'s sub-closure ``(exact, always, wild)``: its own
+        bindings plus, composed through each hop, its destinations'."""
+        if name in subs:
+            sub = subs[name]
+            if sub is None:
+                # open on the current path — a pre-guard (recovered)
+                # cycle: the walk dedups it, a flat table cannot
+                # represent it
+                raise rcompile.Uncompilable("cycle in e2e closure")
+            return sub
+        subs[name] = None
         # dependency edge FIRST (even for dangling/failing members): an
         # Uncompilable verdict cached for the root must also be dropped
         # when any member's bindings change
         self._closure_deps.setdefault((vhost_name, name), set()).add(
             (vhost_name, root))
+        exact: dict[str, set] = {}
+        always: set = set()
+        wild: dict[str, set] = {}
+        sub = (exact, always, wild)
         ex = vhost.exchanges.get(name)
         if ex is None:
-            return  # dangling e2e target: routes nowhere until redeclared
+            # dangling e2e target: routes nowhere until redeclared
+            subs[name] = sub
+            return sub
         if ex.alternate is not None:
             raise rcompile.Uncompilable("alternate exchange in e2e closure")
         kind = ex.type
@@ -246,18 +276,10 @@ class TensorRouter:
                 exact.setdefault(key, set()).add(queue)
             else:
                 _classify_topic(key, (queue,), exact, always, wild)
-        if ex.ex_matcher is None:
-            return
-        for pkey, dst, _args in ex.ex_matcher.bindings():
-            if dst in path:
-                # a pre-guard (recovered) cycle: the walk dedups it, a
-                # flat table cannot represent it
-                raise rcompile.Uncompilable("cycle in e2e closure")
-            s_exact: dict[str, set] = {}
-            s_always: set = set()
-            s_wild: dict[str, set] = {}
-            self._flatten(vhost, vhost_name, root, dst,
-                          s_exact, s_always, s_wild, path + (dst,))
+        hops = ex.ex_matcher.bindings() if ex.ex_matcher is not None else ()
+        for pkey, dst, _args in hops:
+            s_exact, s_always, s_wild = self._flatten(
+                vhost, vhost_name, root, dst, subs)
             toks = pkey.split(".") if kind == "topic" else None
             if kind == "fanout" or (toks is not None and toks == ["#"]):
                 # always-match hop: sub-closure merges wholesale
@@ -288,6 +310,8 @@ class TensorRouter:
                 if s_wild:
                     raise rcompile.Uncompilable(
                         "wildcard-over-wildcard e2e chain")
+        subs[name] = sub
+        return sub
 
     def _queues(self, vhost_name: str, vhost, names) -> list:
         """Resolve a routed name-set to live Queue objects, memoized per
@@ -357,6 +381,9 @@ class TensorRouter:
                                  name_sets)
                 metrics.router_batches += 1
                 metrics.router_batch_msgs += len(idxs)
+                if vhost.exchanges[exchange_name].ex_matcher is not None:
+                    # the snapshot is a flattened closure's
+                    metrics.router_closure_msgs += len(idxs)
                 metrics.router_batch_size.observe_us(len(idxs))
                 for idx, names in zip(idxs, name_sets):
                     out[idx] = self._queues(vhost_name, vhost, names)

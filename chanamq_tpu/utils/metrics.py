@@ -61,6 +61,11 @@ class Metrics:
         "router_h2d_bytes", "router_table_uploads", "router_mask_decodes",
         "router_route_ns",
     )
+    # the exchange-to-exchange closure's counters, likewise
+    ROUTER_CLOSURE = (
+        "router_closure_compiles", "router_closure_flattens",
+        "router_closure_flatten_ns", "router_closure_msgs",
+    )
 
     def __init__(self) -> None:
         self.published_msgs = 0
@@ -290,6 +295,19 @@ class Metrics:
         self.router_table_uploads = 0
         self.router_mask_decodes = 0
         self.router_route_ns = 0
+        # exchange-to-exchange closures (router/engine.py): closures that
+        # compiled into one flattened table (each also counts in
+        # router_compiles); member exchanges flattened, each once a
+        # compile however many hops lead to it (a compile that ends
+        # Uncompilable adds the members it reached, and its time, here
+        # and to no compile); wall ns of the compiles, flatten and
+        # compile_effective together, on the loop at the deferral
+        # decision of the first publish after any bind in the graph;
+        # messages a flush routed through such a snapshot
+        self.router_closure_compiles = 0
+        self.router_closure_flattens = 0
+        self.router_closure_flatten_ns = 0
+        self.router_closure_msgs = 0
         # native batch egress (native/chanamq_native.cpp): delivery
         # batches rendered by chana_encode_deliveries, the messages and
         # wire bytes they covered, pool-dry acquires that fell back to a
@@ -556,6 +574,7 @@ class Metrics:
             "router_batch_size_p99": self.router_batch_size.percentile_us(0.99),
             "router_batch_size_mean": self.router_batch_size.mean_us,
             **self.router_launch(),
+            **{name: getattr(self, name) for name in self.ROUTER_CLOSURE},
             "native_egress_batches": self.native_egress_batches,
             "native_egress_msgs": self.native_egress_msgs,
             "native_egress_bytes": self.native_egress_bytes,

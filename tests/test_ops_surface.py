@@ -10,7 +10,9 @@ import pytest
 from chanamq_tpu.broker.server import BrokerServer
 from chanamq_tpu.client import AMQPClient
 from chanamq_tpu.config import Config, ConfigError, parse_duration_s, parse_size_bytes
+from chanamq_tpu.profile.runtime import ProfileRuntime
 from chanamq_tpu.rest.admin import AdminServer
+from chanamq_tpu.utils.metrics import Metrics
 
 pytestmark = pytest.mark.asyncio
 
@@ -564,6 +566,72 @@ async def test_dispatch_counters_on_both_surfaces(stack):
     types = {line.split()[2]: line.split()[3] for line in text.splitlines()
              if line.startswith("# TYPE chanamq_dispatch_")}
     assert types["chanamq_dispatch_drains"] == types["chanamq_dispatch_passes"]
+    await c.close()
+
+
+async def test_closure_counters_on_both_surfaces(stack):
+    """The four counters of the exchange-to-exchange closure are on
+    /admin/overview and, typed as counters, on /metrics; `router_closure_msgs`
+    advances by the messages of a flush through the flattened table and by
+    nothing for a plain exchange; /admin/profile's router block gains
+    `closure` once one was flattened."""
+    server, admin = stack
+    metrics = server.broker.metrics
+    c = await AMQPClient.connect("127.0.0.1", server.bound_port)
+    ch = await c.channel()
+    await ch.exchange_declare("ingest", "topic")
+    await ch.exchange_declare("region", "fanout")
+    await ch.exchange_declare("plain", "topic")
+    for queue in ("dash1", "dash2", "own"):
+        await ch.queue_declare(queue)
+    await ch.queue_bind("dash1", "region", "")
+    await ch.queue_bind("dash2", "region", "")
+    await ch.queue_bind("own", "ingest", "*.k.#")
+    await ch.queue_bind("own", "plain", "r1.#")
+    await ch.exchange_bind("region", "ingest", "r1.#")
+    await ch.confirm_select()
+
+    async def publish(exchange: str, n: int) -> dict:
+        for i in range(n):
+            ch.basic_publish(b"m", exchange=exchange, routing_key=f"r1.d{i}")
+        await ch.wait_unconfirmed_below(1)
+        status, overview = await http_req(admin.bound_port, "/admin/overview")
+        assert status == 200
+        return overview["metrics"]
+
+    seen = await publish("plain", 40)
+    assert all(seen[name] == 0 for name in Metrics.ROUTER_CLOSURE)
+    seen = await publish("ingest", 64)
+    assert seen["router_closure_compiles"] == 1
+    assert seen["router_closure_flattens"] == 2  # the root and the fanout
+    assert seen["router_closure_flatten_ns"] > 0
+    if metrics.router_kernel_launches:  # behind the native scan only
+        assert seen["router_closure_msgs"] + seen["router_fallback_msgs"] == 64
+        assert seen["router_closure_msgs"] >= 32
+    before = seen["router_closure_msgs"]
+    seen = await publish("plain", 40)
+    assert seen["router_closure_msgs"] == before
+    assert seen["router_closure_compiles"] == 1
+    # r1.d<i> reaches `own` through `plain`, the region's two through `ingest`
+    depth = {name: queue.message_count for name, queue
+             in server.broker.vhost("/").queues.items()}
+    assert depth == {"own": 80, "dash1": 64, "dash2": 64}
+
+    status, _ctype, text = await http_text(admin.bound_port, "/metrics")
+    assert status == 200
+    prom = dict(line.rsplit(" ", 1) for line in text.splitlines()
+                if line.startswith("chanamq_router_closure_"))
+    assert prom == {f"chanamq_{name}": str(seen[name])
+                    for name in Metrics.ROUTER_CLOSURE}
+    types = {line.split()[3] for line in text.splitlines()
+             if line.startswith("# TYPE chanamq_router_closure_")}
+    assert types == {"counter"}
+    # /admin/profile's page (the ledger itself stays off)
+    page = ProfileRuntime(metrics=metrics, slow_callback_ms=0, gc_hook=False,
+                          broker=server.broker).snapshot()
+    assert page["router"]["closure"] == {
+        "compiles": 1, "flattens": 2, "ms_per_compile": round(
+            seen["router_closure_flatten_ns"] * 1e-6, 3)}
     await c.close()
 
 

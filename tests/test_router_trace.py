@@ -427,6 +427,53 @@ def test_a_profiler_trace_holds_the_spans_flat_on_the_loops_line(
     assert 1 <= metrics.dispatch_drains <= drains < metrics.delivered_msgs
 
 
+@pytest.mark.skipif(not native_ext.pipeline_available(),
+                    reason="the router batches only behind the native scan")
+def test_a_graph_routes_under_the_same_flat_spans_and_compiles_once_a_bind(
+        event_loop, tmp_path):
+    """A publish into an exchange graph's root takes the publish path of a
+    plain exchange, through the flattened snapshot: the same spans, flat.
+    The closure compiles at the first publish after a bind in the graph
+    and at no burst or message after it; its time is a counter's."""
+
+    async def drive(port: int) -> None:
+        c = await AMQPClient.connect("127.0.0.1", port)
+        ch = await c.channel()
+        await ch.exchange_declare("ingest", "topic")
+        await ch.exchange_declare("region", "fanout")
+        for queue in ("dash1", "dash2"):
+            await ch.queue_declare(queue)
+        await ch.queue_bind("dash1", "region", "")
+        await ch.exchange_bind("region", "ingest", "r1.#")
+        got = []
+        await ch.basic_consume("dash1", got.append, no_ack=True)
+        await ch.basic_consume("dash2", got.append, no_ack=True)
+        await ch.confirm_select()
+        for burst in range(4):
+            if burst == 2:  # a bind below the root: the next publish recompiles
+                await ch.queue_bind("dash2", "region", "")
+            for i in range(64):
+                ch.basic_publish(b"m", exchange="ingest",
+                                 routing_key=f"r1.{burst}-{i}")
+            await ch.wait_unconfirmed_below(1)
+        for _ in range(200):
+            if len(got) == 2 * 64 + 2 * 128:
+                break
+            await asyncio.sleep(0.01)
+        assert len(got) == 2 * 64 + 2 * 128
+        await c.close()
+
+    server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0)
+    event_loop.run_until_complete(_traced(tmp_path, server, drive))
+    metrics = server.broker.metrics
+    assert metrics.router_closure_compiles == 2
+    assert metrics.router_closure_flattens == 4
+    assert metrics.router_closure_msgs == 256 - metrics.router_fallback_msgs
+    assert metrics.router_closure_flatten_ns > 0
+    on_loop, _ = _flat_spans_on_the_loops_line(str(tmp_path), SPANS)
+    assert "PjitFunction(topic_match)" in {e[0] for e in on_loop}
+
+
 DURABLE_SPANS = ("wal.commit", "store.settle", "store.deliver",
                  "wal.checkpoint")
 
