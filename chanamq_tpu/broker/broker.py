@@ -17,6 +17,7 @@ from __future__ import annotations
 import asyncio
 import itertools
 import logging
+import sys
 import time
 from typing import Any, Awaitable, Optional
 
@@ -39,7 +40,8 @@ from ..store.memory import MemoryStore
 from ..streams import VALID_QUEUE_TYPES, StreamQueue
 from ..streams.queue import _parse_max_age_ms
 from ..utils.metrics import Metrics
-from .entities import Exchange, Message, Queue, VHost, now_ms
+from .entities import (
+    Exchange, Message, Queue, QueuedMessage, VHost, now_ms)
 
 log = logging.getLogger("chanamq.broker")
 
@@ -324,31 +326,38 @@ class Broker:
         confirm_marks: Optional[list],
     ) -> None:
         """Publish one connection's deferred fused-publish buffer: route
-        the whole batch through the tensor router, then run the same
-        _publish_local the inline path uses, in arrival order. Rows are
+        the whole batch through the tensor router, then enqueue it in
+        arrival order: as a run (_enqueue_run) or, while a trace sampler is
+        on or a firehose tap is bound, through the same _publish_local the
+        inline path uses, message by message. Rows are
         (exchange, routing_key, props, body, header_raw, exrk_raw,
         confirmed). Never raises: defer_ok pre-validated the exchanges and
         nothing can mutate topology between deferral and flush (the
         connection flushes before every await)."""
         routes, t0, t1 = self.router.route_pending(vhost_name, entries)
-        metrics = self.metrics
         prof = profile.ACTIVE
         t_enq = time.perf_counter_ns() if prof is not None else 0
+        fh = events.FIREHOSE
         with device.span("broker.enqueue"):
-            for entry, queues in zip(entries, routes):
-                (exchange, routing_key, props, body, header, exrk,
-                 confirmed) = entry
-                metrics.published(len(body))
-                if trace.ACTIVE is not None:
-                    tr = trace.ACTIVE.begin_publish(self.trace_node,
-                                                    props.headers)
-                    if tr is not None:
-                        # the whole flush routed as one kernel call: each
-                        # sampled message carries the batch's ROUTE window
-                        tr.span(trace.ROUTE, t0, t1, self.trace_node)
-                self._publish_local(
-                    queues, exchange, routing_key, props, body, False,
-                    header, confirm_marks if confirmed else None, exrk)
+            if trace.ACTIVE is None and (fh is None or not fh.tap_bindings):
+                self._enqueue_run(entries, routes, confirm_marks)
+            else:
+                metrics = self.metrics
+                for entry, queues in zip(entries, routes):
+                    (exchange, routing_key, props, body, header, exrk,
+                     confirmed) = entry
+                    metrics.published(len(body))
+                    if trace.ACTIVE is not None:
+                        tr = trace.ACTIVE.begin_publish(self.trace_node,
+                                                        props.headers)
+                        if tr is not None:
+                            # the whole flush routed as one kernel call:
+                            # each sampled message carries the batch's
+                            # ROUTE window
+                            tr.span(trace.ROUTE, t0, t1, self.trace_node)
+                    self._publish_local(
+                        queues, exchange, routing_key, props, body, False,
+                        header, confirm_marks if confirmed else None, exrk)
         if prof is not None:
             # batch-granular ledger: one accumulate covers the whole flush
             # (route window from the router, enqueue from the loop above),
@@ -359,6 +368,110 @@ class Broker:
             sc[profile.ROUTE] += n
             sns[profile.ENQUEUE] += time.perf_counter_ns() - t_enq
             sc[profile.ENQUEUE] += n
+
+    def _enqueue_run(
+        self, entries: list, routes: list, confirm_marks: Optional[list],
+    ) -> None:
+        """The enqueue loop of a flush as a run: for a transient message
+        without expiration whose routed queues are all plain (Queue.plain)
+        and under their resident cap, one loop does what _publish_local ->
+        push_local -> Queue.push do, with what cannot change inside a
+        synchronous flush read once and the counters added once
+        (_settle_run). Such a message writes nothing to the store, so it
+        has no confirm mark. Whatever the run cannot prove goes through
+        _publish_local at its place in the arrival order, after the run
+        has handed over every count it still held, so that path finds the
+        broker as the per-message loop would have left it; the cap and the
+        accountant's room are read again after it, since only that path
+        can move the flow stage. A persistent or expiring publish routed
+        nowhere is that path's too (it returns at once), so the run counts
+        none of a persistent flush. The bodies the run made resident are
+        accounted in one step, which the room keeps short of the
+        accountant's next threshold up: the message that would cross it
+        is _publish_local's, accounted alone, so the stage changes at the
+        message it changes at per message."""
+        publish_local = self._publish_local
+        next_id = self.idgen.next_id
+        cap = room = None  # read before their first use and after a hand-over
+        n_msgs = n_bytes = n_pushes = resident = 0
+        for entry, queues in zip(entries, routes):
+            (exchange, routing_key, props, body, header, exrk,
+             confirmed) = entry
+            size = len(body)
+            taken = props.delivery_mode != 2 and not props.expiration
+            if taken and queues:
+                if room is None:
+                    cap = self._resident_cap()
+                    room = self._memory_room()
+                if resident + size > room:
+                    taken = False
+                else:
+                    for queue in queues:
+                        if not queue.plain or len(queue.messages) >= cap:
+                            taken = False
+                            break
+            if not taken:
+                if n_msgs:
+                    self._settle_run(n_msgs, n_bytes, n_pushes, resident)
+                    n_msgs = n_bytes = n_pushes = resident = 0
+                self.metrics.published(size)
+                publish_local(
+                    queues, exchange, routing_key, props, body, False,
+                    header, confirm_marks if confirmed else None, exrk)
+                cap = room = None
+                continue
+            if queues:
+                message = Message(next_id(), props, body, exchange,
+                                  routing_key, None, header)
+                message.exrk_raw = exrk
+                message.refer_count = len(queues)
+                message.accounted = True
+                for queue in queues:
+                    offset = queue.next_offset
+                    queue.next_offset = offset + 1
+                    queue.messages.append(
+                        QueuedMessage(message, offset, None, size))
+                    queue.ready_bytes += size
+                    queue.n_published += 1
+                    if not queue._dispatch_scheduled and queue.consumers:
+                        queue.schedule_dispatch()
+                n_pushes += len(queues)
+                resident += size
+            n_msgs += 1
+            n_bytes += size
+        if n_msgs:
+            self._settle_run(n_msgs, n_bytes, n_pushes, resident)
+
+    def _settle_run(
+        self, n_msgs: int, n_bytes: int, n_pushes: int, resident: int,
+    ) -> None:
+        """Hand over what an enqueue run counted: at the end of a flush,
+        and before each message the run leaves to _publish_local."""
+        metrics = self.metrics
+        metrics.published_msgs += n_msgs
+        metrics.published_bytes += n_bytes
+        metrics.enqueue_run_msgs += n_msgs
+        metrics.enqueue_run_pushes += n_pushes
+        self.queue_depth += n_pushes
+        if resident:
+            self.account_memory(resident)
+
+    def _resident_cap(self) -> int:
+        """The ready length from which Queue.push pages out the body it
+        is given, for a queue with no cap of its own."""
+        cap = self.queue_max_resident
+        page_cap = self.flow_page_resident_active
+        if cap and page_cap and page_cap < cap:
+            cap = page_cap
+        return cap or sys.maxsize
+
+    def _memory_room(self) -> int:
+        """Body bytes that account_memory can take in one step and end
+        where taking them message by message ends."""
+        flow = self.flow
+        if flow is not None:
+            return flow.headroom()
+        return 0 if self.memory_high_watermark else sys.maxsize
 
     def drain_dispatch(self) -> None:
         """The one dispatch callback of a loop tick: run the pass of every

@@ -241,6 +241,16 @@ class Queue:
         # mirrors itself into it so followers track exactly the rows a
         # restart of THIS node would recover
         self.repl = None  # Optional[replicate.QueueRepLog]
+        # a plain queue: FIFO, no x-message-ttl, no length or byte cap,
+        # not lazy, not a stream, no replication log, counted in the
+        # gauges — push() of a transient message without expiration then
+        # comes to an append and four counters, which the enqueue run of a
+        # deferred flush (Broker._enqueue_run) does inline. Only a declare
+        # (here), ReplicationManager.attach and gauges_detach change it.
+        self.plain = (
+            self.max_priority is None and ttl_ms is None
+            and self.max_length is None and self.max_length_bytes is None
+            and self.max_resident_override is None and not self.is_stream)
 
         # ready list: plain FIFO deque, or — when x-max-priority is set —
         # the per-priority fan (semantics/priority.py), which keeps the
@@ -1089,6 +1099,7 @@ class Queue:
         if not self._counted:
             return
         self._counted = False
+        self.plain = False
         broker = self.broker
         broker.queue_depth -= len(self.messages)
         broker.queue_unacked -= len(self.outstanding)

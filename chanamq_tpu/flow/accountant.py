@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import sys
 from typing import Any, Callable, Optional
 
 log = logging.getLogger("chanamq.flow")
@@ -163,6 +164,20 @@ class MemoryAccountant:
                 listener(old, stage)
             except Exception:
                 log.exception("flow stage listener failed")
+
+    def headroom(self) -> int:
+        """Bytes a gate-counted component may still grow by before
+        reevaluate() would escalate: the distance to enter[stage + 1],
+        ``held`` left out as reevaluate() leaves it out (no limit at the
+        last stage). A caller that adds up to this much in one step
+        leaves the ladder where adding it piece by piece would: every
+        component write is followed by a reevaluate(), so between calls
+        ``total`` is current, the stage is settled and growth alone
+        cannot de-escalate it."""
+        stage = self.stage
+        if stage >= STAGE_REFUSE:
+            return sys.maxsize
+        return self.enter[stage + 1] - (self.total - self.components["held"])
 
     async def cluster_stall(self, timeout: float = 0.25) -> None:
         """One bounded wait for pressure to drop below the cluster stage.

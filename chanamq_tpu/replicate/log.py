@@ -164,6 +164,7 @@ class ReplicationManager:
             self._logs[key] = repl
         if getattr(queue, "repl", None) is not repl:
             queue.repl = repl
+            queue.plain = False
             self._meta_event(repl, queue)
 
     def _meta_event(self, repl: QueueRepLog, queue: "Queue") -> None:

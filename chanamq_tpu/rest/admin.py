@@ -861,6 +861,7 @@ class AdminServer:
         *Metrics.ROUTER_CLOSURE,
         "wal_queue_msg_records", "wal_queue_msgs_committed",
         "wal_settle_rows", "wal_commit_ns", "acked_msgs", "settle_ns",
+        "enqueue_run_msgs", "enqueue_run_pushes",
         "profile_samples_total", "profile_slow_callbacks_total",
         "profile_gc_pauses_total", "profile_gc_pause_ns_total",
         "events_published_total", "events_dropped_total",

@@ -328,6 +328,16 @@ class Metrics:
         self.dispatch_passes = 0
         self.dispatch_run_msgs = 0
         self.dispatch_drains = 0
+        # the enqueue run of a deferred flush (broker/broker.py
+        # Broker._enqueue_run): publishes it built and pushed in its own
+        # loop, those routed nowhere included, and the pushes it made;
+        # each added once a flush. Over published_msgs: the share of
+        # publishes the run takes; what it hands to _publish_local (a
+        # persistent or expiring message, a queue that is not plain or
+        # is at its resident cap) and every publish outside a flush
+        # count in neither
+        self.enqueue_run_msgs = 0
+        self.enqueue_run_pushes = 0
         # continuous profiling (chanamq_tpu/profile/): stack-sampler
         # samples taken, event-loop callbacks caught over the slow
         # threshold, and collector pauses seen by the gc hook. All zero
@@ -583,6 +593,8 @@ class Metrics:
             "dispatch_passes": self.dispatch_passes,
             "dispatch_run_msgs": self.dispatch_run_msgs,
             "dispatch_drains": self.dispatch_drains,
+            "enqueue_run_msgs": self.enqueue_run_msgs,
+            "enqueue_run_pushes": self.enqueue_run_pushes,
             "profile_samples_total": self.profile_samples_total,
             "profile_slow_callbacks_total": self.profile_slow_callbacks_total,
             "profile_gc_pauses_total": self.profile_gc_pauses_total,

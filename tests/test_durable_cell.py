@@ -70,4 +70,6 @@ def test_the_durable_cell_runs_correct_on_the_cpu(trace, tmp_path):
     assert 0 < value["wal_commit_share"] < 100
     assert value["settle_us_per_ack"] > 0
     assert metrics["dispatch_run_share"]["value"] == 0  # acked: no head run
+    # persistent: the enqueue run takes none, not even what routes nowhere
+    assert metrics["enqueue_run_share"]["value"] == 0
     assert metrics["router_fallback_share"]["value"] == 0
