@@ -21,7 +21,7 @@ import sys
 import time
 from typing import Any, Awaitable, Optional
 
-from .. import chaos, device, events, profile, trace
+from .. import chaos, device, events, loopbooks, profile, trace
 
 from ..amqp.constants import ErrorCode, ExchangeType
 from ..amqp.properties import BasicProperties
@@ -103,6 +103,8 @@ class Broker:
         self.store = store or MemoryStore()
         self.idgen = IdGenerator(node_id)
         self.metrics = Metrics()
+        # the collector's pauses are counted whatever else is on
+        loopbooks.watch_gc()
         # native batch egress (chana.mq.native.*): the process-wide
         # encoder + buffer-pool singleton, or None when the native
         # pipeline is unavailable / disabled — connections snapshot this

@@ -429,6 +429,9 @@ class AMQPConnection:
         enc = self._egress
         buf = None
         slot = -1
+        # egress_render_ns: the encode up to the writer's wake-up. A
+        # counter and no span: this runs inside broker.dispatch
+        t0 = time.perf_counter_ns()
         if enc is not None and nrec >= _EGRESS_MIN_BATCH:
             res = enc.encode_packed(pend, nrec, self.frame_max, nbytes)
             if res is not None:
@@ -466,6 +469,7 @@ class AMQPConnection:
             out.append(bytearray(buf))
         self._out_bytes += nbytes
         self._out_event.set()
+        metrics.egress_render_ns += time.perf_counter_ns() - t0
 
     # -- writer task ----------------------------------------------------
 
@@ -518,25 +522,44 @@ class AMQPConnection:
         asyncio forbids a second add_writer on a transport-owned fd, so a
         full kernel buffer (EAGAIN) spills the remainder into the transport
         — which owns the fd's writability callback — and writev resumes
-        once the transport reports its buffer drained."""
+        once the transport reports its buffer drained.
+
+        The synchronous writev loop, and never the drain, is the span
+        ``conn.egress_write`` and ``egress_write_ns`` /
+        ``egress_writev_calls``; a spill counts in ``egress_write_spills``.
+        A write that goes through the transport (TLS, or while a spill is
+        still buffered there) is counted by none of them."""
         sock = self._sock
         if sock is None or self.writer.transport.get_write_buffer_size():
             self.writer.write(b"".join(bufs))
             await self.writer.drain()
             return
-        fd = sock.fileno()
+        metrics = self.broker.metrics
+        t0 = time.perf_counter_ns()
+        with device.span("conn.egress_write"):
+            spilled = self._writev(sock.fileno(), bufs)
+        metrics.egress_write_ns += time.perf_counter_ns() - t0
+        if spilled:
+            metrics.egress_write_spills += 1
+            await self.writer.drain()
+
+    def _writev(self, fd: int, bufs: list) -> bool:
+        """writev ``bufs`` until all are with the kernel (False) or its
+        buffer is full: the rest is then with the transport, for the
+        caller to drain (True)."""
+        metrics = self.broker.metrics
         idx = 0
         total = len(bufs)
         while idx < total:
             batch = bufs[idx:idx + _IOV_MAX]
             try:
+                metrics.egress_writev_calls += 1
                 sent = os.writev(fd, batch)
             except InterruptedError:
                 continue
             except BlockingIOError:
                 self.writer.write(b"".join(bufs[idx:]))
-                await self.writer.drain()
-                return
+                return True
             while sent > 0:
                 blen = len(bufs[idx])
                 if sent >= blen:
@@ -551,6 +574,7 @@ class AMQPConnection:
                         mv = memoryview(mv)
                     bufs[idx] = mv[sent:]
                     sent = 0
+        return False
 
     def _resume_dispatch(self) -> None:
         for channel in self.channels.values():

@@ -16,6 +16,7 @@ import os
 import ssl
 from typing import Optional
 
+from .. import loopbooks
 from ..store.api import StoreService
 from .broker import Broker
 from .connection import AMQPConnection
@@ -730,7 +731,9 @@ def main() -> None:
         overrides["chana.mq.store.path"] = args.store
     config = Config(overrides, file=args.config)
     try:
-        asyncio.run(run_node(config))
+        # the loop over the timed selector: its waits and turns are
+        # counted from inside (chanamq_tpu/loopbooks.py)
+        asyncio.run(run_node(config), loop_factory=loopbooks.new_event_loop)
     except KeyboardInterrupt:
         pass
 

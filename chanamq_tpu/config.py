@@ -386,8 +386,6 @@ DEFAULTS: dict[str, Any] = {
     "chana.mq.profile.slow-callback-ms": 100,
     # bounded ring of recent slow-callback captures kept for /admin/profile
     "chana.mq.profile.ring-size": 64,
-    # attribute collector pauses via gc.callbacks (the "gc" ledger stage)
-    "chana.mq.profile.gc": True,
     # broker-native event bus (chanamq_tpu/events/): internal transitions
     # (alert.fired.<rule>, control.decision.<kind>, lifecycle.<state>,
     # flow.stage.<n>, chaos.fired.<rule>, profile.slow-callback,

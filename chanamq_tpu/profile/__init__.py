@@ -1,6 +1,6 @@
 """Continuous performance observability (``chana.mq.profile.*``).
 
-Three coupled parts, all always-cheap enough to leave on in production:
+Two coupled parts and the page over them:
 
 - a **per-message cost ledger**: the hot-path seams that already carry
   trace spans (ingress-parse / route / enqueue / wal-append / wal-commit /
@@ -14,10 +14,12 @@ Three coupled parts, all always-cheap enough to leave on in production:
   ROADMAP.md D7).
 - a **sampling wall profiler + stall attribution**: an off-loop thread
   samples ``sys._current_frames()`` into folded-stack counts (flamegraph
-  collapsed format at ``GET /admin/profile/stacks``), doubles as the
+  collapsed format at ``GET /admin/profile/stacks``) and doubles as the
   event-loop watchdog that captures the stack and duration of any
-  callback stalling the loop past ``chana.mq.profile.slow-callback-ms``,
-  and a ``gc.callbacks`` hook attributes collector pauses.
+  callback stalling the loop past ``chana.mq.profile.slow-callback-ms``.
+  It reads the stamp the loop's timed selector keeps
+  (``loopbooks.TimedSelector.busy_since_ns``); no heartbeat task runs
+  on the loop, and a loop built otherwise has no watchdog.
 - the aggregate view at ``GET /admin/profile``: µs/msg by stage and by
   subsystem plus the fraction of process CPU the ledger attributes, and a
   ``router`` block: the launch counters ``Metrics`` keeps whether the
@@ -28,6 +30,13 @@ Three coupled parts, all always-cheap enough to leave on in production:
   ``per_launch``, the ``route`` stage split per device launch by them.
   The ledger's ``route`` window and ``router_route_ns`` are one pair of
   stamps.
+
+The collector's hook and the loop's waits and turns are NOT the
+profile's: ``chanamq_tpu/loopbooks.py`` counts them always
+(``Metrics``: ``gc_pause_ns`` answers "is the loop collecting",
+``loop_idle_ns`` "is it waiting", ``loop_slow_turns`` / ``loop_stalls`` /
+``loop_max_turn_ns`` "is it starved or stuck in one callback"), and the ledger's ``gc`` stage,
+this page's ``gc`` block and ``chanamq_profile_gc_*`` read those counters.
 
 The hierarchy of the loop's work lives here (``ingress-cycle`` ⊃ ``route``
 ⊃ …). The flat names a ``jax.profiler`` trace shows on the loop's thread
@@ -69,13 +78,12 @@ def clear() -> None:
 def enable_from_config(config, broker) -> ProfileRuntime:
     """Boot-time wiring (``chana.mq.profile.enabled``): build the runtime
     from the knobs, hang it off the broker for the admin surface, install
-    the gate, and start the sampler/watchdog/GC hooks."""
+    the gate, and start the sampler and the watchdog."""
     runtime = ProfileRuntime(
         metrics=broker.metrics,
         sample_hz=config.int("chana.mq.profile.sample-hz"),
         slow_callback_ms=config.int("chana.mq.profile.slow-callback-ms"),
         ring_size=config.int("chana.mq.profile.ring-size"),
-        gc_hook=config.bool("chana.mq.profile.gc"),
         broker=broker,
     )
     broker.profile = runtime

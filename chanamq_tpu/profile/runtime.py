@@ -23,12 +23,13 @@ an ingress cycle); only top-level stages are summed for attribution.
 from __future__ import annotations
 
 import asyncio
-import gc
 import threading
 import time
 from typing import Optional
 
 import numpy as np
+
+from .. import loopbooks
 
 STAGES = (
     "ingress-parse",   # 0  native frame scan, per read-chunk pass
@@ -42,7 +43,7 @@ STAGES = (
     "flow-throttle",   # 8  publish-gate park window (wall, per episode)
     "dispatch",        # 9  whole coalesced dispatch pass (top-level)
     "ingress-cycle",   # 10 whole read-chunk consume cycle (top-level)
-    "gc",              # 11 collector pauses (gc.callbacks)
+    "gc",              # 11 collector pauses (loopbooks.GC, always on)
     "tx-commit",       # 12 Tx.Commit staged replay: scope open -> sealed
 )
 (INGRESS_PARSE, ROUTE, ENQUEUE, WAL_APPEND, WAL_COMMIT, CLUSTER_PUSH,
@@ -61,13 +62,14 @@ TOP_LEVEL = frozenset({INGRESS_CYCLE, DISPATCH, CLUSTER_PUSH})
 
 
 class ProfileRuntime:
-    """Fixed accumulators + the sampler/watchdog/GC hooks around them.
+    """Fixed accumulators + the sampler and the stall watchdog around them.
 
     ``stage_ns`` / ``stage_calls`` are fixed int64 numpy vectors; seams
     add into them directly (``prof.stage_ns[profile.ROUTE] += dt``) so
     the enabled hot path is two array adds, no method call, no dict, no
     allocation. Everything else (snapshot math, subsystem rollup) runs
-    on the admin path only.
+    on the admin path only. The ``gc`` stage has no seam: it reads the
+    collector's always-on counters (``loopbooks.GC``) when it is asked.
     """
 
     def __init__(
@@ -78,7 +80,6 @@ class ProfileRuntime:
         sample_hz: int = 0,
         slow_callback_ms: int = 100,
         ring_size: int = 64,
-        gc_hook: bool = True,
         broker=None,
     ) -> None:
         self.node = node
@@ -87,7 +88,6 @@ class ProfileRuntime:
         self.sample_hz = max(0, int(sample_hz))
         self.slow_callback_ms = max(0, int(slow_callback_ms))
         self.ring_size = max(1, int(ring_size))
-        self.gc_hook = gc_hook
         self.stage_ns = np.zeros(len(STAGES), dtype=np.int64)
         self.stage_calls = np.zeros(len(STAGES), dtype=np.int64)
         # attribution denominators since enable: loop-thread CPU (the
@@ -98,41 +98,28 @@ class ProfileRuntime:
         self._tcpu0_ns = time.thread_time_ns()
         self._cpu0_ns = time.process_time_ns()
         self._wall0_ns = time.perf_counter_ns()
-        # loop heartbeat for the watchdog (monotonic ns, written by the
-        # heartbeat task; read by the sampler thread — GIL-atomic int)
-        self.beat_ns = 0
+        # the watchdog's input: the timed selector under the loop, whose
+        # busy_since_ns says how long the running turn has lasted (None:
+        # no loop, or one loopbooks did not build: no watchdog)
+        self.loop_books: Optional[loopbooks.TimedSelector] = None
         self.loop_thread_id = threading.get_ident()
         self.sampler = None
-        self._hb_task: Optional[asyncio.Task] = None
-        self._gc_t0 = 0
-        self.gc_pauses = 0
-        self.gc_pause_ns = 0
-        self.gc_max_pause_ns = 0
         self._started = False
 
     # -- lifecycle ----------------------------------------------------------
 
     def start(self, loop: Optional[asyncio.AbstractEventLoop] = None) -> None:
         """Arm the off-ledger parts. Callable without a running loop (unit
-        tests drive the ledger alone); the heartbeat task only starts when
-        one is available."""
+        tests drive the ledger alone); the watchdog only watches a loop
+        built over the timed selector (loopbooks.new_event_loop)."""
         if self._started:
             return
         self._started = True
         self.loop_thread_id = threading.get_ident()
         self._tcpu0_ns = time.thread_time_ns()
-        if self.gc_hook:
-            gc.callbacks.append(self._on_gc)
-        if loop is None:
-            try:
-                loop = asyncio.get_running_loop()
-            except RuntimeError:
-                loop = None
-        if loop is not None and self.slow_callback_ms > 0:
-            self.beat_ns = time.monotonic_ns()
-            self._hb_task = loop.create_task(self._heartbeat())
-        if self.sample_hz > 0 or (
-                loop is not None and self.slow_callback_ms > 0):
+        if self.slow_callback_ms > 0:
+            self.loop_books = loopbooks.selector_of(loop)
+        if self.sample_hz > 0 or self.loop_books is not None:
             from .sampler import Sampler
 
             self.sampler = Sampler(self)
@@ -142,47 +129,10 @@ class ProfileRuntime:
         if not self._started:
             return
         self._started = False
-        if self.gc_hook:
-            try:
-                gc.callbacks.remove(self._on_gc)
-            except ValueError:
-                pass
-        if self._hb_task is not None:
-            self._hb_task.cancel()
-            self._hb_task = None
+        self.loop_books = None
         if self.sampler is not None:
             self.sampler.shutdown()
             self.sampler = None
-
-    async def _heartbeat(self) -> None:
-        # beats 4x faster than the stall threshold so a missing beat means
-        # the loop really is inside one long callback, not between beats
-        interval = max(self.slow_callback_ms / 4000.0, 0.005)
-        try:
-            while True:
-                self.beat_ns = time.monotonic_ns()
-                await asyncio.sleep(interval)
-        except asyncio.CancelledError:
-            pass
-
-    # -- GC pauses ----------------------------------------------------------
-
-    def _on_gc(self, phase: str, info: dict) -> None:
-        if phase == "start":
-            self._gc_t0 = time.perf_counter_ns()
-        elif phase == "stop" and self._gc_t0:
-            dt = time.perf_counter_ns() - self._gc_t0
-            self._gc_t0 = 0
-            self.stage_ns[GC] += dt
-            self.stage_calls[GC] += 1
-            self.gc_pauses += 1
-            self.gc_pause_ns += dt
-            if dt > self.gc_max_pause_ns:
-                self.gc_max_pause_ns = dt
-            m = self.metrics
-            if m is not None:
-                m.profile_gc_pauses_total += 1
-                m.profile_gc_pause_ns_total += dt
 
     # -- cold-path helper (tests, non-seam callers) --------------------------
 
@@ -191,6 +141,14 @@ class ProfileRuntime:
         self.stage_calls[stage] += calls
 
     # -- aggregate view ------------------------------------------------------
+
+    def stage_totals(self) -> "tuple[np.ndarray, np.ndarray]":
+        """``(stage_ns, stage_calls)`` with the ``gc`` stage brought up to
+        the collector's counters: what every reader of the ledger takes."""
+        books = loopbooks.GC
+        self.stage_ns[GC] = books.gc_pause_ns
+        self.stage_calls[GC] = books.gc_collections
+        return self.stage_ns, self.stage_calls
 
     def _router_block(self) -> dict:
         """The route stage seen from inside: the integers /admin/overview
@@ -229,8 +187,7 @@ class ProfileRuntime:
     def snapshot(self) -> dict:
         """The /admin/profile payload: per-stage and per-subsystem µs plus
         the attribution ratio. Pure reads — safe on the admin path."""
-        ns = self.stage_ns
-        calls = self.stage_calls
+        ns, calls = self.stage_totals()
         loop_cpu_ns = time.thread_time_ns() - self._tcpu0_ns
         cpu_ns = time.process_time_ns() - self._cpu0_ns
         wall_ns = time.perf_counter_ns() - self._wall0_ns
@@ -272,9 +229,11 @@ class ProfileRuntime:
                 round(busy_ns / loop_cpu_ns * 100.0, 1)
                 if loop_cpu_ns > 0 else None),
             "gc": {
-                "pauses": self.gc_pauses,
-                "pause_ns": self.gc_pause_ns,
-                "max_pause_ns": self.gc_max_pause_ns,
+                "pauses": loopbooks.GC.gc_collections,
+                "pause_ns": loopbooks.GC.gc_pause_ns,
+                "max_pause_ns": loopbooks.GC.gc_max_pause_ns,
+                "full_pauses": loopbooks.GC.gc_full_collections,
+                "full_pause_ns": loopbooks.GC.gc_full_pause_ns,
             },
         }
         if self.metrics is not None:
@@ -303,8 +262,9 @@ class ProfileRuntime:
         if name not in STAGES:
             return None
         i = STAGES.index(name)
-        c = int(self.stage_calls[i])
-        n = int(self.stage_ns[i])
+        ns, calls = self.stage_totals()
+        c = int(calls[i])
+        n = int(ns[i])
         return {
             "stage": name,
             "subsystem": SUBSYSTEMS[i],

@@ -5,13 +5,15 @@ One daemon thread does both jobs:
 - at ``chana.mq.profile.sample-hz`` it snapshots the event-loop thread's
   stack via ``sys._current_frames()`` and folds it into a bounded
   ``stack -> count`` table (flamegraph collapsed format on read);
-- between samples it checks the loop heartbeat the runtime's on-loop
-  task writes: a beat older than ``slow-callback-ms`` means the loop is
-  pinned inside one callback, so the watchdog captures that callback's
-  live stack *while it runs* and, once the beat resumes, records the
-  episode (duration + folded stack) into a bounded ring, emits a
-  structured JSON log line, and bumps ``profile_slow_callbacks_total``
-  — the existing loop-lag telemetry gets names, not just lag numbers.
+- between samples it reads the stamp the loop's timed selector keeps
+  (``loopbooks.TimedSelector.busy_since_ns``: when the running turn
+  began, 0 while the loop waits in ``select``): a turn older than
+  ``slow-callback-ms`` means the loop is pinned inside one callback, so
+  the watchdog captures that callback's live stack *while it runs* and,
+  once the turn ends, records the episode (duration + folded stack) into
+  a bounded ring, emits a structured JSON log line, and bumps
+  ``profile_slow_callbacks_total`` — the always-on ``loop_slow_turns``
+  gets names, not just a count. Nothing runs on the loop for it.
 
 Sampling happens entirely off-loop; the hot path never sees it. The GIL
 grants the sampler a slice every switch interval (~5 ms), so stalls of
@@ -67,8 +69,8 @@ class Sampler(threading.Thread):
         self.ring: deque = deque(maxlen=runtime.ring_size)
         self.slow_count = 0
         self._stop = threading.Event()
-        # in-flight stall episode: (first-seen beat, captured stack, max lag)
-        self._stall_beat = 0
+        # in-flight stall episode: (the turn's stamp, captured stack, max lag)
+        self._stall_turn = 0
         self._stall_stack = ""
         self._stall_max_ns = 0
 
@@ -92,22 +94,25 @@ class Sampler(threading.Thread):
                 else:
                     self.stacks[_OVERFLOW_KEY] = (
                         self.stacks.get(_OVERFLOW_KEY, 0) + 1)
-            beat = rt.beat_ns
-            if not slow_ns or not beat:
+            books = rt.loop_books
+            if books is None:
                 continue
-            lag_ns = time.monotonic_ns() - beat
-            if lag_ns > slow_ns + self.interval * 2e9:
+            turn = books.busy_since_ns
+            lag_ns = time.perf_counter_ns() - turn if turn else 0
+            if lag_ns > slow_ns:
                 # loop pinned: capture the offending callback's stack the
                 # first time we see this episode, track the worst lag
-                if self._stall_beat != beat:
-                    self._stall_beat = beat
+                if self._stall_turn != turn:
+                    if self._stall_turn:
+                        self._finish_stall()  # one slow turn after another
+                    self._stall_turn = turn
                     self._stall_stack = (
                         fold_stack(loop_frame) if loop_frame is not None
                         else "<no-frames>")
                     self._stall_max_ns = lag_ns
                 elif lag_ns > self._stall_max_ns:
                     self._stall_max_ns = lag_ns
-            elif self._stall_beat:
+            elif self._stall_turn:
                 self._finish_stall()
 
     def _finish_stall(self) -> None:
@@ -118,7 +123,7 @@ class Sampler(threading.Thread):
             "duration_ms": duration_ms,
             "stack": self._stall_stack,
         }
-        self._stall_beat = 0
+        self._stall_turn = 0
         self._stall_max_ns = 0
         self.ring.append(entry)
         self.slow_count += 1

@@ -20,7 +20,7 @@ import time
 from typing import Optional
 from urllib.parse import parse_qs, unquote
 
-from .. import device, native_ext
+from .. import device, loopbooks, native_ext
 from ..broker.broker import Broker
 from ..store.api import is_replica_vhost
 from ..utils.metrics import Metrics
@@ -861,7 +861,12 @@ class AdminServer:
         *Metrics.ROUTER_CLOSURE,
         "wal_queue_msg_records", "wal_queue_msgs_committed",
         "wal_settle_rows", "wal_commit_ns", "acked_msgs", "settle_ns",
+        "wal_checkpoint_drain_ns", "wal_checkpoint_flush_ns",
+        "wal_checkpoint_sync_ns", "wal_checkpoint_ns",
         "enqueue_run_msgs", "enqueue_run_pushes",
+        "egress_render_ns", "egress_write_ns", "egress_writev_calls",
+        "egress_write_spills",
+        *loopbooks.SUMS,
         "profile_samples_total", "profile_slow_callbacks_total",
         "profile_gc_pauses_total", "profile_gc_pause_ns_total",
         "events_published_total", "events_dropped_total",
@@ -974,14 +979,15 @@ class AdminServer:
 
             out.append("# TYPE chanamq_profile_stage_ns_total counter")
             out.append("# TYPE chanamq_profile_stage_calls_total counter")
+            stage_ns, stage_calls = prof.stage_totals()
             for i, stage in enumerate(profile_mod.STAGES):
                 labels = f'{{stage="{self._prom_label(stage)}"}}'
                 out.append(
                     f"chanamq_profile_stage_ns_total{labels} "
-                    f"{int(prof.stage_ns[i])}")
+                    f"{int(stage_ns[i])}")
                 out.append(
                     f"chanamq_profile_stage_calls_total{labels} "
-                    f"{int(prof.stage_calls[i])}")
+                    f"{int(stage_calls[i])}")
         registry = getattr(self.broker, "tenancy", None)
         out.append("# TYPE chanamq_queue_messages gauge")
         out.append("# TYPE chanamq_queue_ready_bytes gauge")

@@ -13,6 +13,8 @@ from bisect import bisect_left
 import time
 from typing import Optional
 
+from .. import loopbooks
+
 
 class Histogram:
     """Fixed-bucket log-scale latency histogram (microseconds)."""
@@ -206,6 +208,16 @@ class Metrics:
         self.wal_commit_ns = 0
         self.acked_msgs = 0
         self.settle_ns = 0
+        # a checkpoint's waits (WalEngine._checkpoint_once), each the wall
+        # from before its await to after it, added when the await returns
+        # (a failed checkpoint adds what it reached): the memtable's drain,
+        # the inner store's flush and the covered LSN's put_kv, the SQLite
+        # file's fsync; wal_checkpoint_ns is their sum: over a window, the
+        # share of it a checkpoint was in flight
+        self.wal_checkpoint_drain_ns = 0
+        self.wal_checkpoint_flush_ns = 0
+        self.wal_checkpoint_sync_ns = 0
+        self.wal_checkpoint_ns = 0
         # multi-process sharding (chanamq_tpu/shard/): cross-shard UDS
         # pushes, ownership re-hashes observed on sibling death, and the
         # restart generation the supervisor hands a respawned worker.
@@ -318,6 +330,16 @@ class Metrics:
         self.native_egress_bytes = 0
         self.native_egress_fallbacks = 0
         self.native_pool_exhausted = 0
+        # the deliveries' way out (broker/connection.py): wall of
+        # flush_egress's encode, native or Python, up to the writer's
+        # wake-up (it runs inside the dispatch drain: broker.dispatch less
+        # this is what the passes cost); wall of the writer tasks'
+        # synchronous os.writev loops, the calls they made, and the times
+        # a full kernel buffer handed the rest to the transport
+        self.egress_render_ns = 0
+        self.egress_write_ns = 0
+        self.egress_writev_calls = 0
+        self.egress_write_spills = 0
         # the classic queues' dispatch passes (broker/entities.py
         # Queue._dispatch): passes that delivered anything, and the
         # deliveries made inside a head run (ServerChannel.deliver_run)
@@ -339,15 +361,14 @@ class Metrics:
         self.enqueue_run_msgs = 0
         self.enqueue_run_pushes = 0
         # continuous profiling (chanamq_tpu/profile/): stack-sampler
-        # samples taken, event-loop callbacks caught over the slow
-        # threshold, and collector pauses seen by the gc hook. All zero
-        # unless chana.mq.profile.enabled. The _total suffix is baked
-        # into the attribute so the Prometheus series follow the naming
-        # convention for counters that grew up after PR 6.
+        # samples taken and event-loop callbacks caught over the slow
+        # threshold. Zero unless chana.mq.profile.enabled. The _total
+        # suffix is baked into the attribute so the Prometheus series
+        # follow the naming convention for counters that grew up after
+        # PR 6. (The collector's pauses and the loop's own waits and turns
+        # are always on and live in loopbooks: snapshot() serves them.)
         self.profile_samples_total = 0
         self.profile_slow_callbacks_total = 0
-        self.profile_gc_pauses_total = 0
-        self.profile_gc_pause_ns_total = 0
         # event bus + firehose (chanamq_tpu/events/): events that reached
         # at least one bound queue vs O(1) drops (nothing bound, or the
         # bus swallowed an emit error), and firehose taps published vs
@@ -440,6 +461,7 @@ class Metrics:
     def snapshot(self) -> dict:
         elapsed = time.time() - self.started_at
         h = self.publish_to_deliver_us
+        books = loopbooks.snapshot()
         out = {
             "uptime_s": round(elapsed, 3),
             "published_msgs": self.published_msgs,
@@ -560,6 +582,10 @@ class Metrics:
             "wal_commit_ns": self.wal_commit_ns,
             "acked_msgs": self.acked_msgs,
             "settle_ns": self.settle_ns,
+            "wal_checkpoint_drain_ns": self.wal_checkpoint_drain_ns,
+            "wal_checkpoint_flush_ns": self.wal_checkpoint_flush_ns,
+            "wal_checkpoint_sync_ns": self.wal_checkpoint_sync_ns,
+            "wal_checkpoint_ns": self.wal_checkpoint_ns,
             "wal_commit_p50_us": self.wal_commit_us.percentile_us(0.50),
             "wal_commit_p99_us": self.wal_commit_us.percentile_us(0.99),
             "wal_commit_mean_us": self.wal_commit_us.mean_us,
@@ -590,15 +616,21 @@ class Metrics:
             "native_egress_bytes": self.native_egress_bytes,
             "native_egress_fallbacks": self.native_egress_fallbacks,
             "native_pool_exhausted": self.native_pool_exhausted,
+            "egress_render_ns": self.egress_render_ns,
+            "egress_write_ns": self.egress_write_ns,
+            "egress_writev_calls": self.egress_writev_calls,
+            "egress_write_spills": self.egress_write_spills,
             "dispatch_passes": self.dispatch_passes,
             "dispatch_run_msgs": self.dispatch_run_msgs,
             "dispatch_drains": self.dispatch_drains,
             "enqueue_run_msgs": self.enqueue_run_msgs,
             "enqueue_run_pushes": self.enqueue_run_pushes,
+            **books,
             "profile_samples_total": self.profile_samples_total,
             "profile_slow_callbacks_total": self.profile_slow_callbacks_total,
-            "profile_gc_pauses_total": self.profile_gc_pauses_total,
-            "profile_gc_pause_ns_total": self.profile_gc_pause_ns_total,
+            # the names the profile's own hook served until PR 39
+            "profile_gc_pauses_total": books["gc_collections"],
+            "profile_gc_pause_ns_total": books["gc_pause_ns"],
             "events_published_total": self.events_published_total,
             "events_dropped_total": self.events_dropped_total,
             "firehose_published_total": self.firehose_published_total,

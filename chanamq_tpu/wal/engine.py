@@ -63,6 +63,13 @@ the wall of every successful commit's executor job.
 Loop-side work shows in a profiler trace as ``device.span`` rows:
 ``wal.commit`` (the two halves of a commit either side of the executor
 call) and ``wal.checkpoint`` (the memtable drain's loop-side part).
+A checkpoint's four awaits cannot sit under one flat span, so they are
+counted: ``wal_checkpoint_drain_ns`` (``_drain``),
+``wal_checkpoint_flush_ns`` (the inner store's ``flush`` and the covered
+LSN's ``put_kv``), ``wal_checkpoint_sync_ns`` (``checkpoint_sync``, the
+SQLite file's fsync) and their sum ``wal_checkpoint_ns``, each the wall
+from before the await to after it, added when it returns: over a window,
+how long a checkpoint was in flight.
 """
 
 from __future__ import annotations
@@ -778,14 +785,30 @@ class WalStore(StoreService):
         # drain the memtable (coalesced — churn that lived and died inside
         # the interval never reaches SQLite), then barrier the inner store:
         # after this the index durably covers every LSN <= target...
-        await self._drain()
-        await self._inner.flush()
-        await self._inner.put_kv(CHECKPOINT_KEY, target)
-        if self.sync_mode == "fsync":
-            # ...and this makes it POWER-durable: under synchronous=NORMAL
-            # SQLite only fsyncs at wal_checkpoint, so without it a power
-            # cut after segment truncation could lose acknowledged data
-            await self._inner.checkpoint_sync()
+        # Each wait is counted when it returns (wal_checkpoint_*_ns): a
+        # counter can run across an await where a span cannot
+        m = self.metrics
+        t0 = t1 = time.perf_counter_ns()
+        try:
+            await self._drain()
+            t1 = time.perf_counter_ns()
+            m.wal_checkpoint_drain_ns += t1 - t0
+            await self._inner.flush()
+            await self._inner.put_kv(CHECKPOINT_KEY, target)
+            t2 = time.perf_counter_ns()
+            m.wal_checkpoint_flush_ns += t2 - t1
+            t1 = t2
+            if self.sync_mode == "fsync":
+                # ...and this makes it POWER-durable: under
+                # synchronous=NORMAL SQLite only fsyncs at wal_checkpoint,
+                # so without it a power cut after segment truncation could
+                # lose acknowledged data
+                await self._inner.checkpoint_sync()
+                t1 = time.perf_counter_ns()
+                m.wal_checkpoint_sync_ns += t1 - t2
+        finally:
+            # the sum of the parts that returned
+            m.wal_checkpoint_ns += t1 - t0
         self._checkpoint_lsn = target
         self.metrics.wal_checkpoints += 1
         drop = [s for s in self._sealed if s[1] <= target]

@@ -28,7 +28,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 @pytest.fixture
 def event_loop():
-    loop = asyncio.new_event_loop()
+    # the loop the broker runs on: over the timed selector (loopbooks), so
+    # the loop's counters and the stall watchdog are tested on the one path
+    from chanamq_tpu import loopbooks
+
+    loop = loopbooks.new_event_loop()
     asyncio.set_event_loop(loop)
     yield loop
     loop.run_until_complete(loop.shutdown_asyncgens())

@@ -7,7 +7,8 @@ import pytest
 _TESTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "tests")
 _MODULES = {}
-for _name in ("test_benchmark", "test_launch_metrics"):
+for _name in ("test_benchmark", "test_launch_metrics",
+              "test_loop_metrics"):
     _spec = importlib.util.spec_from_file_location(
         f"benchmarks_tests_{_name}", os.path.join(_TESTS, _name + ".py"))
     _MODULES[_name] = _module = importlib.util.module_from_spec(_spec)

@@ -678,7 +678,7 @@ async def test_closure_counters_on_both_surfaces(stack):
              if line.startswith("# TYPE chanamq_router_closure_")}
     assert types == {"counter"}
     # /admin/profile's page (the ledger itself stays off)
-    page = ProfileRuntime(metrics=metrics, slow_callback_ms=0, gc_hook=False,
+    page = ProfileRuntime(metrics=metrics, slow_callback_ms=0,
                           broker=server.broker).snapshot()
     assert page["router"]["closure"] == {
         "compiles": 1, "flattens": 2, "ms_per_compile": round(

@@ -18,7 +18,7 @@ import time
 
 import pytest
 
-from chanamq_tpu import profile
+from chanamq_tpu import loopbooks, profile
 from chanamq_tpu.broker.server import BrokerServer
 from chanamq_tpu.client import AMQPClient
 from chanamq_tpu.profile.runtime import ProfileRuntime
@@ -63,7 +63,7 @@ async def http_req_text(port: int, path: str) -> tuple[int, str, str]:
 
 
 def test_ledger_matches_oracle():
-    rt = ProfileRuntime(gc_hook=False)
+    rt = ProfileRuntime()
     # drive the accumulators the way the seams do and keep a dict oracle
     oracle_ns = {}
     oracle_calls = {}
@@ -99,7 +99,7 @@ def test_ledger_hand_timed_window():
     """A real timed busy window lands in the right stage within a loose
     tolerance (the accumulator is exact; the tolerance covers the timer
     reads around the busy loop)."""
-    rt = ProfileRuntime(gc_hook=False)
+    rt = ProfileRuntime()
     t0 = time.perf_counter_ns()
     deadline = t0 + 20_000_000  # 20 ms
     x = 0
@@ -118,7 +118,7 @@ def test_ledger_hand_timed_window():
 def test_disabled_path_and_clear():
     # the module gate defaults to off: seams see None and skip everything
     assert profile.ACTIVE is None
-    rt = profile.install(ProfileRuntime(gc_hook=False))
+    rt = profile.install(ProfileRuntime())
     assert profile.ACTIVE is rt
     prof = profile.ACTIVE
     if prof is not None:  # the exact seam shape used on hot paths
@@ -165,7 +165,7 @@ def test_fold_stack_format():
 
 
 def test_sampler_folds_busy_thread_stacks():
-    rt = ProfileRuntime(sample_hz=200, slow_callback_ms=0, gc_hook=False)
+    rt = ProfileRuntime(sample_hz=200, slow_callback_ms=0)
     rt.start()  # no running loop: ledger + sampler only
     # repoint the sampler at a synthetic "loop" thread we keep busy
     # (start() stamps the caller's thread id, so repoint afterwards)
@@ -203,19 +203,30 @@ def test_sampler_folds_busy_thread_stacks():
 
 async def test_watchdog_captures_slow_callback(caplog):
     rt = ProfileRuntime(sample_hz=0, slow_callback_ms=40, ring_size=8,
-                        gc_hook=False)
+                        )
     rt.start()
     try:
-        await asyncio.sleep(0.05)  # let the heartbeat establish a beat
+        # no heartbeat task: the watchdog reads the stamp the loop's own
+        # timed selector keeps (conftest builds the loop over it)
+        assert rt.loop_books is loopbooks.selector_of()
+        assert isinstance(rt.loop_books, loopbooks.TimedSelector)
+        assert not [t for t in asyncio.all_tasks()
+                    if t is not asyncio.current_task()]
+        await asyncio.sleep(0.05)
+        assert rt.sampler.slow_count == 0  # a loop that waits is not slow
+        slow_before = rt.loop_books.loop_slow_turns
         with caplog.at_level(logging.WARNING, logger="chanamq.profile"):
             _busy_ms(300)  # pin the loop well past threshold + 2 ticks
-            # yield so the heartbeat resumes and the episode closes
+            # yield so the turn ends and the episode closes
             deadline = time.time() + 5
             while time.time() < deadline and rt.sampler.slow_count == 0:
                 await asyncio.sleep(0.02)
         assert rt.sampler.slow_count >= 1
         entry = rt.sampler.ring[-1]
         assert entry["duration_ms"] >= 40
+        # the always-on counter saw the same turn, exactly
+        assert rt.loop_books.loop_slow_turns == slow_before + 1
+        assert rt.loop_books.loop_max_turn_ns >= 300_000_000
         assert entry["stack"]  # the offending callback got a name
         snap = rt.snapshot()
         assert snap["slow_callbacks"]["count"] == rt.sampler.slow_count
@@ -230,13 +241,13 @@ async def test_watchdog_captures_slow_callback(caplog):
 def test_watchdog_bumps_metric_counter():
     m = Metrics()
     rt = ProfileRuntime(metrics=m, sample_hz=0, slow_callback_ms=40,
-                        gc_hook=False)
+                        )
     rt.sampler = None
     from chanamq_tpu.profile.sampler import Sampler
 
     s = Sampler(rt)
     rt.sampler = s
-    s._stall_beat = 1
+    s._stall_turn = 1
     s._stall_max_ns = 50_000_000
     s._stall_stack = "a;b;c"
     s._finish_stall()
@@ -246,26 +257,41 @@ def test_watchdog_bumps_metric_counter():
 
 
 def test_gc_pause_capture():
+    """The profile keeps no hook of its own: its `gc` stage, its page's `gc`
+    block and the two Prometheus names it used to feed read the always-on
+    counters of loopbooks.GC."""
     m = Metrics()
-    rt = ProfileRuntime(metrics=m, gc_hook=True)
+    books = loopbooks.GC
+    loopbooks.watch_gc()
+    hooks = list(gc.callbacks)
+    rt = ProfileRuntime(metrics=m)
     rt.start()
+    gc.disable()  # only this test's own collect() runs between two reads
     try:
-        before = rt.gc_pauses
+        assert gc.callbacks == hooks  # start() installs nothing
+        before = books.gc_collections
         gc.collect()
-        assert rt.gc_pauses > before
-        assert rt.gc_pause_ns > 0
-        assert int(rt.stage_calls[profile.GC]) == rt.gc_pauses
-        assert int(rt.stage_ns[profile.GC]) == rt.gc_pause_ns
-        assert rt.gc_max_pause_ns <= rt.gc_pause_ns
-        assert m.profile_gc_pauses_total == rt.gc_pauses
+        assert books.gc_collections > before
+        assert books.gc_pause_ns > 0
+        assert books.gc_max_pause_ns <= books.gc_pause_ns
+        ns, calls = rt.stage_totals()
         snap = rt.snapshot()
-        assert snap["gc"]["pauses"] == rt.gc_pauses
+        assert int(calls[profile.GC]) == books.gc_collections
+        assert int(ns[profile.GC]) == books.gc_pause_ns
+        assert snap["gc"]["pauses"] == books.gc_collections
+        assert snap["stages"]["gc"]["calls"] == books.gc_collections
+        assert rt.stage_detail("gc")["ns"] == books.gc_pause_ns
+        served = m.snapshot()
+        assert served["profile_gc_pauses_total"] == served["gc_collections"]
+        assert served["profile_gc_pause_ns_total"] == served["gc_pause_ns"]
     finally:
+        gc.enable()
         rt.stop()
-    # stop() unhooks: further collections no longer accumulate
-    after = rt.gc_pauses
+    # stop() has nothing to unhook: the collector is still counted
+    after = books.gc_collections
     gc.collect()
-    assert rt.gc_pauses == after
+    assert books.gc_collections > after
+    assert gc.callbacks == hooks
 
 
 def test_logjson_merges_data_dict():
@@ -365,7 +391,7 @@ async def test_admin_profile_disabled_409():
 async def test_admin_profile_stacks_409_without_sampler():
     server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0)
     await server.start()
-    rt = ProfileRuntime(sample_hz=0, slow_callback_ms=0, gc_hook=False,
+    rt = ProfileRuntime(sample_hz=0, slow_callback_ms=0,
                         broker=server.broker)
     server.broker.profile = rt
     rt.start()

@@ -213,7 +213,7 @@ def test_the_profile_page_reads_the_counters_per_launch(event_loop, kind):
     launch counters: what one launch cost, by the same integers."""
     broker = _broker(event_loop, kind)
     rt = ProfileRuntime(metrics=broker.metrics, slow_callback_ms=0,
-                        gc_hook=False, broker=broker)
+                        broker=broker)
     assert "per_launch" not in rt.snapshot()["router"]  # nothing launched
     n, d = 20, 5
     broker.router.route_pending("/", _entries(kind, n, d))
@@ -275,7 +275,7 @@ def test_the_admin_surfaces_carry_every_new_name(event_loop):
     async def run():
         broker = Broker()
         rt = ProfileRuntime(metrics=broker.metrics, slow_callback_ms=0,
-                            gc_hook=False, broker=broker)
+                            broker=broker)
         broker.profile = rt
         admin = AdminServer(broker, port=0)
         await admin.start()
