@@ -17,6 +17,14 @@ path):
 - the latest forecast is served by the admin API at GET /admin/forecast
   and as chanamq_forecast_* Prometheus gauges (rest/admin.py).
 
+start() builds the JAX state and compiles the train step and the forward
+on the worker thread before it returns, so no round compiles while the
+broker serves. The work is on the books always (Metrics: forecast_samples,
+forecast_rounds, forecast_train_steps, forecast_predicts and their wall
+in ns, two clock reads a site) and, under a profiler session, in three
+flat spans: forecast.sample on the loop, forecast.train and
+forecast.predict on the worker thread.
+
 Enable with chana.mq.forecast.enabled (off by default: a broker should not
 spin an accelerator workload unless the operator asks for capacity
 forecasting).
@@ -104,7 +112,7 @@ class ForecastService:
         self._stopping = False  # cooperative cancel for an in-flight round
         self._np_rng = np.random.default_rng(0)
         # the process's device, claimed in start(); the JAX state on it
-        # is built lazily on the worker thread
+        # is built on the worker thread, in start() and after a divergence
         self.device: Optional[device.Device] = None
         self._jax_state: Optional[dict[str, Any]] = None
         # latest results (event loop writes, anyone reads)
@@ -122,6 +130,12 @@ class ForecastService:
         self.error_scored = 0
         self.error_last: Optional[np.ndarray] = None
         self.error_mae: Optional[np.ndarray] = None
+        # the persistence forecast (next tick = the last tick the round
+        # saw) scored over the same ticks: what the model has to beat
+        self._round_base: Optional[np.ndarray] = None
+        self._pending_base: Optional[np.ndarray] = None
+        self.persistence_scored = 0
+        self.persistence_mae: Optional[np.ndarray] = None
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -129,6 +143,10 @@ class ForecastService:
         # the worker thread jits on whatever device this process holds:
         # claim it now, at boot, so a wrong backend fails the boot
         self.device = device.claim()
+        # and compiles there now, before the broker serves, not at the
+        # first round
+        await asyncio.get_running_loop().run_in_executor(
+            self._executor, self._warm)
         self.broker.forecaster = self
         self._task = asyncio.get_event_loop().create_task(self._run())
         self._task.add_done_callback(self._on_run_done)
@@ -158,38 +176,48 @@ class ForecastService:
 
     async def _run(self) -> None:
         counters = counter_state(self.broker)
+        metrics = self.broker.metrics
         last = time.monotonic()
         next_train = last + self.train_interval_s
         while True:
             await asyncio.sleep(self.interval_s)
+            t0 = time.perf_counter_ns()
             try:
-                now = time.monotonic()
-                vec, counters = sample(self.broker, counters, now - last)
-                last = now
-                if self.queue_top_k:
-                    telemetry = getattr(self.broker, "telemetry", None)
-                    extra = (
-                        self.topk.update(*telemetry.queues.latest_matrix())
-                        if telemetry is not None
-                        else np.zeros(2 * self.queue_top_k, dtype=np.float32))
-                    vec = np.concatenate([vec, extra])
-                self.score_tick(vec)
-                self.ring.push(vec)
-                if (now >= next_train and not self._round_inflight
-                        and len(self.ring) >= self.seq_len + 1):
-                    next_train = now + self.train_interval_s
-                    self._round_inflight = True
-                    history = self.ring.history()  # copy: worker never sees the ring
-                    loop = asyncio.get_event_loop()
-                    loop.run_in_executor(
-                        self._executor, self._round, history
-                    ).add_done_callback(self._on_round_done)
+                with device.span("forecast.sample"):
+                    now = time.monotonic()
+                    vec, counters = sample(self.broker, counters, now - last)
+                    last = now
+                    if self.queue_top_k:
+                        telemetry = getattr(self.broker, "telemetry", None)
+                        extra = (
+                            self.topk.update(
+                                *telemetry.queues.latest_matrix())
+                            if telemetry is not None
+                            else np.zeros(2 * self.queue_top_k,
+                                          dtype=np.float32))
+                        vec = np.concatenate([vec, extra])
+                    self.score_tick(vec)
+                    self.ring.push(vec)
+                    if (now >= next_train and not self._round_inflight
+                            and len(self.ring) >= self.seq_len + 1):
+                        next_train = now + self.train_interval_s
+                        self._round_inflight = True
+                        # copy: the worker never sees the ring
+                        history = self.ring.history()
+                        self._round_base = history[-1]
+                        loop = asyncio.get_event_loop()
+                        loop.run_in_executor(
+                            self._executor, self._round, history
+                        ).add_done_callback(self._on_round_done)
             except asyncio.CancelledError:
                 raise
             except Exception as exc:  # noqa: BLE001 — a bad sample tick
                 # must not kill forecasting forever; record and keep sampling
                 self.last_error = repr(exc)
                 log.exception("forecast sample tick failed")
+            finally:
+                metrics.forecast_samples += 1
+                metrics.forecast_sample_ns += time.perf_counter_ns() - t0
 
     def _on_run_done(self, task: "asyncio.Task") -> None:
         if task.cancelled():
@@ -216,34 +244,48 @@ class ForecastService:
         self.forecast = forecast
         self.updated_at = time.time()
         self.last_error = None
-        # the next realized tick scores this forecast (score_tick)
+        # the next realized tick scores this forecast (score_tick), and
+        # the last tick the round saw as the persistence forecast
         self._pending_forecast = np.array(
             [forecast[name] for name in self.feature_names],
             dtype=np.float32)
+        self._pending_base = self._round_base
 
     # -- forecast accuracy (event loop; numpy only) ------------------------
 
     def score_tick(self, vec: np.ndarray) -> None:
         """Score the pending next-tick forecast against the realized
-        vector: per-feature absolute error, folded into a running MAE.
-        A forecast is consumed by the first tick that follows it."""
-        pending = self._pending_forecast
+        vector: per-feature absolute error, folded into a running MAE; the
+        persistence forecast (the last tick the round saw) beside it over
+        the same ticks. A forecast is consumed by the first tick that
+        follows it."""
+        pending, base = self._pending_forecast, self._pending_base
         if pending is None or len(pending) != len(vec):
             return
-        self._pending_forecast = None
-        err = np.abs(np.asarray(vec, dtype=np.float32) - pending)
+        self._pending_forecast = self._pending_base = None
+        vec = np.asarray(vec, dtype=np.float32)
+        err = np.abs(vec - pending)
         self.error_last = err
         self.error_scored += 1
         if self.error_mae is None:
             self.error_mae = err.copy()
         else:
             self.error_mae += (err - self.error_mae) / self.error_scored
+        if base is not None and len(base) == len(vec):
+            naive = np.abs(vec - base)
+            self.persistence_scored += 1
+            if self.persistence_mae is None:
+                self.persistence_mae = naive
+            else:
+                self.persistence_mae += (
+                    naive - self.persistence_mae) / self.persistence_scored
         # NaN/inf can only come from a poisoned forecast; drop the stats
         # rather than serving non-finite gauges
         if not np.isfinite(err).all():
             self.error_last = None
             self.error_mae = None
-            self.error_scored = 0
+            self.persistence_mae = None
+            self.error_scored = self.persistence_scored = 0
 
     def accuracy(self) -> Optional[dict[str, Any]]:
         if not self.error_scored or self.error_mae is None:
@@ -252,6 +294,10 @@ class ForecastService:
             "scored": self.error_scored,
             "mae": {name: float(v) for name, v in
                     zip(self.feature_names, self.error_mae)},
+            "persistence_mae": (
+                {name: float(v) for name, v in
+                 zip(self.feature_names, self.persistence_mae)}
+                if self.persistence_mae is not None else None),
             "last_abs_error": (
                 {name: float(v) for name, v in
                  zip(self.feature_names, self.error_last)}
@@ -276,21 +322,56 @@ class ForecastService:
         cfg = ForecasterConfig(
             n_features=self.n_features, seq_len=self.seq_len,
             **self.model_kwargs)
+        train_step = make_train_step(cfg, lr=self.lr)
+
+        # functions with names, not lambdas: the device trace's modules
+        # read jit_forecast_train_step and jit_forecast_predict, apart
+        # from the router's jit_topic_match
+        def forecast_train_step(params, momentum, batch):
+            return train_step(params, momentum, batch)
+
+        def forecast_predict(params, window):
+            return forward(params, window, cfg)
+
         params = init_params(jax.random.PRNGKey(0), cfg)
         state = {
             "cfg": cfg,
             "params": params,
             "momentum": init_momentum(params),
-            "step": jax.jit(make_train_step(cfg, lr=self.lr)),
-            "forward": jax.jit(lambda p, x: forward(p, x, cfg)),
+            "step": jax.jit(forecast_train_step),
+            "forward": jax.jit(forecast_predict),
         }
         return state
+
+    def _warm(self) -> None:
+        """Build the JAX state and run the train step and the forward once
+        on zero inputs of a round's shapes, dropping what they return: both
+        are compiled (or read from the compile cache) before the broker
+        serves, so no round compiles."""
+        state = self._jax_setup()
+        x = np.zeros((self.batch, self.seq_len, self.n_features),
+                     dtype=np.float32)
+        y = np.zeros((self.batch, self.n_features), dtype=np.float32)
+        float(state["step"](state["params"], state["momentum"], (x, y))[2])
+        np.asarray(state["forward"](state["params"], x[:1]))
+        self._jax_state = state
 
     def _round(
         self, history: np.ndarray
     ) -> tuple[int, Optional[float], Optional[dict[str, float]]]:
         """One off-path round: K train steps + next-tick forecast."""
-        if self._jax_state is None:
+        metrics = self.broker.metrics
+        t0 = time.perf_counter_ns()
+        try:
+            return self._train_and_predict(history, metrics)
+        finally:
+            metrics.forecast_rounds += 1
+            metrics.forecast_round_ns += time.perf_counter_ns() - t0
+
+    def _train_and_predict(
+        self, history: np.ndarray, metrics
+    ) -> tuple[int, Optional[float], Optional[dict[str, float]]]:
+        if self._jax_state is None:  # rebuilt after a divergence
             self._jax_state = self._jax_setup()
         state = self._jax_state
         mean, std = normalization(history)
@@ -298,19 +379,30 @@ class ForecastService:
         pairs = training_batch(normed, self.seq_len, self.batch, self._np_rng)
         steps = 0
         loss = None
-        if pairs is not None:
-            for _ in range(self.steps_per_round):
-                if self._stopping:
-                    return steps, loss, None
-                state["params"], state["momentum"], loss_arr = state["step"](
-                    state["params"], state["momentum"], pairs)
-                steps += 1
-            if steps:  # steps_per_round == 0 leaves loss_arr unbound
-                loss = float(loss_arr)
+        if pairs is not None and self.steps_per_round:
+            # from the first step's dispatch to the loss on the host: the
+            # steps run back to back on the device
+            t0 = time.perf_counter_ns()
+            with device.span("forecast.train"):
+                for _ in range(self.steps_per_round):
+                    if self._stopping:
+                        break
+                    state["params"], state["momentum"], loss_arr = \
+                        state["step"](state["params"], state["momentum"],
+                                      pairs)
+                    steps += 1
+                if steps and not self._stopping:
+                    loss = float(loss_arr)
+            metrics.forecast_train_steps += steps
+            metrics.forecast_train_ns += time.perf_counter_ns() - t0
         if self._stopping:
             return steps, loss, None
         window = normed[-self.seq_len:][None, ...].astype(np.float32)
-        pred = np.asarray(state["forward"](state["params"], window))[0]
+        t0 = time.perf_counter_ns()
+        with device.span("forecast.predict"):
+            pred = np.asarray(state["forward"](state["params"], window))[0]
+        metrics.forecast_predicts += 1
+        metrics.forecast_predict_ns += time.perf_counter_ns() - t0
         if (loss is not None and not np.isfinite(loss)) \
                 or not np.isfinite(pred).all():
             # diverged despite clipping: drop the poisoned params and start

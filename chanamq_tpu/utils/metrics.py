@@ -360,6 +360,20 @@ class Metrics:
         # count in neither
         self.enqueue_run_msgs = 0
         self.enqueue_run_pushes = 0
+        # the telemetry forecaster (models/service.py), all zero unless
+        # chana.mq.forecast.enabled: +1 and the wall of each sampler tick
+        # on the event loop; of each train/predict round on the worker
+        # thread; the train steps a round ran and the wall from the first
+        # step's dispatch to its loss on the host, added once a round; the
+        # forecasts (forward + copy back) and their wall
+        self.forecast_samples = 0
+        self.forecast_sample_ns = 0
+        self.forecast_rounds = 0
+        self.forecast_round_ns = 0
+        self.forecast_train_steps = 0
+        self.forecast_train_ns = 0
+        self.forecast_predicts = 0
+        self.forecast_predict_ns = 0
         # continuous profiling (chanamq_tpu/profile/): stack-sampler
         # samples taken and event-loop callbacks caught over the slow
         # threshold. Zero unless chana.mq.profile.enabled. The _total
@@ -625,6 +639,14 @@ class Metrics:
             "dispatch_drains": self.dispatch_drains,
             "enqueue_run_msgs": self.enqueue_run_msgs,
             "enqueue_run_pushes": self.enqueue_run_pushes,
+            "forecast_samples": self.forecast_samples,
+            "forecast_sample_ns": self.forecast_sample_ns,
+            "forecast_rounds": self.forecast_rounds,
+            "forecast_round_ns": self.forecast_round_ns,
+            "forecast_train_steps": self.forecast_train_steps,
+            "forecast_train_ns": self.forecast_train_ns,
+            "forecast_predicts": self.forecast_predicts,
+            "forecast_predict_ns": self.forecast_predict_ns,
             **books,
             "profile_samples_total": self.profile_samples_total,
             "profile_slow_callbacks_total": self.profile_slow_callbacks_total,
