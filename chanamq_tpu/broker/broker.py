@@ -40,6 +40,7 @@ from ..store.memory import MemoryStore
 from ..streams import VALID_QUEUE_TYPES, StreamQueue
 from ..streams.queue import _parse_max_age_ms
 from ..utils.metrics import Metrics
+from .channel import DispatchDrain
 from .entities import (
     Exchange, Message, Queue, QueuedMessage, VHost, now_ms)
 
@@ -475,6 +476,14 @@ class Broker:
             return flow.headroom()
         return 0 if self.memory_high_watermark else sys.maxsize
 
+    def _memory_room_down(self) -> int:
+        """Body bytes whose release account_memory can take in one step
+        and end where releasing them message by message ends."""
+        flow = self.flow
+        if flow is not None:
+            return flow.room_down()
+        return 0 if self.memory_high_watermark else sys.maxsize
+
     def drain_dispatch(self) -> None:
         """The one dispatch callback of a loop tick: run the pass of every
         queue scheduled since the last drain, in the order they were
@@ -488,6 +497,12 @@ class Broker:
         would make its pending batch outgrow one pooled buffer of the
         encoder (egress_deliver, deliver_run), so a tick that buffers more
         renders into the pool in several batches.
+
+        The drain is the unit of the head runs' bookkeeping: the passes on
+        one consuming channel extend one head run (ServerChannel.deliver_run)
+        held in the drain's DispatchDrain, which hands the runs' counts over
+        and releases the last references they kept when the passes have
+        run, before the flushes.
 
         One profiler span a drain, its flushes included (a span a queue
         cost throughput with the profiler off), and one ledger window: two
@@ -503,15 +518,17 @@ class Broker:
         t_drain = time.thread_time_ns() if prof is not None else 0
         delivered = 0
         with device.span("broker.dispatch"):
+            drain = DispatchDrain(self)
             for queue in ready:
                 try:
-                    delivered += queue._dispatch()
+                    delivered += queue._dispatch(drain)
                 except Exception as exc:
                     asyncio.get_event_loop().call_exception_handler({
                         "message": "Exception in the dispatch pass of "
                                    f"queue {queue.name!r}",
                         "exception": exc,
                     })
+            drain.close()
             dirty = self.egress_dirty
             while dirty:
                 dirty.pop().flush_egress()

@@ -145,6 +145,126 @@ class Consumer:
         return True
 
 
+class HeadRun:
+    """The head-run bookkeeping of one consuming channel for one dispatch
+    drain (ServerChannel.deliver_run). The first pass of a drain that takes
+    a head run on the channel opens it and reads once what cannot change
+    inside the synchronous drain; every later pass on the channel extends
+    it. It holds what the channel, its connection and the metrics take per
+    delivery: the delivery tag, the connection's pending batch with its
+    room and bytes, the deliveries and their bytes, the histogram's count
+    and total. handover() writes them back; DispatchDrain.close() hands
+    over and closes every open run. At most one run a connection is open."""
+
+    __slots__ = (
+        "channel", "conn", "metrics", "hist", "buckets", "bounds",
+        "limit", "chunk", "pend", "opened", "room", "first_room",
+        "batch_room", "tag", "first_tag", "nbytes", "waited_ns",
+    )
+
+    def __init__(self, channel: "ServerChannel") -> None:
+        conn = self.conn = channel.connection
+        self.channel = channel
+        broker = conn.broker
+        metrics = self.metrics = broker.metrics
+        hist = self.hist = metrics.publish_to_deliver_us
+        self.buckets = hist.buckets
+        self.bounds = hist.BOUNDS
+        self.limit = broker.flow_consumer_buffer
+        frame_max = conn.frame_max
+        self.chunk = frame_max - FRAME_OVERHEAD if frame_max else 0
+        self.reload()
+
+    def reload(self) -> None:
+        """Read the connection's pending batch and the channel's tag: at
+        the opening, and after a flush the run asked for."""
+        conn = self.conn
+        pend = self.pend = conn._egress_pending
+        self.opened = not pend
+        self.room = self.first_room = conn.egress_room()
+        self.batch_room = conn._egress_cap - conn._egress_bytes
+        self.tag = self.first_tag = self.channel._delivery_tag
+        self.nbytes = self.waited_ns = 0
+
+    def handover(self) -> None:
+        """Write what the run counted since it last read the connection
+        back to the channel, the connection and the metrics."""
+        n = self.tag - self.first_tag
+        if not n:
+            return
+        self.channel._delivery_tag = self.tag
+        conn = self.conn
+        if self.opened:
+            conn.egress_opened()
+        conn._egress_records += n
+        conn._egress_bytes += self.first_room - self.room
+        conn.delivered_msgs += n
+        metrics = self.metrics
+        metrics.delivered_msgs += n
+        metrics.delivered_bytes += self.nbytes
+        metrics.dispatch_run_msgs += n
+        hist = self.hist
+        hist.count += n
+        hist.total_us += self.waited_ns // 1000
+
+    def close(self) -> None:
+        self.handover()
+        self.conn._head_run = None
+
+
+class DispatchDrain:
+    """What one dispatch drain keeps across its passes: the head runs it
+    has open (HeadRun: at most one a connection) and the last references
+    they kept, their bytes and count, with the bytes that may still be kept
+    before a release in one step would move the accountant's stage
+    (MemoryAccountant.room_down). Broker.drain_dispatch makes one a drain
+    and hands it to every pass (Queue._dispatch, ServerChannel.deliver_run);
+    nothing of it outlives the drain."""
+
+    __slots__ = ("broker", "runs", "freed", "kept", "room")
+
+    def __init__(self, broker) -> None:
+        self.broker = broker
+        self.runs: list[HeadRun] = []
+        self.freed = self.kept = self.room = 0
+
+    def open(self, channel: "ServerChannel") -> HeadRun:
+        """Open the channel's head run; a run another channel of the same
+        connection holds is handed over and closed first."""
+        conn = channel.connection
+        other = conn._head_run
+        if other is not None:
+            other.close()
+            self.runs.remove(other)
+        run = conn._head_run = HeadRun(channel)
+        self.runs.append(run)
+        broker = self.broker
+        broker.metrics.dispatch_run_setups += 1
+        if not self.kept:
+            self.room = broker._memory_room_down()
+        return run
+
+    def close(self) -> None:
+        """Hand every open run's counts back to its channel, its connection
+        and the metrics, close it, and release in one step the last
+        references the runs kept. Called when the passes have run, and
+        inside the drain before anything that reads those counts or the
+        accountant: a pass's per-message loop, a release at its own
+        message."""
+        runs = self.runs
+        if runs:
+            self.runs = []
+            for run in runs:
+                run.close()
+        n = self.kept
+        if n:
+            freed = self.freed
+            self.kept = self.freed = 0
+            broker = self.broker
+            broker.metrics.dispatch_run_releases += n
+            broker.account_memory(-freed)
+
+
 class ServerChannel:
     """Per-channel broker state on one connection."""
 
@@ -288,66 +408,67 @@ class ServerChannel:
         consumer.unacked_size += len(body)
         return delivery
 
-    def deliver_run(self, consumer: Consumer, queue: Queue, messages) -> int:
+    def deliver_run(self, consumer: Consumer, queue: Queue, messages,
+                    drain: DispatchDrain) -> int:
         """The head run of a dispatch pass: Queue._dispatch's loop body and
         deliver() above as one loop, for the one plain no_ack consumer
-        (`takes_runs`) of a FIFO queue. What those read that cannot change
-        inside a synchronous pass is read once; the counters they bump per
-        message are added once a stretch; the publish->deliver histogram
-        takes one clock read a run. Pops and buffers head messages up to
-        the first one for which a per-message check is not trivially true
-        (dead, any TTL, a passivated body, the write watermark, the
+        (`takes_runs`) of a FIFO queue. Pops and buffers head messages up
+        to the first one for which a per-message check is not trivially
+        true (dead, any TTL, a passivated body, the write watermark, the
         consumer-buffer bound) and leaves that one, unpopped, to the
         per-message loop: a prefix of the one pass, never a second policy.
         Returns the deliveries made; 0 when the pass as a whole is not a
         run's (no native encoder, channel flow off, a trace sampler, a
         firehose tap, a tenant latency histogram).
 
-        A stretch ends with the run, at a message whose last reference
-        went, or before a message that would make the connection's pending
-        batch outgrow one pooled buffer of the encoder (egress_deliver's
-        rule: the batch is rendered, and the next stretch opens a new
-        one). unrefer_n's tail may cross a flow stage, whose listeners
-        write to connections (Connection.Unblocked) and so flush what is
-        pending. Every count is therefore handed over before the tail, and
-        the connection's buffer is read anew after it: between stretches
-        the broker's state is the per-message path's at that message."""
-        conn = self.connection
-        if (conn._egress is None or not self.flow_active or self.closed
-                or trace.ACTIVE is not None):
-            return 0
-        fh = events.FIREHOSE
-        if fh is not None and fh.tap_bindings:
-            return 0
-        tenant = conn.tenant
-        if tenant is not None and tenant.latency_hist is not None:
-            return 0
-        broker = conn.broker
-        metrics = broker.metrics
-        limit = broker.flow_consumer_buffer
-        frame_max = conn.frame_max
-        chunk = frame_max - FRAME_OVERHEAD if frame_max else 0
+        The unit of the bookkeeping is the dispatch drain, not the pass:
+        the channel's HeadRun, opened by its first pass of the drain, holds
+        what the drain cannot change and the counts the channel, the
+        connection and the metrics take per delivery; the queue's own
+        counts are written when its pass ends. The publish->deliver
+        histogram takes one clock read a call. The last reference of a
+        message is kept, and released with the drain's others in one step
+        (DispatchDrain.close), while their sum stays under the distance
+        to the accountant's next threshold down (DispatchDrain.room).
+        The release that would reach it, and that of a persisted or paged
+        message, is made at its own message: every open run hands over and
+        closes first, since a flow stage's listeners write to connections
+        (Connection.Unblocked), and the run is opened again after it. A
+        record that would make the connection's pending batch outgrow one
+        pooled buffer of the encoder renders the batch first
+        (egress_deliver's rule), the counts handed over before it and the
+        connection's buffer read anew after it."""
+        run = self.connection._head_run
+        if run is None or run.channel is not self:
+            run = self._open_head_run(drain)
+            if run is None:
+                return 0
+        conn = run.conn
+        broker = drain.broker
+        limit = run.limit
+        chunk = run.chunk
+        buckets = run.buckets
+        bounds = run.bounds
+        now_ns = time.perf_counter_ns()
+        cid = self.id
         prefix = consumer._deliver_prefix
         plen = len(prefix)
         fixed = 25 + plen
-        cid = self.id
-        hist = metrics.publish_to_deliver_us
-        buckets = hist.buckets
-        bounds = hist.BOUNDS
-        now_ns = time.perf_counter_ns()
         popleft = messages.popleft
         delivered = 0
         while messages:
-            pend = conn._egress_pending
-            opened = not pend
-            room = first_room = conn.egress_room()
-            batch_room = conn._egress_cap - conn._egress_bytes
-            batch_full = False
-            tag = first_tag = self._delivery_tag
+            pend = run.pend
+            room = run.room
+            batch_room = run.batch_room
+            tag = first_tag = run.tag
+            nbytes = run.nbytes
+            waited_ns = run.waited_ns
+            keep = drain.room
             buffered = consumer.buffered_bytes
             top_offset = queue.last_consumed
             top = last_ref = None
-            ready = nbytes = waited_ns = 0
+            ready = freed = kept = 0
+            batch_full = False
             try:
                 while messages:
                     qm = messages[0]
@@ -399,38 +520,62 @@ class ServerChannel:
                     left = msg.refer_count = msg.refer_count - 1
                     if left <= 0 and (msg.accounted or msg.persisted
                                       or msg.paged):
-                        last_ref = msg
-                        break
+                        if msg.persisted or msg.paged or blen >= keep:
+                            last_ref = msg
+                            break
+                        msg.accounted = False
+                        keep -= blen
+                        freed += blen
+                        kept += 1
             finally:
                 n = tag - first_tag
                 if n:
                     delivered += n
-                    self._delivery_tag = tag
-                    if opened:
-                        conn.egress_opened()
-                    conn._egress_records += n
-                    conn._egress_bytes += first_room - room
-                    conn.delivered_msgs += n
+                    run.tag = tag
+                    run.room = room
+                    run.batch_room = batch_room
+                    run.nbytes = nbytes
+                    run.waited_ns = waited_ns
                     if limit:
                         consumer.buffered_bytes = buffered
-                    metrics.delivered_msgs += n
-                    metrics.delivered_bytes += nbytes
-                    metrics.dispatch_run_msgs += n
-                    hist.count += n
-                    hist.total_us += waited_ns // 1000
                     queue.ready_bytes -= ready
                     if queue._counted:
                         broker.queue_depth -= n
                     queue.n_delivered += n
                     if top is not None:
                         queue._advance_watermark(top)
+                if kept:
+                    drain.room = keep
+                    drain.freed += freed
+                    drain.kept += kept
             if batch_full:
+                run.handover()
                 conn.flush_egress()
+                run.reload()
             elif last_ref is None:
                 break
             else:
+                drain.close()
                 broker.unrefer_n(last_ref, 0)
+                run = self._open_head_run(drain)
+                if run is None:
+                    break
         return delivered
+
+    def _open_head_run(self, drain: DispatchDrain) -> Optional[HeadRun]:
+        """Open this channel's head run in the drain; None when its
+        deliveries are not a run's."""
+        conn = self.connection
+        if (conn._egress is None or not self.flow_active or self.closed
+                or trace.ACTIVE is not None):
+            return None
+        fh = events.FIREHOSE
+        if fh is not None and fh.tap_bindings:
+            return None
+        tenant = conn.tenant
+        if tenant is not None and tenant.latency_hist is not None:
+            return None
+        return drain.open(self)
 
     def _render_deliver(
         self, consumer: Consumer, tag: int, redelivered: bool, msg, body: bytes

@@ -230,6 +230,10 @@ class AMQPConnection:
         self._egress_records = 0
         self._egress_bytes = 0
         self._egress_guard_scheduled = False
+        # the head run a dispatch drain has open on one of this
+        # connection's channels (channel.HeadRun), which holds the
+        # connection's egress counts until it hands them over
+        self._head_run = None
         self._writer_task: Optional[asyncio.Task] = None
         self._heartbeat_task: Optional[asyncio.Task] = None
         self._last_recv = time.monotonic()

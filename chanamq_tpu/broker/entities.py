@@ -39,7 +39,7 @@ from .matchers import Matcher, matcher_for
 
 if TYPE_CHECKING:  # pragma: no cover
     from .broker import Broker
-    from .channel import Consumer, ServerChannel
+    from .channel import Consumer, DispatchDrain, ServerChannel
 
 
 log = logging.getLogger("chanamq.broker")
@@ -617,7 +617,7 @@ class Queue:
             asyncio.get_event_loop().call_soon(self.broker.drain_dispatch)
         ready.append(self)
 
-    def _dispatch(self) -> int:
+    def _dispatch(self, drain: DispatchDrain) -> int:
         """One coalesced dispatch pass: round-robin messages to eligible
         consumers until either runs out (reference's fair poll,
         AMQChannel.scala:43-48 + FrameStage.scala:380-443, turned inside out
@@ -625,9 +625,11 @@ class Queue:
 
         The pass buffers its deliveries on their connections and renders
         nothing: Broker.drain_dispatch, which runs the passes of a tick,
-        flushes each connection once when the last pass has run (a pass
-        called outside a drain leaves the flush to the connection's
-        call_soon guard)."""
+        flushes each connection once when the last pass has run.
+
+        A head run stays open on its channel for the rest of the drain
+        (ServerChannel.deliver_run, `drain`); before the loop below looks
+        at a head message, every open run hands its counts over."""
         self._dispatch_scheduled = False
         if self.deleted:
             return 0
@@ -643,7 +645,10 @@ class Queue:
             # every head message for which each check below comes out
             # trivially true in one loop (ServerChannel.deliver_run); the
             # loop below goes on from the first message it left
-            consumers[0].channel.deliver_run(consumers[0], self, messages)
+            consumers[0].channel.deliver_run(
+                consumers[0], self, messages, drain)
+        if messages and drain.runs:
+            drain.close()
         while messages and self.consumers:
             # expiry is checked on the head inline (no clock read for the
             # overwhelming TTL-less case); head checks and the pop below

@@ -179,6 +179,18 @@ class MemoryAccountant:
             return sys.maxsize
         return self.enter[stage + 1] - (self.total - self.components["held"])
 
+    def room_down(self) -> int:
+        """headroom()'s mirror: bytes a gate-counted component may still
+        shrink by before reevaluate() would de-escalate, the distance down
+        to exit[stage] (no limit at stage 0, nor at the pinned floor, where
+        a drop cannot move the stage). A caller that takes off less than
+        this in one step leaves the ladder where taking it off piece by
+        piece would, for the reasons headroom() gives."""
+        stage = self.stage
+        if stage <= self.floor:
+            return sys.maxsize
+        return (self.total - self.components["held"]) - self.exit[stage]
+
     async def cluster_stall(self, timeout: float = 0.25) -> None:
         """One bounded wait for pressure to drop below the cluster stage.
         Callers loop (or simply proceed after the timeout): a bounded

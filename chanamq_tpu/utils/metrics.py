@@ -346,10 +346,16 @@ class Metrics:
         # rather than one by one. With delivered_msgs: deliveries a pass,
         # and the share of them the run takes. dispatch_drains: the
         # once-a-tick callbacks (Broker.drain_dispatch) that ran at least
-        # one such pass; dispatch_passes over it is passes a drain
+        # one such pass; dispatch_passes over it is passes a drain.
+        # dispatch_run_setups: head runs a drain opened (one a consuming
+        # channel a drain, again after a hand-over that closed it);
+        # dispatch_run_releases: last references a head run kept and
+        # released after their message, in one step
         self.dispatch_passes = 0
         self.dispatch_run_msgs = 0
         self.dispatch_drains = 0
+        self.dispatch_run_setups = 0
+        self.dispatch_run_releases = 0
         # the enqueue run of a deferred flush (broker/broker.py
         # Broker._enqueue_run): publishes it built and pushed in its own
         # loop, those routed nowhere included, and the pushes it made;
@@ -637,6 +643,8 @@ class Metrics:
             "dispatch_passes": self.dispatch_passes,
             "dispatch_run_msgs": self.dispatch_run_msgs,
             "dispatch_drains": self.dispatch_drains,
+            "dispatch_run_setups": self.dispatch_run_setups,
+            "dispatch_run_releases": self.dispatch_run_releases,
             "enqueue_run_msgs": self.enqueue_run_msgs,
             "enqueue_run_pushes": self.enqueue_run_pushes,
             "forecast_samples": self.forecast_samples,

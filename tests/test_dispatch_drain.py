@@ -15,7 +15,7 @@ import asyncio
 
 import pytest
 
-from chanamq_tpu.broker.channel import Consumer
+from chanamq_tpu.broker.channel import Consumer, DispatchDrain
 from chanamq_tpu.broker.connection import WRITE_HIGH_WATERMARK
 
 from test_dispatch_run import PerMessageConsumer, World
@@ -29,7 +29,9 @@ def flush_after_each_pass(broker):
     def drain():
         ready, broker.dispatch_ready = broker.dispatch_ready, []
         for queue in ready:
-            queue._dispatch()
+            one = DispatchDrain(broker)
+            queue._dispatch(one)
+            one.close()
             for conn in list(broker.egress_dirty):
                 conn.flush_egress()
             broker.egress_dirty.clear()

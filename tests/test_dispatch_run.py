@@ -135,6 +135,10 @@ class World:
                      [(c.slow, c.buffered_bytes) for c in self.consumers]),
             "passes": m.dispatch_passes,
             "encoder_fallbacks": m.native_egress_fallbacks,
+            "ladder": (m.flow_escalations, m.flow_deescalations,
+                       self.broker.blocked,
+                       None if self.broker.flow is None
+                       else self.broker.flow.stage),
         }
 
 
@@ -297,6 +301,107 @@ def durable_queue(w):
     return 20
 
 
+# -- the drain: a channel's head run carried across the passes of one drain
+
+
+def many_queues_one_channel(w):
+    # one drain of forty queues consumed on one channel: one head run
+    # carries the channel's tags and the connection's batch through them
+    ch = w.channel(w.conn())
+    queues = [w.queue(f"one{i}") for i in range(40)]
+    for queue in queues:
+        w.consume(queue, ch)
+    for _ in range(120):
+        w.publish([w.rng.choice(queues)])
+    return 120
+
+
+def two_channels_interleaved(w):
+    # two channels of one connection alternate in the ready list: each
+    # pass hands the other channel's run over and opens its own
+    conn = w.conn()
+    channels = [w.channel(conn, 1), w.channel(conn, 2)]
+    queues = [w.queue(f"alt{i}") for i in range(12)]
+    for i, queue in enumerate(queues):
+        w.consume(queue, channels[i % 2])
+    n = 0
+    for queue in queues:
+        for _ in range(w.rng.randrange(1, 5)):
+            w.publish([queue])
+            n += 1
+    return n
+
+
+def ttl_head_then_run_queues(w):
+    # the first queue's head run stops at a TTL message and its per-message
+    # loop takes the rest; the queues of the same channel after it in the
+    # drain open the channel's run again
+    ch = w.channel(w.conn())
+    first = w.queue("ttl-first")
+    rest = [w.queue(f"after{i}") for i in range(3)]
+    for queue in (first, *rest):
+        w.consume(queue, ch)
+    for i in range(10):
+        if i == 4:
+            _with_ttl(w, first)  # a day to run: delivered one by one
+        else:
+            w.publish([first])
+    for queue in rest:
+        for _ in range(6):
+            w.publish([queue])
+    return 4 + 18
+
+
+def batch_full_mid_drain(w):
+    # 2 kB bodies over thirty queues of one channel: the pending batch
+    # outgrows a pooled buffer more than once inside the one drain
+    ch = w.channel(w.conn())
+    queues = [w.queue(f"full{i}") for i in range(30)]
+    for queue in queues:
+        w.consume(queue, ch)
+        for _ in range(10):
+            w.publish([queue], body=bytes([65 + w.rng.randrange(26)]) * 2000)
+    return 300
+
+
+def releases_cross_the_exit_mid_drain(w):
+    # listener_writes_mid_run over eight queues of one channel: the last
+    # references the run keeps would reach the ladder's exit thresholds
+    # (stage 2's at 1,500 resident bytes, stage 1's at 900) in later passes
+    # of the drain; the release that reaches one is made at its own
+    # message, and the listeners write between the same two deliveries
+    conn = w.conn()
+    ch = w.channel(conn)
+    w.broker.blocked_listeners.add(
+        lambda blocked: conn.send_bytes(b"<blocked>" if blocked
+                                        else b"<unblocked>"))
+    w.broker.flow_stage_listeners.add(
+        lambda old, new: conn.send_bytes(b"<stage %d>" % new))
+    queues = [w.queue(f"gate{i}") for i in range(8)]
+    for queue in queues:
+        w.consume(queue, ch)
+    for i in range(40):
+        w.publish([queues[i % 8]], body=b"%03d" % i * 33 + b"!")
+    assert w.broker.blocked
+    return 40
+
+
+def fanout_last_reference_in_a_later_pass(w):
+    # each message goes to two to five of six queues of one channel: its
+    # last reference falls in the pass of the last of them, later in the
+    # drain than its first delivery; the accountant is on, at stage 0
+    ch = w.channel(w.conn())
+    queues = [w.queue(f"fan{i}") for i in range(6)]
+    for queue in queues:
+        w.consume(queue, ch)
+    n = 0
+    for _ in range(60):
+        targets = w.rng.sample(queues, w.rng.randrange(2, 6))
+        w.publish(targets)
+        n += len(targets)
+    return n
+
+
 EQUIVALENT = {build.__name__: (build, broker_kw) for build, broker_kw in (
     (plain_run, {}),
     (several_queues_two_connections, {}),
@@ -312,6 +417,14 @@ EQUIVALENT = {build.__name__: (build, broker_kw) for build, broker_kw in (
     (listener_writes_mid_run, {"memory_high_watermark": 3000,
                                "memory_low_watermark": 1500}),
     (durable_queue, {}),
+    (many_queues_one_channel, {}),
+    (two_channels_interleaved, {}),
+    (ttl_head_then_run_queues, {}),
+    (batch_full_mid_drain, {}),
+    (releases_cross_the_exit_mid_drain, {"memory_high_watermark": 3000,
+                                         "memory_low_watermark": 1500}),
+    (fanout_last_reference_in_a_later_pass,
+     {"memory_high_watermark": 1 << 30}),
 )}
 
 
@@ -370,6 +483,71 @@ async def test_the_cases_stop_where_they_say():
     assert not w.broker.blocked
     # the unblock lands between two deliveries, not after the last
     assert 0 < wire.index(b"<unblocked>") < wire.rindex(b"g" * 100)
+
+    w, _, _ = await _both("many_queues_one_channel", 5)
+    m = w.broker.metrics
+    assert m.dispatch_drains == 1 and m.dispatch_run_setups == 1
+    w, _, _ = await _both("two_channels_interleaved", 5)
+    assert w.broker.metrics.dispatch_run_setups == 12  # one a pass
+    w, _, _ = await _both("ttl_head_then_run_queues", 5)
+    m = w.broker.metrics
+    assert m.dispatch_drains == 1 and m.dispatch_run_setups == 2
+    w, _, _ = await _both("batch_full_mid_drain", 5)
+    m = w.broker.metrics
+    assert m.dispatch_run_setups == 1 and m.native_egress_batches >= 3
+    w, _, _ = await _both("releases_cross_the_exit_mid_drain", 5)
+    wire = b"".join(bytes(part) for part in w.conns[0]._out)
+    # queue i % 8 holds message i: the 25th release (queue 4's last message,
+    # 36) leaves 1,500 bytes, the 31st (queue 6's first, 6) 900, each
+    # written right after its own delivery; the wire
+    # opens with the escalations the publishes made
+    assert wire.startswith(b"<stage 1><blocked><stage 2>")
+    assert (wire.index(b"036" * 33) < wire.index(b"<unblocked>")
+            < wire.rindex(b"<stage 1>") < wire.index(b"005" * 33))
+    assert (wire.index(b"006" * 33) < wire.index(b"<stage 0>")
+            < wire.index(b"014" * 33))
+    w, _, _ = await _both("fanout_last_reference_in_a_later_pass", 5)
+    m = w.broker.metrics
+    assert m.dispatch_run_setups == 1 and m.dispatch_run_releases == 60
+    assert w.broker.resident_bytes == 0
+    assert all(msg.refer_count == 0 for msg in w.messages)
+
+
+async def test_the_head_run_counters():
+    """dispatch_run_setups counts one head run a consuming channel a drain;
+    dispatch_run_releases counts the last references released after their
+    message, in one step, and none of those released at their own."""
+    w = World(Consumer, 3)
+    if w.broker.egress_encoder is None:
+        pytest.skip("native egress encoder not built")
+    conns = [w.conn(), w.conn()]
+    channels = [w.channel(conns[0]), w.channel(conns[1])]
+    queues = [w.queue(f"c{i}") for i in range(10)]
+    for i, queue in enumerate(queues):
+        w.consume(queue, channels[i % 2])
+    m = w.broker.metrics
+    for drains in (1, 2):
+        for queue in queues:
+            for _ in range(3):
+                w.publish([queue], body=b"c" * 10)
+        await asyncio.sleep(0)  # one tick: one drain
+        assert m.dispatch_drains == drains
+        assert m.dispatch_run_setups == 2 * drains
+        assert m.dispatch_run_releases == m.dispatch_run_msgs == 30 * drains
+        assert conns[0]._head_run is conns[1]._head_run is None
+    assert w.broker.resident_bytes == 0
+
+    # 40 messages of 100 bytes on one queue, released from 4,000 resident
+    # bytes: the 25th release reaches stage 2's exit (1,500) and the 31st
+    # stage 1's (900); those two are made at their message, each after a
+    # hand-over that closes the run, which then opens again
+    w, ref, _ = await _both("listener_writes_mid_run", 5)
+    m = w.broker.metrics
+    assert m.dispatch_drains == 1 and m.dispatch_run_setups == 3
+    assert m.dispatch_run_releases == 40 - 2
+    assert m.flow_deescalations == ref.broker.metrics.flow_deescalations == 2
+    assert ref.broker.metrics.dispatch_run_setups == 0
+    assert ref.broker.metrics.dispatch_run_releases == 0
 
 
 # -- the run is not taken ---------------------------------------------------
@@ -485,7 +663,7 @@ async def test_the_run_is_not_taken(case, monkeypatch):
         w = World(cls, 7, **broker_kw)
         build(w, monkeypatch)
         if case == "remote_consumer":
-            w.queues[0]._dispatch()
+            w.broker.drain_dispatch()
             assert w.remote._buf_count == 12
             w.remote._buf = []  # nothing for the scheduled flush to ship
         await w.settle()

@@ -11,6 +11,7 @@ wall-clock tick timing.
 """
 
 import asyncio
+import sys
 
 import pytest
 
@@ -106,6 +107,37 @@ async def test_accountant_held_excluded_from_gate_but_counted():
     acc.add("bodies", -1100)
     acc.add("held", -5000)
     assert acc.stage == STAGE_NORMAL and acc.total == 0
+
+
+async def test_accountant_room_down_is_the_distance_to_the_exit():
+    """room_down(), headroom()'s mirror: no limit at stage 0 or at the
+    pinned floor; elsewhere the gated total's distance down to the stage's
+    exit threshold, so that a drop taken in one step moves the stage
+    exactly where the same drop taken byte by byte does."""
+
+    def at(bodies, held=0, floor=STAGE_NORMAL):
+        acc = MemoryAccountant(high_watermark=1000, low_watermark=800)
+        acc.components["held"] = held
+        acc.floor = floor
+        acc.add("bodies", bodies)
+        return acc
+
+    assert at(500).stage == STAGE_NORMAL
+    assert at(500).room_down() == sys.maxsize
+    acc = at(1200, held=300)
+    assert acc.stage == STAGE_THROTTLE and acc.exit[STAGE_THROTTLE] == 800
+    room = acc.room_down()
+    assert room == 1200 - 800  # held is no gate input
+    for drop, stage in ((room - 1, STAGE_THROTTLE), (room, STAGE_PAGE)):
+        one_step, byte_by_byte = at(1200, held=300), at(1200, held=300)
+        one_step.add("bodies", -drop)
+        for _ in range(drop):
+            byte_by_byte.add("bodies", -1)
+        assert one_step.stage == byte_by_byte.stage == stage
+    pinned = at(1200, floor=STAGE_THROTTLE)
+    assert pinned.room_down() == sys.maxsize
+    pinned.add("bodies", -1200)
+    assert pinned.stage == STAGE_THROTTLE
 
 
 async def test_accountant_cluster_stall_bounded():

@@ -559,10 +559,16 @@ async def test_dispatch_counters_on_both_surfaces(stack):
     assert status == 200
     prom = dict(line.rsplit(" ", 1) for line in text.splitlines()
                 if line.startswith("chanamq_dispatch_"))
+    # the run's channel opens a head run in each drain that delivers on it,
+    # and every last reference is released after its message
+    assert 1 <= metrics["dispatch_run_setups"] <= metrics["dispatch_drains"]
+    assert metrics["dispatch_run_releases"] == 60
     assert prom == {
         "chanamq_dispatch_passes": str(metrics["dispatch_passes"]),
         "chanamq_dispatch_drains": str(metrics["dispatch_drains"]),
         "chanamq_dispatch_run_msgs": "60",
+        "chanamq_dispatch_run_setups": str(metrics["dispatch_run_setups"]),
+        "chanamq_dispatch_run_releases": "60",
     }
     types = {line.split()[2]: line.split()[3] for line in text.splitlines()
              if line.startswith("# TYPE chanamq_dispatch_")}

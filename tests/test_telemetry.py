@@ -369,6 +369,40 @@ async def test_health_flips_503_on_drain(telemetry_stack):
     assert status == 200 and body["live"] is True
 
 
+async def test_health_stays_503_through_dispatch_drains(telemetry_stack):
+    """A node that is going away keeps delivering to its consumers: the
+    dispatch drains that do so leave the readiness flag as they found it."""
+    server, admin = telemetry_stack
+    broker = server.broker
+    server.broker.telemetry.sample_tick(1.0)
+    c = await AMQPClient.connect("127.0.0.1", server.bound_port)
+    ch = await c.channel()
+    got = []
+    done = asyncio.Event()
+
+    def on_msg(msg):
+        got.append(msg.body)
+        if len(got) == 60:
+            done.set()
+
+    for name in ("drain_a", "drain_b", "drain_c"):
+        await ch.queue_declare(name)
+        await ch.basic_consume(name, on_msg, no_ack=True)
+    broker.draining = True
+    drains = broker.metrics.dispatch_drains
+    for i in range(20):
+        for name in ("drain_a", "drain_b", "drain_c"):
+            ch.basic_publish(b"m%d" % i, routing_key=name)
+    await asyncio.wait_for(done.wait(), 5)
+    assert broker.metrics.dispatch_drains > drains
+    assert broker.metrics.dispatch_run_msgs >= 60
+    assert broker.draining is True
+    status, body = await http_req(admin.bound_port, "/admin/health")
+    assert status == 503 and body["ready"] is False
+    assert any("draining" in r for r in body["reasons"])
+    await c.close()
+
+
 async def test_admin_telemetry_disabled_409():
     server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0)
     await server.start()
