@@ -22,7 +22,7 @@ from ..amqp.command import AMQCommand
 from ..amqp.constants import FRAME_OVERHEAD
 from ..amqp.frame import ENC_META
 from ..amqp.methods import Basic
-from .entities import Delivery, Queue, QueuedMessage
+from .entities import Delivery, Queue, QueuedMessage, now_ms
 
 if TYPE_CHECKING:  # pragma: no cover
     from .connection import AMQPConnection
@@ -83,11 +83,11 @@ class Consumer:
         self.buffered_bytes = 0
         self.slow = False
         # a dispatch pass may hand this consumer the queue's head run in
-        # one loop (ServerChannel.deliver_run): only the plain local no_ack
-        # consumer, whose deliveries leave nothing outstanding. A subclass
-        # delivers per message, as does the cluster's RemoteConsumer, which
-        # has no such attribute
-        self.takes_runs = no_ack and type(self) is Consumer
+        # one loop (ServerChannel.deliver_run): only the plain local
+        # consumer, no_ack or acknowledging. A subclass delivers per
+        # message, as does the cluster's RemoteConsumer, which has no such
+        # attribute
+        self.takes_runs = type(self) is Consumer
         # precomputed basic.deliver method-payload prefix:
         # class 60, method 60, shortstr consumer-tag
         tag_b = tag.encode("utf-8")
@@ -411,8 +411,9 @@ class ServerChannel:
     def deliver_run(self, consumer: Consumer, queue: Queue, messages,
                     drain: DispatchDrain) -> int:
         """The head run of a dispatch pass: Queue._dispatch's loop body and
-        deliver() above as one loop, for the one plain no_ack consumer
-        (`takes_runs`) of a FIFO queue. Pops and buffers head messages up
+        deliver() above as one loop, for the one plain consumer
+        (`takes_runs`) of a FIFO queue; an acknowledging one's stretch is
+        _deliver_acked_run's loop. Pops and buffers head messages up
         to the first one for which a per-message check is not trivially
         true (dead, any TTL, a passivated body, the write watermark, the
         consumer-buffer bound) and leaves that one, unpopped, to the
@@ -443,6 +444,8 @@ class ServerChannel:
             run = self._open_head_run(drain)
             if run is None:
                 return 0
+        if not consumer.no_ack:
+            return self._deliver_acked_run(run, consumer, queue, messages)
         conn = run.conn
         broker = drain.broker
         limit = run.limit
@@ -560,6 +563,155 @@ class ServerChannel:
                 run = self._open_head_run(drain)
                 if run is None:
                     break
+        return delivered
+
+    def _deliver_acked_run(self, run: HeadRun, consumer: Consumer,
+                           queue: Queue, messages) -> int:
+        """deliver_run's stretch for an acknowledging consumer, a loop of
+        its own so that the no_ack loop stays as it is. Each delivery is
+        made outstanding as deliver() and Queue._dispatch make it: a
+        Delivery in `unacked` and `queue.outstanding`, stamped with the
+        stretch's one clock reading, the message's reference kept for the
+        ack to release. The credit is Consumer.can_take's prefetch budgets
+        (per consumer and channel-global, count and size, one oversized
+        delivery let through while nothing is outstanding), read once a
+        call; the stretch stops, unpopped, at the first message the budget
+        refuses, where the per-message loop finds no eligible consumer.
+        The consumer's unacked count and size and the broker's
+        `queue_unacked` are added to once a stretch."""
+        conn = run.conn
+        broker = conn.broker
+        metrics = run.metrics
+        limit = run.limit
+        chunk = run.chunk
+        buckets = run.buckets
+        bounds = run.bounds
+        now_ns = time.perf_counter_ns()
+        at_ms = now_ms()
+        cid = self.id
+        ctag = consumer.tag
+        prefix = consumer._deliver_prefix
+        plen = len(prefix)
+        fixed = 25 + plen
+        popleft = messages.popleft
+        unacked = self.unacked
+        outstanding = queue.outstanding
+        per_size = self.prefetch_size_consumer
+        glob_size = self.prefetch_size_global
+        held = consumer.unacked_count
+        held_size = consumer.unacked_size
+        total = self.total_unacked_count()
+        total_size = self.total_unacked_size() if glob_size else 0
+        per_count = self.prefetch_count_consumer
+        glob_count = self.prefetch_count_global
+        credit = per_count - held if per_count else len(messages)
+        if glob_count:
+            credit = min(credit, glob_count - total)
+        sized = per_size or glob_size
+        delivered = 0
+        while messages:
+            pend = run.pend
+            room = run.room
+            batch_room = run.batch_room
+            tag = first_tag = run.tag
+            nbytes = run.nbytes
+            waited_ns = run.waited_ns
+            buffered = consumer.buffered_bytes
+            top_offset = queue.last_consumed
+            top = None
+            ready = taken = 0
+            batch_full = stopped = False
+            try:
+                while messages:
+                    qm = messages[0]
+                    msg = qm.message
+                    body = msg.body
+                    size = qm.body_size
+                    if (qm.dead or qm.expire_at_ms is not None
+                            or body is None or room <= 0
+                            or (limit and buffered
+                                and buffered + size > limit)):
+                        break
+                    if credit <= 0 or (sized and (
+                            (per_size and held
+                             and held_size + size > per_size)
+                            or (glob_size and total
+                                and total_size + size > glob_size))):
+                        stopped = True
+                        break
+                    exrk = msg.exrk_raw
+                    if exrk is None:
+                        exrk = _exrk_of(msg)
+                    header = msg.header_raw
+                    if header is None:
+                        header = msg.header_payload()
+                    elen = len(exrk)
+                    hlen = len(header)
+                    blen = len(body)
+                    if not blen:
+                        wire = fixed + elen + hlen
+                    elif chunk:
+                        wire = (fixed + elen + hlen + blen
+                                + 8 * -(-blen // chunk))
+                    else:
+                        wire = fixed + elen + hlen + blen + 8
+                    if wire > batch_room and pend:
+                        batch_full = True
+                        break
+                    popleft()
+                    tag += 1
+                    pend += (_ENC_META_PACK(
+                        cid, tag, 1 if qm.redelivered else 0,
+                        plen, elen, hlen, blen), prefix, exrk, header, body)
+                    room -= wire
+                    batch_room -= wire
+                    ready += size
+                    taken += blen
+                    nbytes += blen
+                    buffered += blen
+                    waited = now_ns - msg.published_ns
+                    waited_ns += waited
+                    buckets[bisect_left(bounds, waited / 1000.0)] += 1
+                    offset = qm.offset
+                    if offset > top_offset:
+                        top_offset = offset
+                        top = qm
+                    unacked[tag] = outstanding[offset] = Delivery(
+                        qm, queue, self, ctag, tag, False, at_ms)
+                    credit -= 1
+                    if sized:
+                        held += 1
+                        held_size += blen
+                        total += 1
+                        total_size += size
+            finally:
+                n = tag - first_tag
+                if n:
+                    delivered += n
+                    run.tag = tag
+                    run.room = room
+                    run.batch_room = batch_room
+                    run.nbytes = nbytes
+                    run.waited_ns = waited_ns
+                    if limit:
+                        consumer.buffered_bytes = buffered
+                    consumer.unacked_count += n
+                    consumer.unacked_size += taken
+                    queue.ready_bytes -= ready
+                    if queue._counted:
+                        broker.queue_depth -= n
+                        broker.queue_unacked += n
+                    queue.n_delivered += n
+                    metrics.dispatch_run_unacked += n
+                    if top is not None:
+                        queue._advance_watermark(top)
+            if stopped:
+                metrics.dispatch_run_credit_stops += 1
+            if not batch_full:
+                break
+            run.handover()
+            conn.flush_egress()
+            run.reload()
         return delivered
 
     def _open_head_run(self, drain: DispatchDrain) -> Optional[HeadRun]:

@@ -151,6 +151,7 @@ class Delivery:
         consumer_tag: str,
         delivery_tag: int,
         no_ack: bool,
+        delivered_at_ms: Optional[int] = None,
     ) -> None:
         self.queued = queued
         self.queue = queue
@@ -160,8 +161,10 @@ class Delivery:
         self.no_ack = no_ack
         # ack-timeout clock (chana.mq.consumer.timeout; RabbitMQ's
         # consumer_timeout): a delivery unacked past the deadline closes
-        # its channel so a stuck consumer can't pin messages forever
-        self.delivered_at_ms = now_ms()
+        # its channel so a stuck consumer can't pin messages forever. A
+        # head run passes the one reading it takes a stretch
+        self.delivered_at_ms = (now_ms() if delivered_at_ms is None
+                                else delivered_at_ms)
 
 
 class Queue:
@@ -639,12 +642,15 @@ class Queue:
         consumers = self.consumers
         if (len(consumers) == 1 and messages
                 and getattr(consumers[0], "takes_runs", False)
+                and (consumers[0].no_ack or not self.durable)
                 and self.max_priority is None and not self.single_active
                 and self._prio_groups is None):
-            # the head run: one plain no_ack consumer of a FIFO queue takes
-            # every head message for which each check below comes out
-            # trivially true in one loop (ServerChannel.deliver_run); the
-            # loop below goes on from the first message it left
+            # the head run: one plain consumer of a FIFO queue takes every
+            # head message for which each check below comes out trivially
+            # true in one loop (ServerChannel.deliver_run); the loop below
+            # goes on from the first message it left. An acknowledging
+            # consumer takes it only on a transient queue: on a durable one
+            # a delivery writes its unack row (`new_unacks`)
             consumers[0].channel.deliver_run(
                 consumers[0], self, messages, drain)
         if messages and drain.runs:

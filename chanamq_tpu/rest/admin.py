@@ -864,6 +864,7 @@ class AdminServer:
         "wal_checkpoint_drain_ns", "wal_checkpoint_flush_ns",
         "wal_checkpoint_sync_ns", "wal_checkpoint_ns",
         "enqueue_run_msgs", "enqueue_run_pushes",
+        "dispatch_run_unacked", "dispatch_run_credit_stops",
         "egress_render_ns", "egress_write_ns", "egress_writev_calls",
         "egress_write_spills",
         *loopbooks.SUMS,

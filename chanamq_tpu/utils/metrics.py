@@ -350,12 +350,17 @@ class Metrics:
         # dispatch_run_setups: head runs a drain opened (one a consuming
         # channel a drain, again after a hand-over that closed it);
         # dispatch_run_releases: last references a head run kept and
-        # released after their message, in one step
+        # released after their message, in one step;
+        # dispatch_run_unacked: deliveries a head run made outstanding for
+        # an acknowledging consumer, added once a stretch;
+        # dispatch_run_credit_stops: stretches a prefetch budget ended
         self.dispatch_passes = 0
         self.dispatch_run_msgs = 0
         self.dispatch_drains = 0
         self.dispatch_run_setups = 0
         self.dispatch_run_releases = 0
+        self.dispatch_run_unacked = 0
+        self.dispatch_run_credit_stops = 0
         # the enqueue run of a deferred flush (broker/broker.py
         # Broker._enqueue_run): publishes it built and pushed in its own
         # loop, those routed nowhere included, and the pushes it made;
@@ -645,6 +650,8 @@ class Metrics:
             "dispatch_drains": self.dispatch_drains,
             "dispatch_run_setups": self.dispatch_run_setups,
             "dispatch_run_releases": self.dispatch_run_releases,
+            "dispatch_run_unacked": self.dispatch_run_unacked,
+            "dispatch_run_credit_stops": self.dispatch_run_credit_stops,
             "enqueue_run_msgs": self.enqueue_run_msgs,
             "enqueue_run_pushes": self.enqueue_run_pushes,
             "forecast_samples": self.forecast_samples,

@@ -234,8 +234,8 @@ async def test_a_batch_never_outgrows_a_pooled_buffer(no_ack, monkeypatch):
     """A tick that buffers several pooled buffers' worth on one connection
     renders into the pool in several batches: the pending batch is flushed
     before the record that would not fit, by the head run and by
-    egress_deliver alike."""
-    w = World(Consumer, 3)
+    egress_deliver alike (acknowledging consumers that take no run)."""
+    w = World(Consumer if no_ack else PerMessageConsumer, 3)
     enc = w.broker.egress_encoder
     if enc is None:
         pytest.skip("native egress encoder not built")

@@ -1,14 +1,17 @@
 """The head run of a dispatch pass is held to the per-message path.
 
 `ServerChannel.deliver_run` delivers the head messages of a queue to its one
-plain `no_ack` consumer in one loop; whatever it cannot prove it leaves to
-the per-message loop of `Queue._dispatch`. Here the same seeded state is
-built twice and dispatched (a) with the run and (b) with the run excluded by
-the test alone: the queue's consumers are a trivial subclass of `Consumer`,
-which the type condition (`takes_runs`) excludes; the product has no switch.
-Both worlds must write the same bytes to every connection and end in the
-same state; the cases that the run must not take at all show
-`dispatch_run_msgs` at 0.
+plain consumer in one loop, `no_ack` or acknowledging (on a transient
+queue); whatever it cannot prove it leaves to the per-message loop of
+`Queue._dispatch`. Here the same seeded state is built twice and dispatched
+(a) with the run and (b) with the run excluded by the test alone: the
+queue's consumers are a trivial subclass of `Consumer`, which the type
+condition (`takes_runs`) excludes; the product has no switch. Both worlds
+must write the same bytes to every connection and end in the same state,
+the deliveries left outstanding and the prefetch counts among it; then
+every delivery is acknowledged in both and the states, refcounts and
+resident bytes included, must agree again. The cases that the run must not
+take at all show `dispatch_run_msgs` at 0.
 """
 
 import asyncio
@@ -52,6 +55,8 @@ class World:
         self.queues = []
         self.consumers = []
         self.messages = []
+        # what a case does once the first passes have run
+        self.after = []
 
     def conn(self, frame_max=4096):
         conn = AMQPConnection(self.broker, None, _Writer(),
@@ -112,6 +117,20 @@ class World:
         for conn in self.conns:
             conn.flush_egress()
 
+    async def ack_all(self):
+        """Acknowledge every outstanding delivery, each channel's in tag
+        order, until the passes the acks schedule leave none."""
+        for _ in range(50):
+            pending = [(ch, tag) for conn in self.conns
+                       for ch in conn.channels.values()
+                       for tag in sorted(ch.unacked)]
+            if not pending:
+                return
+            for ch, tag in pending:
+                ch.ack(ch.unacked[tag])
+            await self.settle()
+        raise AssertionError("deliveries still outstanding")
+
     def state(self):
         m = self.broker.metrics
         return {
@@ -124,6 +143,17 @@ class World:
             "queues": [(q.name, q.n_delivered, q.ready_bytes, q.last_consumed,
                         [qm.offset for qm in q.messages], len(q.outstanding))
                        for q in self.queues],
+            "unacked": [{cid: [(tag, d.consumer_tag, d.queued.offset,
+                                d.no_ack) for tag, d in ch.unacked.items()]
+                         for cid, ch in conn.channels.items()}
+                        for conn in self.conns],
+            "outstanding": [[(offset, d.delivery_tag)
+                             for offset, d in q.outstanding.items()]
+                            for q in self.queues],
+            "prefetch_held": [(c.unacked_count, c.unacked_size)
+                              for c in self.consumers],
+            "queue_unacked": self.broker.queue_unacked,
+            "acked": self.broker.metrics.acked_msgs,
             "queue_depth": self.broker.queue_depth,
             "resident": self.broker.resident_bytes,
             "refs": [(msg.refer_count, msg.accounted, msg.body is None)
@@ -402,6 +432,125 @@ def fanout_last_reference_in_a_later_pass(w):
     return n
 
 
+# -- acknowledging consumers: the run makes each delivery outstanding, and
+# stops where a prefetch budget refuses the next message
+
+
+def acked_consumer(w):
+    conn = w.conn()
+    queue = w.queue("q")
+    for _ in range(12):
+        w.publish([queue])
+    w.consume(queue, w.channel(conn), no_ack=False)
+    return 12
+
+
+def acked_plain_run(w):
+    conns = [w.conn(), w.conn(frame_max=131072)]
+    queues = []
+    for i in range(5):
+        queue = w.queue(f"ack{i}")
+        w.consume(queue, w.channel(conns[i % 2], 1 + i % 2), no_ack=False)
+        queues.append(queue)
+    n = 0
+    for _ in range(60):
+        targets = w.rng.sample(queues, w.rng.randrange(1, 4))
+        w.publish(targets)
+        n += len(targets)
+    return n
+
+
+def acked_count_prefetch_mid_run(w):
+    # a per-consumer prefetch of 5 over 20 messages: each pass takes five,
+    # and the acks open the window for the next five
+    ch = w.channel(w.conn())
+    ch.prefetch_count_consumer = 5
+    queue = w.queue("window")
+    w.consume(queue, ch, no_ack=False)
+    for _ in range(20):
+        w.publish([queue])
+    return 5
+
+
+def acked_size_prefetch_oversized(w):
+    # a per-consumer prefetch of 1,000 bytes: three of 100 pass, the 5,000
+    # byte one waits for the window to empty and then passes alone
+    # (RabbitMQ's one oversized delivery while nothing is outstanding)
+    ch = w.channel(w.conn())
+    ch.prefetch_size_consumer = 1000
+    queue = w.queue("bytes")
+    w.consume(queue, ch, no_ack=False)
+    for size in (100, 100, 100, 5000, 100, 100, 100, 100, 100):
+        w.publish([queue], body=b"z" * size)
+    return 3
+
+
+def acked_global_prefetch_several_queues(w):
+    # a channel-global prefetch of 7 over four queues of one channel: the
+    # first queues' passes take seven, the later ones find it spent
+    ch = w.channel(w.conn())
+    ch.prefetch_count_global = 7
+    queues = [w.queue(f"glob{i}") for i in range(4)]
+    for queue in queues:
+        w.consume(queue, ch, no_ack=False)
+        for _ in range(5):
+            w.publish([queue])
+    return 7
+
+
+def acked_global_size_prefetch(w):
+    # a channel-global byte prefetch over three queues with seeded bodies:
+    # the window is the channel's, its bytes summed over every delivery
+    ch = w.channel(w.conn())
+    ch.prefetch_size_global = 3000
+    queues = [w.queue(f"gbytes{i}") for i in range(3)]
+    for queue in queues:
+        w.consume(queue, ch, no_ack=False)
+        for _ in range(6):
+            w.publish([queue], body=b"y" * w.rng.randrange(1, 1500))
+    return None
+
+
+def acked_and_no_ack_interleaved(w):
+    # a no_ack channel and an acknowledging one of one connection alternate
+    # in the ready list: each pass hands the other's run over
+    conn = w.conn()
+    channels = [w.channel(conn, 1), w.channel(conn, 2)]
+    queues = [w.queue(f"mix{i}") for i in range(10)]
+    for i, queue in enumerate(queues):
+        w.consume(queue, channels[i % 2], no_ack=i % 2 == 0)
+    n = 0
+    for queue in queues:
+        for _ in range(w.rng.randrange(1, 6)):
+            w.publish([queue])
+            n += 1
+    return n
+
+
+def acked_channel_closed_outstanding(w):
+    # the channel closes with eight run-made deliveries outstanding and
+    # twelve messages still ready: the eight go back ahead of them, in
+    # offset order, marked redelivered
+    ch = w.channel(w.conn())
+    ch.prefetch_count_consumer = 8
+    queue = w.queue("closing")
+    w.consume(queue, ch, no_ack=False)
+    for _ in range(20):
+        w.publish([queue])
+    w.after.append(ch.release_all)
+    return 8
+
+
+def acked_durable_queue(w):
+    # on a durable queue a delivery writes its unack row: one by one
+    ch = w.channel(w.conn())
+    queue = w.queue("acked-durable", durable=True)
+    w.consume(queue, ch, no_ack=False)
+    for _ in range(20):
+        w.publish([queue], delivery_mode=2)
+    return 0
+
+
 EQUIVALENT = {build.__name__: (build, broker_kw) for build, broker_kw in (
     (plain_run, {}),
     (several_queues_two_connections, {}),
@@ -425,6 +574,15 @@ EQUIVALENT = {build.__name__: (build, broker_kw) for build, broker_kw in (
                                          "memory_low_watermark": 1500}),
     (fanout_last_reference_in_a_later_pass,
      {"memory_high_watermark": 1 << 30}),
+    (acked_consumer, {}),
+    (acked_plain_run, {}),
+    (acked_count_prefetch_mid_run, {}),
+    (acked_size_prefetch_oversized, {}),
+    (acked_global_prefetch_several_queues, {}),
+    (acked_global_size_prefetch, {}),
+    (acked_and_no_ack_interleaved, {}),
+    (acked_channel_closed_outstanding, {}),
+    (acked_durable_queue, {}),
 )}
 
 
@@ -439,8 +597,20 @@ async def _both(case, seed):
             pytest.skip("native egress encoder not built")
         expected = build(w)
         await w.settle()
+        for step in w.after:
+            step()
+        await w.settle()
         worlds.append(w)
     return worlds[0], worlds[1], expected
+
+
+def _same(run, ref):
+    got, want = run.state(), ref.state()
+    for key in want:
+        assert got[key] == want[key], key
+    assert want["encoder_fallbacks"] == 0
+    assert want["hist_count"] == want["hist_buckets"] == want["delivered"][0]
+    return want
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2147483659])
@@ -448,16 +618,22 @@ async def _both(case, seed):
 async def test_the_run_is_the_per_message_path(case, seed):
     run, ref, expected = await _both(case, seed)
     assert ref.broker.metrics.dispatch_run_msgs == 0
-    got, want = run.state(), ref.state()
-    for key in want:
-        assert got[key] == want[key], key
-    assert want["encoder_fallbacks"] == 0
-    assert want["hist_count"] == want["hist_buckets"] == want["delivered"][0]
-    in_run = run.broker.metrics.dispatch_run_msgs
+    want = _same(run, ref)
+    m = run.broker.metrics
+    in_run = m.dispatch_run_msgs
     if expected is None:
         assert 0 < in_run <= want["delivered"][0]
     else:
         assert in_run == expected
+    assert m.dispatch_run_unacked <= in_run
+    assert want["queue_unacked"] == sum(len(q) for q in want["outstanding"])
+    # every delivery acknowledged in both worlds, and the passes the acks
+    # scheduled run, the run's world again through its head runs
+    await run.ack_all()
+    await ref.ack_all()
+    want = _same(run, ref)
+    assert want["queue_unacked"] == 0 and not any(want["outstanding"])
+    assert want["prefetch_held"] == [(0, 0)] * len(run.consumers)
 
 
 async def test_the_cases_stop_where_they_say():
@@ -511,6 +687,61 @@ async def test_the_cases_stop_where_they_say():
     assert m.dispatch_run_setups == 1 and m.dispatch_run_releases == 60
     assert w.broker.resident_bytes == 0
     assert all(msg.refer_count == 0 for msg in w.messages)
+
+
+async def test_the_acked_cases_stop_where_they_say():
+    """Where each acknowledging case's budget binds, read from the run's
+    world before and after every delivery is acknowledged."""
+    w, ref, _ = await _both("acked_count_prefetch_mid_run", 5)
+    m = w.broker.metrics
+    consumer, queue = w.consumers[0], w.queues[0]
+    assert (m.dispatch_run_unacked, m.dispatch_run_credit_stops) == (5, 1)
+    assert (consumer.unacked_count, len(queue.messages)) == (5, 15)
+    assert w.broker.queue_unacked == 5 and len(queue.outstanding) == 5
+    await w.ack_all()
+    await ref.ack_all()
+    # four windows of five: the first three end at the budget, the last
+    # where the queue does
+    assert m.dispatch_run_msgs == m.dispatch_run_unacked == 20
+    assert m.dispatch_run_credit_stops == 3
+    assert m.acked_msgs == ref.broker.metrics.acked_msgs == 20
+    assert ref.broker.metrics.dispatch_run_credit_stops == 0
+
+    w, ref, _ = await _both("acked_size_prefetch_oversized", 5)
+    m = w.broker.metrics
+    assert w.consumers[0].unacked_size == 300
+    await w.ack_all()
+    await ref.ack_all()
+    # 100 x 3 | 5,000 alone | 100 x 5
+    assert m.dispatch_run_credit_stops == 2 and m.dispatch_run_msgs == 9
+    assert w.broker.resident_bytes == ref.broker.resident_bytes == 0
+
+    w, _, _ = await _both("acked_global_prefetch_several_queues", 5)
+    m = w.broker.metrics
+    # the second queue's pass spends the window, and it and the two after
+    # it stop at the budget, each after a hand-over that closed the run
+    assert m.dispatch_drains == 1 and m.dispatch_run_setups == 3
+    assert m.dispatch_run_credit_stops == 3
+    assert [len(q.outstanding) for q in w.queues] == [5, 2, 0, 0]
+
+    w, _, _ = await _both("acked_and_no_ack_interleaved", 5)
+    m = w.broker.metrics
+    assert m.dispatch_run_setups == 10  # one a pass
+    acked = sum(len(q.outstanding) for q in w.queues)
+    assert 0 < acked == m.dispatch_run_unacked < m.dispatch_run_msgs
+
+    w, _, _ = await _both("acked_channel_closed_outstanding", 5)
+    queue = w.queues[0]
+    offsets = [qm.offset for qm in queue.messages]
+    assert offsets == sorted(offsets) and len(offsets) == 20
+    assert [qm.redelivered for qm in queue.messages] == [True] * 8 + [False] * 12
+    assert w.broker.queue_unacked == 0 and not queue.outstanding
+    assert w.broker.metrics.dispatch_run_unacked == 8
+
+    w, _, _ = await _both("acked_durable_queue", 5)
+    m = w.broker.metrics
+    assert m.dispatch_run_msgs == m.dispatch_run_setups == 0
+    assert m.dispatch_run_unacked == 0 and len(w.queues[0].outstanding) == 20
 
 
 async def test_the_head_run_counters():
@@ -583,11 +814,6 @@ def _one_queue(w, n=12, **queue_kw):
     return conn, queue
 
 
-def acked_consumer(w, monkeypatch):
-    conn, queue = _one_queue(w)
-    w.consume(queue, w.channel(conn), no_ack=False)
-
-
 def two_consumers(w, monkeypatch):
     conn, queue = _one_queue(w)
     w.consume(queue, w.channel(conn, 1))
@@ -642,7 +868,6 @@ def no_native_encoder(w, monkeypatch):
 
 
 NOT_TAKEN = {build.__name__: (build, broker_kw) for build, broker_kw in (
-    (acked_consumer, {}),
     (two_consumers, {}),
     (priority_queue, {}),
     (consumer_with_priority, {}),

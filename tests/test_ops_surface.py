@@ -521,9 +521,9 @@ async def test_prometheus_metrics_endpoint(stack):
 async def test_dispatch_counters_on_both_surfaces(stack):
     """`dispatch_passes`, `dispatch_drains` and `dispatch_run_msgs` are on
     /admin/overview and /metrics and add up against `delivered_msgs`: the
-    no_ack consumer's deliveries are made inside head runs, the acked
-    consumer's one by one, and a drain (one callback a loop tick) runs one
-    pass or more."""
+    no_ack consumer's deliveries and the acked consumer's (a transient
+    queue) are made inside head runs, and a drain (one callback a loop
+    tick) runs one pass or more."""
     server, admin = stack
     c = await AMQPClient.connect("127.0.0.1", server.bound_port)
     ch = await c.channel()
@@ -549,7 +549,8 @@ async def test_dispatch_counters_on_both_surfaces(stack):
     assert status == 200
     metrics = overview["metrics"]
     assert metrics["delivered_msgs"] == 75
-    assert metrics["dispatch_run_msgs"] == 60
+    assert metrics["dispatch_run_msgs"] == 75
+    assert metrics["dispatch_run_unacked"] == 15
     # the backlog of 40 went in one pass; no pass is empty, none counted twice
     assert 2 <= metrics["dispatch_passes"] <= 75 - 39
     # a drain is counted only when a pass of it delivered
@@ -560,19 +561,87 @@ async def test_dispatch_counters_on_both_surfaces(stack):
     prom = dict(line.rsplit(" ", 1) for line in text.splitlines()
                 if line.startswith("chanamq_dispatch_"))
     # the run's channel opens a head run in each drain that delivers on it,
-    # and every last reference is released after its message
+    # and every last reference of the no_ack deliveries is released after
+    # its message; the acked ones keep theirs for the ack
     assert 1 <= metrics["dispatch_run_setups"] <= metrics["dispatch_drains"]
     assert metrics["dispatch_run_releases"] == 60
     assert prom == {
         "chanamq_dispatch_passes": str(metrics["dispatch_passes"]),
         "chanamq_dispatch_drains": str(metrics["dispatch_drains"]),
-        "chanamq_dispatch_run_msgs": "60",
+        "chanamq_dispatch_run_msgs": "75",
         "chanamq_dispatch_run_setups": str(metrics["dispatch_run_setups"]),
         "chanamq_dispatch_run_releases": "60",
+        "chanamq_dispatch_run_unacked": "15",
+        "chanamq_dispatch_run_credit_stops": "0",
     }
     types = {line.split()[2]: line.split()[3] for line in text.splitlines()
              if line.startswith("# TYPE chanamq_dispatch_")}
     assert types["chanamq_dispatch_drains"] == types["chanamq_dispatch_passes"]
+    await c.close()
+
+
+async def test_acked_head_run_counters_on_both_surfaces(stack):
+    """`dispatch_run_unacked` and `dispatch_run_credit_stops` are on
+    /admin/overview and typed `counter` at /metrics: an acknowledging
+    consumer under a prefetch of 5 takes a backlog of 20 in head runs of
+    five, each ended by the budget until the last, and every delivery the
+    runs made is outstanding until its ack."""
+    server, admin = stack
+    c = await AMQPClient.connect("127.0.0.1", server.bound_port)
+    ch = await c.channel()
+    await ch.queue_declare("window_q")
+    for i in range(20):
+        ch.basic_publish(b"w%d" % i, routing_key="window_q")
+    got = []
+    await ch.basic_qos(prefetch_count=5)
+    await ch.basic_consume("window_q", got.append, no_ack=False)
+
+    async def overview():
+        for _ in range(100):
+            status, body = await http_req(admin.bound_port, "/admin/overview")
+            assert status == 200
+            if body["metrics"]["delivered_msgs"] == len(got):
+                return body["metrics"]
+            await asyncio.sleep(0.02)
+        raise AssertionError("deliveries still in flight")
+
+    for _ in range(100):
+        if len(got) == 5:
+            break
+        await asyncio.sleep(0.02)
+    metrics = await overview()
+    assert [m.body for m in got] == [b"w%d" % i for i in range(5)]
+    assert metrics["dispatch_run_unacked"] == metrics["dispatch_run_msgs"] == 5
+    assert metrics["dispatch_run_credit_stops"] >= 1
+    assert server.broker.queue_unacked == 5
+    for window in range(1, 4):
+        ch.basic_ack(got[-1].delivery_tag, multiple=True)
+        for _ in range(100):
+            if len(got) == 5 * (window + 1):
+                break
+            await asyncio.sleep(0.02)
+    ch.basic_ack(got[-1].delivery_tag, multiple=True)
+    for _ in range(100):
+        if server.broker.queue_unacked == 0:
+            break
+        await asyncio.sleep(0.02)
+    metrics = await overview()
+    assert [m.body for m in got] == [b"w%d" % i for i in range(20)]
+    assert [m.delivery_tag for m in got] == list(range(1, 21))
+    assert metrics["dispatch_run_unacked"] == metrics["dispatch_run_msgs"] == 20
+    assert metrics["acked_msgs"] == 20
+    stops = metrics["dispatch_run_credit_stops"]
+    assert 3 <= stops <= 20
+
+    status, _ctype, text = await http_text(admin.bound_port, "/metrics")
+    assert status == 200
+    lines = text.splitlines()
+    assert "chanamq_dispatch_run_unacked 20" in lines
+    assert f"chanamq_dispatch_run_credit_stops {stops}" in lines
+    types = {line.split()[2]: line.split()[3] for line in lines
+             if line.startswith("# TYPE chanamq_dispatch_run_")}
+    assert types["chanamq_dispatch_run_unacked"] == "counter"
+    assert types["chanamq_dispatch_run_credit_stops"] == "counter"
     await c.close()
 
 
