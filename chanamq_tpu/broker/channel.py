@@ -105,6 +105,16 @@ class Consumer:
         self.channel.consumers.pop(self.tag, None)
         self.channel.connection.notify_consumer_cancel(self.channel, self.tag)
 
+    def release(self, count: int, size: int) -> None:
+        """Give back `count` settled deliveries of `size` body bytes of
+        the prefetch budget, clamped at 0: the same as giving them back
+        one by one (ServerChannel._release_budget, AMQPConnection._ack_run
+        once a stretch of one consumer)."""
+        left = self.unacked_count - count
+        self.unacked_count = left if left > 0 else 0
+        left = self.unacked_size - size
+        self.unacked_size = left if left > 0 else 0
+
     def can_take(self, next_size: int) -> bool:
         """Unified consumer-credit admission (reference:
         FrameStage.scala:387-392 + QueueEntity.scala:342-359): every
@@ -786,10 +796,7 @@ class ServerChannel:
     def _release_budget(self, delivery: Delivery) -> None:
         consumer = self.consumers.get(delivery.consumer_tag)
         if consumer is not None:
-            consumer.unacked_count = max(0, consumer.unacked_count - 1)
-            consumer.unacked_size = max(
-                0, consumer.unacked_size - delivery.queued.body_size
-            )
+            consumer.release(1, delivery.queued.body_size)
 
     # -- ack paths ---------------------------------------------------------
 

@@ -18,10 +18,11 @@ coalesces into one TCP write). Delivery pushes come from queue dispatch
 Hot loop (_consume_scan): the native scanner hands back frame-index arrays
 for a whole read chunk; contained Basic.Publish triples and Basic.Ack
 frames are handled straight off the arrays with no Frame/Method/AMQCommand
-objects (_fused_publish/_fused_ack), and everything else falls back to the
-per-frame assembler path. Batch boundaries double as barriers: publisher
-confirms, the store group-commit flush, and pipelined remote queue.push
-RPCs all settle once per read batch (_confirm_barrier).
+objects (_fused_publish; _ack_run, the single acks of one channel in a
+batch settled as one run, else _fused_ack), and everything else falls back
+to the per-frame assembler path. Batch boundaries double as barriers:
+publisher confirms, the store group-commit flush, and pipelined remote
+queue.push RPCs all settle once per read batch (_confirm_barrier).
 """
 
 from __future__ import annotations
@@ -30,6 +31,7 @@ import asyncio
 import logging
 import os
 import socket
+import struct
 import time
 import uuid
 from typing import Optional
@@ -103,6 +105,9 @@ _WRITEV_ENABLED = hasattr(os, "writev") and os.environ.get(
 # decode: Basic.Publish (class 60, method 40) and Basic.Ack (60, 80)
 _PUBLISH_SIG = b"\x00\x3c\x00\x28"
 _ACK_SIG = b"\x00\x3c\x00\x50"
+# a whole Basic.Ack method payload: class+method, delivery tag, bits
+_ACK_FRAME = struct.Struct(">IQB")
+_ACK_METHOD = 0x003C0050
 
 # fused-path publish-args cache: a flow's exchange+routing-key repeat on
 # every message, so their utf-8 decodes cache keyed by the raw args slice
@@ -1144,8 +1149,9 @@ class AMQPConnection:
                                     raw, i, n, types, channels, offsets,
                                     lengths)
                             elif sig == _ACK_SIG and lengths[i] == 13:
-                                consumed = self._fused_ack(
-                                    raw, off, channel_id)
+                                consumed = self._ack_run(
+                                    raw, i, n, types, channels, offsets,
+                                    lengths)
                     except HardError as exc:
                         await self._hard_close(
                             exc.code, exc.text, exc.class_id, exc.method_id)
@@ -2253,6 +2259,100 @@ class AMQPConnection:
         self._check_settled_raw(channel, deliveries, tag, multiple, 60, 80)
         self._ack_all(channel, deliveries)
         return 1
+
+    def _ack_run(self, raw, i: int, n: int, types, channels, offsets,
+                 lengths) -> int:
+        """basic.ack frame i of a scan batch and the frames after it,
+        settled as one run: each METHOD frame of the same channel that is
+        a 13-byte basic.ack with `multiple` 0 and a tag in `unacked` joins
+        it. Each delivery is settled as ServerChannel.ack -> Queue.ack
+        settles it: popped from `unacked` and `queue.outstanding`, the
+        queue's `n_acked`, the message's reference. What they write per
+        ack the run writes once: the ack counters, `queue_unacked`, the
+        consumer's prefetch budget (once a stretch of one consumer, clamped
+        as _release_budget clamps), the released bytes in one
+        account_memory while their sum stays under
+        Broker._memory_room_down (DispatchDrain.close's rule), one
+        schedule_dispatch a queue in first-ack order, one pair of clock
+        reads into `settle_ns`. The run ends before a frame it does not
+        take: another frame or channel, `multiple`, an unknown tag, a
+        queue that is not plain (stream, replicated, remote), a persisted
+        or paged message, the release that would reach that room. Such a
+        frame at the run's start, and every frame under a TX channel, a
+        trace sampler or the profiler, goes to _fused_ack. Returns the
+        frames consumed."""
+        channel_id = channels[i]
+        channel = self.channels.get(channel_id)
+        if (channel is None or channel.mode is ChannelMode.TX
+                or trace.ACTIVE is not None or profile.ACTIVE is not None):
+            return self._fused_ack(raw, offsets[i], channel_id)
+        broker = self.broker
+        unacked = channel.unacked
+        consumers = channel.consumers
+        unpack = _ACK_FRAME.unpack_from
+        keep = broker._memory_room_down()
+        t0 = time.perf_counter_ns()
+        queues: dict = {}
+        freed = settled = held = held_size = 0
+        ctag = consumer = None
+        j = i
+        while j < n:
+            if j != i and (types[j] != 1 or channels[j] != channel_id
+                           or lengths[j] != 13):
+                break
+            sig, tag, bits = unpack(raw, offsets[j])
+            if sig != _ACK_METHOD or bits & 1:
+                break
+            delivery = unacked.get(tag)
+            if delivery is None:
+                break
+            queue = delivery.queue
+            if not getattr(queue, "plain", False):
+                break
+            qm = delivery.queued
+            msg = qm.message
+            if msg.persisted or msg.paged:
+                break
+            left = msg.refer_count - 1
+            if left <= 0 and msg.accounted:
+                size = len(msg.body or b"")
+                if size >= keep:
+                    break
+                keep -= size
+                freed += size
+                msg.accounted = False
+            msg.refer_count = left
+            del unacked[tag]
+            if delivery.consumer_tag != ctag:
+                if consumer is not None:
+                    consumer.release(held, held_size)
+                ctag = delivery.consumer_tag
+                consumer = consumers.get(ctag)
+                held = held_size = 0
+            held += 1
+            held_size += qm.body_size
+            if queue.outstanding.pop(qm.offset, None) is not None:
+                settled += 1
+            queue.n_acked += 1
+            queues[queue] = None
+            j += 1
+        if j == i:
+            return self._fused_ack(raw, offsets[i], channel_id)
+        count = j - i
+        if consumer is not None:
+            consumer.release(held, held_size)
+        self.acked_msgs += count
+        metrics = broker.metrics
+        metrics.acked_msgs += count
+        metrics.ack_runs += 1
+        metrics.ack_run_msgs += count
+        broker.queue_unacked -= settled
+        if freed:
+            broker.account_memory(-freed)
+        for queue in queues:
+            queue.schedule_dispatch()
+        metrics.settle_ns += time.perf_counter_ns() - t0
+        return count
 
     def _ack_all(self, channel: ServerChannel, deliveries: list) -> None:
         """Settle what one Basic.Ack frame covers (`multiple` included),

@@ -861,6 +861,7 @@ class AdminServer:
         *Metrics.ROUTER_CLOSURE,
         "wal_queue_msg_records", "wal_queue_msgs_committed",
         "wal_settle_rows", "wal_commit_ns", "acked_msgs", "settle_ns",
+        "ack_runs", "ack_run_msgs",
         "wal_checkpoint_drain_ns", "wal_checkpoint_flush_ns",
         "wal_checkpoint_sync_ns", "wal_checkpoint_ns",
         "enqueue_run_msgs", "enqueue_run_pushes",

@@ -201,13 +201,18 @@ class Metrics:
         # commit's executor job (write + fsync), the histogram's own
         # stamps. acked_msgs: deliveries acknowledged (ServerChannel.ack),
         # broker-wide; settle_ns: wall of handling the Basic.Ack frames
-        # that settled them, one pair of clock reads a frame
+        # that settled them, one pair of clock reads a frame or an ack run.
+        # ack_runs: runs of consecutive basic.ack frames of one channel in
+        # a read batch settled in one loop (AMQPConnection._ack_run);
+        # ack_run_msgs: the deliveries those runs settled
         self.wal_queue_msg_records = 0
         self.wal_queue_msgs_committed = 0
         self.wal_settle_rows = 0
         self.wal_commit_ns = 0
         self.acked_msgs = 0
         self.settle_ns = 0
+        self.ack_runs = 0
+        self.ack_run_msgs = 0
         # a checkpoint's waits (WalEngine._checkpoint_once), each the wall
         # from before its await to after it, added when the await returns
         # (a failed checkpoint adds what it reached): the memtable's drain,
@@ -607,6 +612,8 @@ class Metrics:
             "wal_commit_ns": self.wal_commit_ns,
             "acked_msgs": self.acked_msgs,
             "settle_ns": self.settle_ns,
+            "ack_runs": self.ack_runs,
+            "ack_run_msgs": self.ack_run_msgs,
             "wal_checkpoint_drain_ns": self.wal_checkpoint_drain_ns,
             "wal_checkpoint_flush_ns": self.wal_checkpoint_flush_ns,
             "wal_checkpoint_sync_ns": self.wal_checkpoint_sync_ns,

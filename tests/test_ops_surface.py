@@ -762,9 +762,10 @@ async def test_closure_counters_on_both_surfaces(stack):
 
 
 async def test_log_and_settle_counters_on_both_surfaces(stack):
-    """The six counters of the log per message and queue and of the settle
-    path are on /admin/overview and, typed as counters, on /metrics.
-    Without a store the log's stay 0 while acks are still counted."""
+    """The eight counters of the log per message and queue and of the
+    settle path are on /admin/overview and, typed as counters, on /metrics.
+    Without a store the log's stay 0 while acks are still counted; the
+    single acks after the `multiple` one are settled in ack runs."""
     server, admin = stack
     c = await AMQPClient.connect("127.0.0.1", server.bound_port)
     ch = await c.channel()
@@ -792,11 +793,14 @@ async def test_log_and_settle_counters_on_both_surfaces(stack):
     log_side = ("wal_queue_msg_records", "wal_queue_msgs_committed",
                 "wal_settle_rows", "wal_commit_ns")
     assert [metrics[name] for name in log_side] == [0, 0, 0, 0]
+    # the `multiple` frame settles frame by frame, the five after it in runs
+    assert metrics["ack_run_msgs"] == 5 and 1 <= metrics["ack_runs"] <= 5
 
     status, _ctype, text = await http_text(admin.bound_port, "/metrics")
     assert status == 200
     lines = text.splitlines()
-    for name in log_side + ("acked_msgs", "settle_ns"):
+    for name in log_side + ("acked_msgs", "settle_ns", "ack_runs",
+                            "ack_run_msgs"):
         assert f"# TYPE chanamq_{name} counter" in lines
         assert f"chanamq_{name} {metrics[name]}" in lines
     await c.close()

@@ -276,6 +276,58 @@ async def test_service_samples_and_serves_payload():
         await server.stop()
 
 
+async def test_ack_runs_keep_the_ack_rates():
+    """Acks settled as a run (one read batch of single basic.ack frames)
+    move each queue's and the connection's ack counters as acks settled one
+    by one do: the sampled ack rates, unacked and the broker's gauges."""
+    server = BrokerServer(host="127.0.0.1", port=0, heartbeat_s=0)
+    await server.start()
+    try:
+        broker = server.broker
+        svc = TelemetryService(broker, interval_s=1.0, ring_ticks=16)
+        broker.telemetry = svc
+        c = await AMQPClient.connect("127.0.0.1", server.bound_port)
+        ch = await c.channel()
+        got = []
+        for name in ("ar_a", "ar_b"):
+            await ch.queue_declare(name)
+            await ch.basic_consume(name, got.append, no_ack=False)
+        for i in range(12):
+            for name in ("ar_a", "ar_b"):
+                ch.basic_publish(b"r%d" % i, routing_key=name)
+        for _ in range(100):
+            if len(got) == 24:
+                break
+            await asyncio.sleep(0.02)
+        svc.sample_tick(1.0)  # baseline: everything delivered, none acked
+        for msg in got:
+            ch.basic_ack(msg.delivery_tag)
+        for _ in range(100):
+            if broker.metrics.acked_msgs == 24:
+                break
+            await asyncio.sleep(0.02)
+        svc.sample_tick(1.0)
+        assert broker.metrics.ack_run_msgs == 24
+        assert 1 <= broker.metrics.ack_runs <= 24
+
+        payload = svc.local_payload(window=8)
+        fields = payload["fields"]["queue"]
+        for name in ("ar_a", "ar_b"):
+            entry = next(q for q in payload["queues"] if q["name"] == name)
+            latest = dict(zip(fields, entry["series"][-1]))
+            assert latest["ack_rate"] == 12.0 and latest["unacked"] == 0.0
+        fields = payload["fields"]["connection"]
+        rates = [dict(zip(fields, conn["series"][-1]))
+                 for conn in payload["connections"]]
+        assert [r["ack_rate"] for r in rates if r["ack_rate"]] == [24.0]
+        assert all(r["unacked"] == 0.0 for r in rates)
+        assert (broker.queue_depth, broker.queue_unacked,
+                broker.queue_consumers) == _walk(broker)
+        await c.close()
+    finally:
+        await server.stop()
+
+
 # ---------------------------------------------------------------------------
 # admin routes: conventions, 404s, readiness 503, opaque 500
 # ---------------------------------------------------------------------------
